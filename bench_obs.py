@@ -185,6 +185,9 @@ def bench_serving_rtt(reps=8):
 
 
 def main():
+    from deeplearning4j_tpu.nn.jit_cache import place_compile_cache
+
+    place_compile_cache()
     steps = int(sys.argv[1]) if len(sys.argv) > 1 else 300
     train = bench_training(steps=steps)
     serve = bench_serving_rtt()
@@ -220,15 +223,9 @@ def main():
                    "serving: stub rtt=5ms compute=4ms batch_limit=32 "
                    "24 clients pipelined depth 2"),
     }
-    try:
-        import jax
+    from bench_serving import _device_facts
 
-        dev = jax.devices()[0]
-        out["device"] = str(dev.device_kind)
-        out["platform"] = str(dev.platform)
-        out["jax"] = jax.__version__
-    except Exception:   # noqa: BLE001 - stub serving needs no backend
-        pass
+    out.update(_device_facts())
     print(json.dumps(out))
 
 

@@ -51,9 +51,11 @@ def main():
     rows = int(sys.argv[2]) if len(sys.argv) > 2 else 256
     hidden = int(sys.argv[3]) if len(sys.argv) > 3 else 256
 
+    from deeplearning4j_tpu.nn.jit_cache import place_compile_cache
     from deeplearning4j_tpu.parallel.training_master import TrainingMaster
     from deeplearning4j_tpu.resilience import NonFiniteGuard, StepWatchdog
 
+    place_compile_cache()
     rng = np.random.default_rng(0)
     x = rng.normal(size=(rows, 64)).astype(np.float32)
     y = np.eye(10, dtype=np.float32)[rng.integers(0, 10, rows)]

@@ -13,7 +13,7 @@ is traced/lowered here and checked against its *declared* facts:
                                  program's declared precision_policy
   prog-unhonored-donation        donate_argnums arg absent from the
                                  executable's input-output alias map
-  prog-transpose-churn           transpose/copy bytes above threshold
+  prog-transpose-churn           authored transpose bytes above threshold
   prog-hidden-host-transfer      outfeed/callback edges in a hot program
   prog-dead-output               computed outputs no caller consumes
   prog-excess-padding            serving pow2 bucket fill below threshold
@@ -99,9 +99,9 @@ class ProgramRecord:
     declaration is read back from `lowered.args_info`) or a plain
     callable jitted here with `donate_argnums`. `fn=None` records carry
     only registration metadata (the serving bucket fill records).
-    `compile=False` restricts the lint to trace/lower-level rules —
-    the flagship ResNet50 lowers in ~2s on CPU but XLA-compiles in
-    minutes, and the dtype/donation rules don't need the compile."""
+    Programs are traced and lowered, never compiled or run: every
+    rule reads the jaxpr or the lowered module, which are the same
+    whatever backend will compile them."""
 
     name: str
     fn: Optional[Callable] = None
@@ -112,14 +112,13 @@ class ProgramRecord:
     precision_policy: Optional[str] = None    # "bf16" | "f16" | "f32"
     consumed_outputs: Optional[Tuple[int, ...]] = None  # None = all
     source: str = "deeplearning4j_tpu/analysis/programs.py"
-    compile: bool = True
     # serving bucket metadata (prog-excess-padding)
     bucket_capacity: Optional[int] = None
     bucket_rows_per_dispatch: Optional[float] = None
     # mesh-sharded registration fact (prog-unsharded-optimizer-state):
     # top-level example_args indices whose leaves the program DECLARES
     # sharded (the ZeRO-1 optimizer state). The lint verifies the
-    # lowered module actually carries non-replicated mhlo.sharding
+    # lowered module actually carries non-replicated sdy.sharding
     # annotations AND donation/aliasing on those arguments — a silent
     # fallback to replicated state is exactly the O(n) memory
     # regression the rule exists to catch.
@@ -184,22 +183,11 @@ def _tensor_bytes(type_str: str) -> int:
     return n * _DTYPE_BYTES.get(dt, 4)
 
 
-def _hlo_shape_bytes(dtype: str, dims: str) -> int:
-    n = 1
-    for d in dims.split(","):
-        d = d.strip()
-        if d.isdigit():
-            n *= int(d)
-    return n * _DTYPE_BYTES.get(dtype, 4)
-
-
 _MAIN_SIG_RE = re.compile(
     r"func\.func\s+(?:public\s+)?@main\((.*?)\)\s*->", re.S)
 _ARG_RE = re.compile(r"%arg(\d+): tensor<([^>]*)>\s*(\{[^}]*\})?")
 _STABLE_TRANSPOSE_RE = re.compile(
     r"stablehlo\.transpose.*?->\s*tensor<([^>]*)>")
-_HLO_TRANSPOSE_RE = re.compile(
-    r"= (\w+)\[([^\]]*)\][^ ]* (?:transpose|copy)\(")
 _RESULT_RE = re.compile(r"->\s*\((.*?)\)\s*\{", re.S)
 
 
@@ -276,8 +264,7 @@ def _lint_one(rec: ProgramRecord, th: Thresholds) -> List[Finding]:
                          static_argnums=rec.static_argnums)
 
     # ONE trace serves every rule: jaxpr + out tree from the Traced,
-    # the lowered module (donation attrs) from it, the compile only
-    # when the record allows it
+    # the lowered module (donation and sharding attrs) from it
     with warnings.catch_warnings(record=True) as wrec:
         warnings.simplefilter("always")
         traced = jitted.trace(*rec.example_args, **rec.example_kwargs)
@@ -345,42 +332,30 @@ def _lint_one(rec: ProgramRecord, th: Thresholds) -> List[Finding]:
         _dead_outputs(rec, closed, out_shape, finding)
 
     # ---- prog-transpose-churn ----------------------------------------
-    if rec.compile:
-        compiled = lowered.compile()
-        txt = compiled.as_text()
-        ops = _HLO_TRANSPOSE_RE.findall(txt)
-        churn = sum(_hlo_shape_bytes(dt, dims) for dt, dims in ops)
-        total = _compiled_bytes_accessed(compiled)
-        if total is None:
-            total = _signature_bytes(lowered_text)
-        if (len(ops) >= th.transpose_min_ops and total
-                and churn / total >= th.transpose_bytes_frac):
-            finding(
-                "prog-transpose-churn",
-                f"{len(ops)} transpose/copy op(s) move "
-                f"{churn} bytes = {churn / total:.0%} of program "
-                f"traffic (threshold {th.transpose_bytes_frac:.0%}) — "
-                f"layout thrash")
-    else:
-        # lower-only records: model-authored transposes in StableHLO
-        trs = _STABLE_TRANSPOSE_RE.findall(lowered_text)
-        churn = sum(_tensor_bytes(t) for t in trs)
-        total = _signature_bytes(lowered_text)
-        if (len(trs) >= th.transpose_min_ops and total
-                and churn / total >= th.transpose_bytes_frac):
-            finding(
-                "prog-transpose-churn",
-                f"{len(trs)} authored transpose(s) move {churn} bytes "
-                f"= {churn / total:.0%} of program I/O (threshold "
-                f"{th.transpose_bytes_frac:.0%}) — layout thrash")
+    # Authored stablehlo.transpose bytes against the program signature.
+    # The lowered module is what the model's code asked for and is the
+    # same for every backend; the transposes and copies a backend's
+    # compiler adds are its own layout decisions (the CPU compiler's
+    # say nothing about the chip's), so they are read from a profile
+    # of the chip, not here.
+    trs = _STABLE_TRANSPOSE_RE.findall(lowered_text)
+    churn = sum(_tensor_bytes(t) for t in trs)
+    total = _signature_bytes(lowered_text)
+    if (len(trs) >= th.transpose_min_ops and total
+            and churn / total >= th.transpose_bytes_frac):
+        finding(
+            "prog-transpose-churn",
+            f"{len(trs)} authored transpose(s) move {churn} bytes "
+            f"= {churn / total:.0%} of program I/O (threshold "
+            f"{th.transpose_bytes_frac:.0%}) — layout thrash")
     return findings
 
 
 def _arg_segments(lowered_text: str) -> Dict[int, str]:
     """{arg_index: raw attribute text} of the lowered @main signature.
-    Attribute dicts may nest braces inside quoted mhlo.sharding values
-    (`"{devices=[8]<=[8]}"`), so the signature is split on `%arg`
-    boundaries instead of brace-matched."""
+    Attribute dicts nest braces inside sdy.sharding values
+    (`#sdy.sharding<@mesh, [{"dp"}, {}]>`), so the signature is split
+    on `%arg` boundaries instead of brace-matched."""
     m = _MAIN_SIG_RE.search(lowered_text)
     if m is None:
         return {}
@@ -396,12 +371,26 @@ def _arg_segments(lowered_text: str) -> Dict[int, str]:
     return out
 
 
+# Shardy's argument annotation: `sdy.sharding = #sdy.sharding<@mesh,
+# [{"dp"}, {}]>` — one `{...}` per dimension, naming the mesh axes that
+# dimension is split over; `[{}, {}]` is replicated
+_SDY_DIMS_RE = re.compile(r"sdy\.sharding\s*=\s*#sdy\.sharding<@\w+,"
+                          r"\s*\[([^\]]*)\]")
+
+
+def _axis_sharded(seg: str) -> bool:
+    """True when an @main argument's attribute text shards at least
+    one dimension over a named mesh axis."""
+    m = _SDY_DIMS_RE.search(seg)
+    return m is not None and '"' in m.group(1)
+
+
 def _check_sharded_args(rec: ProgramRecord, lowered_text: str,
                         finding) -> None:
     """prog-unsharded-optimizer-state: every example leaf of a
     declared `sharded_argnums` argument that IS sharded at the call
     site must appear in the lowered @main with a non-replicated
-    mhlo.sharding annotation AND donation/aliasing; a declaration with
+    sdy.sharding annotation AND donation/aliasing; a declaration with
     no sharded leaf at all is the catastrophic silent-replication
     case."""
     import jax
@@ -437,7 +426,7 @@ def _check_sharded_args(rec: ProgramRecord, lowered_text: str,
         unaliased = []
         for i in expected:
             seg = segs.get(i, "")
-            if "mhlo.sharding" not in seg or "devices=" not in seg:
+            if not _axis_sharded(seg):
                 unannotated.append(i)
             elif "buffer_donor" not in seg \
                     and "aliasing_output" not in seg:
@@ -488,22 +477,9 @@ def _dead_outputs(rec: ProgramRecord, closed, out_shape,
                 f"no caller consumes it — wasted flops and transfer")
 
 
-def _compiled_bytes_accessed(compiled) -> Optional[float]:
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:   # noqa: BLE001 - cost analysis is best-effort
-        return None
-    entries = ca if isinstance(ca, (list, tuple)) else [ca]
-    total = 0.0
-    for e in entries:
-        if isinstance(e, dict):
-            total += float(e.get("bytes accessed", 0.0) or 0.0)
-    return total or None
-
-
 def _signature_bytes(lowered_text: str) -> int:
-    """Sum of @main argument + result tensor bytes — the lower-only
-    fallback denominator for churn fractions."""
+    """Sum of @main argument + result tensor bytes — the denominator
+    of the churn fraction."""
     total = sum(_tensor_bytes(t) for _, t, _ in
                 _main_signature(lowered_text))
     m = _RESULT_RE.search(lowered_text)

@@ -29,9 +29,7 @@ registration) but at CPU-lintable dims:
                               whose dropped donation the first audit
                               run caught — PERF.md)
   bench_flagship_k_steps      the bench's ResNet50 k-step program at
-                              reduced dims, lower-only (XLA-compiling
-                              it takes minutes on CPU; the dtype and
-                              alias-map rules only need the lowering)
+                              reduced dims
   graft_entry_forward         the published __graft_entry__ forward,
                               pinned to the flagship bf16 policy (the
                               fp32-default the first audit run caught)
@@ -190,7 +188,7 @@ def _flagship_records() -> List[ProgramRecord]:
         batch=2, hw=32, n_classes=8, unroll=2)
     records = [ProgramRecord(
         name="bench_flagship_k_steps", fn=jit_k, example_args=args,
-        precision_policy="bf16", compile=False, source="bench.py",
+        precision_policy="bf16", source="bench.py",
         consumed_outputs=(0, 1, 2, 3))]
 
     from __graft_entry__ import entry
@@ -198,7 +196,7 @@ def _flagship_records() -> List[ProgramRecord]:
     fwd, fargs = entry(hw=32, n_classes=8)
     records.append(ProgramRecord(
         name="graft_entry_forward", fn=fwd, example_args=fargs,
-        precision_policy="bf16", compile=False,
+        precision_policy="bf16",
         source="__graft_entry__.py"))
     return records
 

@@ -60,7 +60,8 @@ class EarlyStoppingTrainer:
         self.guard = self._harness.guard
         # ZeRO-1 (engine/sharding.py): _fit_batch routes through the
         # mesh-sharded StepProgram — optimizer state sharded over the
-        # live device mesh, byte-identical to the unsharded trainer
+        # live device mesh, equal to the unsharded trainer within a
+        # few ulp
         if sharding not in (None, "replicated", "zero1"):
             raise ValueError(
                 f"sharding must be None|'replicated'|'zero1': {sharding}")

@@ -165,7 +165,8 @@ class StepProgram:
         (engine/sharding.py) over `manager`'s mesh: optimizer state
         lives SHARDED between steps (1/n per replica), the update is
         reduce-scatter → shard-local → all-gather inside the one
-        donated program, byte-identical to the unsharded step. Every
+        donated program, equal to the unsharded step within a few ulp
+        (another reduction order; engine/sharding.py). Every
         harness entry point inherits the sharded compilation through
         run/run_group/run_batch unchanged."""
         if self.is_tbptt:
@@ -508,6 +509,8 @@ class StepProgram:
                             mgr.batch_sharding())
         yb = jax.device_put(jnp.asarray(y, net.dtype),
                             mgr.batch_sharding())
+        if self.is_graph:
+            xb, yb, _, _ = self._graph_args(xb, yb, None, None)
         _, sub = jax.random.split(net._rng)
         args = (params, upd, states,
                 jnp.asarray(net.iteration, jnp.int32), xb, yb, None,

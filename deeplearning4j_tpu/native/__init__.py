@@ -6,15 +6,18 @@ SURVEY L0/L2) are native here too: native/dl4j_tpu_native.cpp provides
 fast CSV->f32 parsing and fused u8->f32 (de)normalization/layout ops.
 
 The library is compiled on demand with g++ (no pybind11 in this image;
-plain C ABI + ctypes) and cached beside the source. Every entry point
-has a NumPy fallback, so the package works — just slower — without a
-toolchain. `available()` reports which path is active.
+plain C ABI + ctypes) and cached beside the source, under a name that
+carries the hash of the source and of the CPU it was built for. Every
+entry point has a NumPy fallback, so the package works — just slower —
+without a toolchain. `available()` reports which path is active.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -24,8 +27,6 @@ import numpy as np
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC_DIR = os.path.join(_REPO_ROOT, "native")
-_LIB_NAME = "libdl4j_tpu_native.so"
-
 _ABI_VERSION = 3
 
 _lock = threading.Lock()
@@ -33,43 +34,54 @@ _lib = None
 _tried = False
 
 
+def _artifact_key(src: str, build: str) -> str:
+    """What a built library is valid for: the source and build script
+    it was made from and the CPU it was made on. build.sh compiles
+    with -march=native, and the library is git-ignored but copied
+    along with the tree, so an artifact may arrive on a machine whose
+    CPU lacks the instructions it uses. The key is in the file name:
+    anything it does not match is simply not found, and built here."""
+    h = hashlib.sha256()
+    for path in (src, build):
+        with open(path, "rb") as f:
+            h.update(f.read())
+    h.update(platform.machine().encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            h.update(next((line for line in f
+                           if line.startswith(("flags", "Features"))),
+                          "").encode())
+    except OSError:
+        pass
+    return h.hexdigest()[:12]
+
+
 def _build_and_load() -> Optional[ctypes.CDLL]:
     src = os.path.join(_SRC_DIR, "dl4j_tpu_native.cpp")
+    build = os.path.join(_SRC_DIR, "build.sh")
     if not os.path.exists(src):
         return None
-    out = os.path.join(_SRC_DIR, _LIB_NAME)
-    if not os.path.exists(out) or (os.path.getmtime(out)
-                                   < os.path.getmtime(src)):
+    out = os.path.join(
+        _SRC_DIR, f"libdl4j_tpu_native-{_artifact_key(src, build)}.so")
+    if not os.path.exists(out):
+        # build beside the target and rename: a test worker that loads
+        # while another builds never sees a half-written library
+        tmp = f"{out}.{os.getpid()}.tmp"
         try:
-            subprocess.run(
-                ["sh", os.path.join(_SRC_DIR, "build.sh"), out],
-                check=True, capture_output=True, timeout=120)
-        except Exception:
+            subprocess.run(["sh", build, tmp], check=True,
+                           capture_output=True, timeout=120)
+            os.replace(tmp, out)
+        except (OSError, subprocess.SubprocessError):
             return None
     try:
-        lib = ctypes.CDLL(out)
-    except OSError:
+        return _bind(ctypes.CDLL(out))
+    except (OSError, AttributeError):
         return None
-    try:
-        return _bind(lib)
-    except AttributeError:
-        # stale cached .so missing a symbol (e.g. a copied artifact with
-        # a newer mtime than the source): rebuild once from the current
-        # tree, then fall back to NumPy if it is still unloadable
-        try:
-            os.remove(out)
-            subprocess.run(
-                ["sh", os.path.join(_SRC_DIR, "build.sh"), out],
-                check=True, capture_output=True, timeout=120)
-            return _bind(ctypes.CDLL(out))
-        except (OSError, subprocess.SubprocessError, AttributeError):
-            return None
 
 
 def _bind(lib: ctypes.CDLL) -> Optional[ctypes.CDLL]:
     if lib.dl4j_native_abi_version() != _ABI_VERSION:
-        # stale cached artifact: raise so _build_and_load's rebuild
-        # path (the AttributeError handler) removes and rebuilds it
+        # source and bindings disagree: the NumPy paths serve
         raise AttributeError(
             f"native ABI {lib.dl4j_native_abi_version()} != "
             f"{_ABI_VERSION}")

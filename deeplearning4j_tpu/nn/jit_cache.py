@@ -32,6 +32,7 @@ compare.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from collections import deque
@@ -40,6 +41,29 @@ from typing import Dict, List, Optional
 from deeplearning4j_tpu.observability import metrics as _obs
 
 COMPILE_RING = 16
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def place_compile_cache() -> str:
+    """Where XLA's persistent compilation cache lives — the ONE place
+    this repository decides it; every entry point (chip_smoke.py, the
+    bench scripts, __graft_entry__, tests/conftest.py) calls this and
+    sets no directory itself. `JAX_COMPILATION_CACHE_DIR` wins: jax
+    reads it on its own, so nothing is set here and whoever runs the
+    program places the cache. Otherwise `<checkout>/.jax_cache`
+    (git-ignored). The path is part of every entry's key, so it is
+    fixed: never built from a temp name, a pid, a version or a time.
+    Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def policy_name(compute_dtype) -> str:

@@ -53,39 +53,35 @@ from typing import Dict, List, Optional, Tuple
 from deeplearning4j_tpu.observability import metrics as _obs
 from deeplearning4j_tpu.observability.metrics import render_prometheus
 
-# per-chip peak compute (bf16 unless the hardware has no bf16 units)
-# and HBM bandwidth — the two roofline axes. "cpu" entries are nominal
-# placeholders: MFU on CPU is a smoke-test number, not a claim.
-PEAK_FLOPS = {
-    "TPU v5 lite": 197e12,    # v5e bf16
-    "TPU v4": 275e12,
-    "TPU v3": 123e12,
-    "cpu": 1e12,
+# Per-chip peaks, keyed by `device_kind`: (bf16 FLOP/s, HBM bytes/s) —
+# the two roofline axes. Sources: Google Cloud TPU documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s), "TPU v4" (275 TFLOP/s,
+# 1228 GB/s), "TPU v3" (123 TFLOP/s, 900 GB/s). A device that is not
+# in the table is an error, not a default: a utilization against a
+# guessed peak is a made-up number.
+PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v3": (123e12, 900e9),
 }
-PEAK_BYTES_PER_S = {
-    "TPU v5 lite": 819e9,
-    "TPU v4": 1228e9,
-    "TPU v3": 900e9,
-    "cpu": 50e9,
-}
-_DEFAULT_PEAK_FLOPS = 197e12
-_DEFAULT_PEAK_BW = 819e9
 
 
 def device_peaks(device=None) -> Tuple[float, float, str]:
     """(peak_flops, peak_bytes_per_s, device_kind) for `device` (default
-    jax.devices()[0]); unknown kinds fall back to the v5e numbers."""
-    kind = "unknown"
-    try:
-        if device is None:
-            import jax
+    jax.devices()[0]). Raises KeyError for a kind `PEAKS` does not
+    list — the CPU among them."""
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        kind = str(device.device_kind)
-    except Exception:   # noqa: BLE001 - no backend: nominal peaks
-        pass
-    return (PEAK_FLOPS.get(kind, _DEFAULT_PEAK_FLOPS),
-            PEAK_BYTES_PER_S.get(kind, _DEFAULT_PEAK_BW), kind)
+        device = jax.devices()[0]
+    kind = str(device.device_kind)
+    if kind not in PEAKS:
+        raise KeyError(
+            f"no published peak for device kind {kind!r} (known: "
+            f"{sorted(PEAKS)}); add it to observability.perf.PEAKS "
+            "with its source")
+    flops, bw = PEAKS[kind]
+    return flops, bw, kind
 
 
 # ------------------------------------------------ analytic flop counts
@@ -110,51 +106,30 @@ def train_step_flops_from_params(n_params: int, rows: int) -> float:
 
 
 # ------------------------------------------------- XLA cost extraction
-def _normalize_cost(ca) -> Optional[dict]:
-    """`cost_analysis()` returns a dict on some backends and a list of
-    per-computation dicts on others; fold either into
-    {flops, bytes_accessed} or None when nothing usable came back."""
-    if ca is None:
-        return None
-    entries = ca if isinstance(ca, (list, tuple)) else [ca]
-    flops = 0.0
-    bytes_accessed = 0.0
-    for e in entries:
-        if not isinstance(e, dict):
-            continue
-        flops += float(e.get("flops", 0.0) or 0.0)
-        bytes_accessed += float(e.get("bytes accessed", 0.0) or 0.0)
-    if flops <= 0.0:
-        return None
-    return {"flops": flops, "bytes_accessed": bytes_accessed}
-
-
 def extract_cost(target, *args, **kwargs) -> Optional[dict]:
     """Pull {flops, bytes_accessed, peak_bytes} from XLA cost analysis.
 
     `target` is either a `jax.jit`-wrapped callable — lowered and
     compiled here with the given example (or ShapeDtypeStruct) args —
     or an already-compiled jax.stages object (the AOT path benches use
-    to avoid a duplicate compile). Returns None when the backend
-    reports nothing usable (the analytic-fallback trigger)."""
-    try:
-        compiled = target
-        if not hasattr(compiled, "cost_analysis"):
-            compiled = target.lower(*args, **kwargs).compile()
-        entry = _normalize_cost(compiled.cost_analysis())
-        if entry is None:
+    to avoid a duplicate compile). Returns None when `target` is
+    neither, or when the compiler counted no flops (the
+    analytic-fallback trigger)."""
+    compiled = target
+    if not hasattr(compiled, "cost_analysis"):
+        if not hasattr(target, "lower"):
             return None
-        try:
-            mem = compiled.memory_analysis()
-            entry["peak_bytes"] = int(
-                getattr(mem, "temp_size_in_bytes", 0)
-                + getattr(mem, "argument_size_in_bytes", 0)
-                + getattr(mem, "output_size_in_bytes", 0))
-        except Exception:   # noqa: BLE001 - memory stats are optional
-            entry["peak_bytes"] = None
-        return entry
-    except Exception:   # noqa: BLE001 - cost extraction must never raise
+        compiled = target.lower(*args, **kwargs).compile()
+    ca = compiled.cost_analysis()    # one dict per executable (jax 0.9)
+    flops = float(ca.get("flops", 0.0))
+    if flops <= 0.0:
         return None
+    mem = compiled.memory_analysis()
+    return {"flops": flops,
+            "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
+            "peak_bytes": int(mem.temp_size_in_bytes
+                              + mem.argument_size_in_bytes
+                              + mem.output_size_in_bytes)}
 
 
 class CostModel:
@@ -169,10 +144,24 @@ class CostModel:
     def __init__(self, peak_flops: Optional[float] = None,
                  peak_bytes_per_s: Optional[float] = None,
                  device=None):
-        det_flops, det_bw, kind = device_peaks(device)
-        self.peak_flops = float(peak_flops or det_flops)
-        self.peak_bytes_per_s = float(peak_bytes_per_s or det_bw)
-        self.device_kind = kind
+        """Peaks come from `PEAKS` by the device's kind unless both are
+        given. On the CPU platform there is no peak: flops and bytes
+        are still counted, and `mfu`/`roofline` answer None — a CPU run
+        reports no utilization rather than one against an invented
+        peak. Any other device missing from the table raises."""
+        if device is None:
+            import jax
+
+            device = jax.devices()[0]
+        self.device_kind = str(device.device_kind)
+        if peak_flops and peak_bytes_per_s:
+            self.peak_flops = float(peak_flops)
+            self.peak_bytes_per_s = float(peak_bytes_per_s)
+        elif device.platform == "cpu":
+            self.peak_flops = self.peak_bytes_per_s = None
+        else:
+            self.peak_flops, self.peak_bytes_per_s, _ = \
+                device_peaks(device)
         self._entries: Dict[str, dict] = {}
 
     # ------------------------------------------------------- register
@@ -247,7 +236,7 @@ class CostModel:
         device peak. The honest headline — counts the flops the model
         NEEDS (as compiled), not the flops the kernel burned."""
         e = self._entries.get(str(key))
-        if e is None or seconds_per_call <= 0.0:
+        if e is None or seconds_per_call <= 0.0 or not self.peak_flops:
             return None
         return e["flops"] / seconds_per_call / self.peak_flops
 
@@ -256,7 +245,7 @@ class CostModel:
         intensity vs the ridge point (peak_flops / peak_bw), plus the
         bandwidth-bound attainable flops ceiling."""
         ai = self.arithmetic_intensity(key)
-        if ai is None:
+        if ai is None or not self.peak_flops:
             return None
         ridge = self.peak_flops / self.peak_bytes_per_s
         return {
@@ -534,7 +523,7 @@ def aggregate_prometheus_text(sources) -> str:
 
 
 __all__ = [
-    "PEAK_FLOPS", "PEAK_BYTES_PER_S", "PHASES",
+    "PEAKS", "PHASES",
     "CostModel", "StepPhaseProfiler",
     "device_peaks", "extract_cost",
     "matmul_flops", "conv2d_flops", "train_step_flops_from_params",

@@ -51,7 +51,7 @@ server — every model × version has its own warmed ParallelInference):
   DELETE /v1/models/<name>/versions/<v> retire a non-active version
   DELETE /v1/models/<name>              remove the model entirely
 
-Failure taxonomy (resilience subsystem) instead of blanket 400:
+Failure classes (resilience subsystem) instead of blanket 400:
   404 unknown route / unknown model or version
   400 malformed payload / client error
   429 + Retry-After tenant quota exhausted or priority class shed
@@ -709,7 +709,7 @@ class ModelServer:
                 return name, cmd, ver
 
             def _guarded(self, fn, value_error_code=400):
-                """Run a handler under the full error taxonomy.
+                """Run a handler under the full error classification.
                 `value_error_code` routes bare ValueErrors: 400 on data
                 routes (bad request payloads), 409 on lifecycle routes
                 (swap/delete conflicts)."""

@@ -26,7 +26,7 @@ def build_records():
         example_args=({"w": jnp.zeros((16, 8), jnp.float32),
                        "b": jnp.zeros((8,), jnp.float32)},
                       jnp.zeros((4, 16), jnp.float32)),
-        precision_policy="bf16", compile=False, source=SRC))
+        precision_policy="bf16", source=SRC))
 
     # prog-unhonored-donation: donated [n_pad, C] buffer can never
     # alias the [n_real, C] output (the pre-fix tsne shape)
@@ -36,10 +36,10 @@ def build_records():
     records.append(ProgramRecord(
         name="bad_unhonored_donation", fn=sliced_step,
         example_args=(jnp.zeros((8, 64), jnp.float32),),
-        donate_argnums=(0,), compile=False, source=SRC))
+        donate_argnums=(0,), source=SRC))
 
     # prog-transpose-churn: eight authored layout round-trips of the
-    # whole activation tensor (lower-only: the rule counts authored
+    # whole activation tensor (the rule counts authored
     # stablehlo.transpose bytes against the program signature)
     def churny(x):
         acc = x
@@ -49,8 +49,7 @@ def build_records():
 
     records.append(ProgramRecord(
         name="bad_transpose_churn", fn=churny,
-        example_args=(jnp.zeros((128, 128), jnp.float32),),
-        compile=False, source=SRC))
+        example_args=(jnp.zeros((128, 128), jnp.float32),), source=SRC))
 
     # prog-hidden-host-transfer: a host callback inside the program
     def hosty(x):
@@ -61,8 +60,7 @@ def build_records():
 
     records.append(ProgramRecord(
         name="bad_host_transfer", fn=hosty,
-        example_args=(jnp.zeros((4, 4), jnp.float32),),
-        compile=False, source=SRC))
+        example_args=(jnp.zeros((4, 4), jnp.float32),), source=SRC))
 
     # prog-dead-output: output 1 is computed but declared unconsumed
     def deady(x):
@@ -71,7 +69,7 @@ def build_records():
     records.append(ProgramRecord(
         name="bad_dead_output", fn=deady,
         example_args=(jnp.zeros((8, 8), jnp.float32),),
-        consumed_outputs=(0,), compile=False, source=SRC))
+        consumed_outputs=(0,), source=SRC))
 
     # prog-excess-padding: 3 real rows per dispatch into a 32-bucket
     records.append(ProgramRecord(
@@ -96,6 +94,6 @@ def build_records():
         example_args=(jax.device_put(jnp.zeros((16, 4)), rep),
                       jax.device_put(jnp.zeros((16, 4)), rep),
                       jax.device_put(jnp.ones((8,)), rep)),
-        donate_argnums=(0, 1), compile=False,
+        donate_argnums=(0, 1),
         sharded_argnums=(1,), source=SRC))
     return records

@@ -22,7 +22,7 @@ def build_records():
         example_args=({"w": jnp.zeros((16, 8), jnp.float32),
                        "b": jnp.zeros((8,), jnp.float32)},
                       jnp.zeros((4, 16), jnp.float32)),
-        precision_policy="bf16", compile=False, source=SRC))
+        precision_policy="bf16", source=SRC))
 
     # donation honored: same-shape update aliases the donated buffer
     def donated_step(y):
@@ -31,7 +31,7 @@ def build_records():
     records.append(ProgramRecord(
         name="clean_donation", fn=donated_step,
         example_args=(jnp.zeros((8, 64), jnp.float32),),
-        donate_argnums=(0,), compile=False, source=SRC))
+        donate_argnums=(0,), source=SRC))
 
     # one authored transpose (the weight transpose every backward pass
     # legitimately pays) stays under the churn threshold
@@ -40,8 +40,7 @@ def build_records():
 
     records.append(ProgramRecord(
         name="clean_single_transpose", fn=one_transpose,
-        example_args=(jnp.zeros((128, 128), jnp.float32),),
-        compile=False, source=SRC))
+        example_args=(jnp.zeros((128, 128), jnp.float32),), source=SRC))
 
     # pure device program: no host edges
     def devicey(x):
@@ -49,8 +48,7 @@ def build_records():
 
     records.append(ProgramRecord(
         name="clean_no_host_transfer", fn=devicey,
-        example_args=(jnp.zeros((4, 4), jnp.float32),),
-        compile=False, source=SRC))
+        example_args=(jnp.zeros((4, 4), jnp.float32),), source=SRC))
 
     # all computed outputs consumed; the UNconsumed output is a pure
     # input pass-through, which costs nothing and must not flag
@@ -60,7 +58,7 @@ def build_records():
     records.append(ProgramRecord(
         name="clean_passthrough_output", fn=passthrough,
         example_args=(jnp.zeros((8, 8), jnp.float32),),
-        consumed_outputs=(0,), compile=False, source=SRC))
+        consumed_outputs=(0,), source=SRC))
 
     # full buckets: the pow2 coalescer's fill > 0.5 invariant
     records.append(ProgramRecord(
@@ -91,6 +89,6 @@ def build_records():
         example_args=(jax.device_put(jnp.zeros((16, 4)), rep),
                       jax.device_put(jnp.zeros((16, 4)), sh),
                       jax.device_put(jnp.ones((8,)), sh)),
-        donate_argnums=(0, 1), compile=False,
+        donate_argnums=(0, 1),
         sharded_argnums=(1,), source=SRC))
     return records

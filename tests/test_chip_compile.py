@@ -1,0 +1,145 @@
+"""The main path's kernels and decode programs, compiled for the chip
+without the chip.
+
+The TPU's compiler is installed beside the CPU backend and compiles for
+a v5e that is described, not attached (`jax.experimental.topologies`).
+Interpret mode — all `tests/test_pallas_kernels.py` can run here —
+accepts kernels the chip's compiler refuses: a slice off the tiling,
+too much VMEM, a program that does not fit HBM. These compiles guard
+that at real widths (ResNet50 batch 128, stage 1 and stage 4, bf16; the
+decode programs at the widths `chip_smoke.py` serves) for about a
+second each. Nothing runs: they say nothing about results or times.
+
+Rules this file keeps (the on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture, never at import,
+so every xdist worker collects the same tests and only the worker that
+runs this file loads libtpu; everything lives in this ONE file, since a
+second file could land on another worker, whose fixture would skip; the
+persistent compile cache is off around these compiles, because such an
+entry cannot be read back without a chip.
+"""
+
+import numpy as np
+import pytest
+
+BATCH = 128
+# ResNet50 bottleneck widths at batch 128: (spatial, mid channels,
+# out channels) of the first and the last residual stage
+STAGES = {"stage1": (56, 64, 256), "stage4": (7, 512, 2048)}
+DECODER = dict(vocab_size=512, d_model=128, n_heads=4, n_layers=4,
+               max_ctx=128, seed=17)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import (
+        compilation_cache as cc,
+    )
+
+    env = pytest.MonkeyPatch()
+    env.setenv("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - any failure means: skip
+        env.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    cc.reset_cache()
+    env.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_case(kernel, stage):
+    """(fn, [(shape, dtype), ...]) for one Pallas entry point at one
+    ResNet50 stage: the affine + relu prologue form the fused graph
+    runs between two convolutions."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.helpers import pallas_conv as pc
+
+    hw, c_mid, c_out = STAGES[stage]
+    m = BATCH * hw * hw
+    bf, f32 = jnp.bfloat16, jnp.float32
+    if kernel == "fused_conv1x1":
+        return (lambda x, w, b, s, t: pc.fused_conv1x1(
+            x, w, b, scale=s, shift=t, relu=True, emit_u=True),
+            [((m, c_mid), bf), ((c_mid, c_out), bf), ((c_out,), f32),
+             ((c_mid,), f32), ((c_mid,), f32)])
+    if kernel == "fused_conv3x3":
+        return (lambda x, w, b, s, t: pc.fused_conv3x3(
+            x, w, b, scale=s, shift=t, relu=True),
+            [((BATCH, hw, hw, c_mid), bf), ((3, 3, c_mid, c_mid), bf),
+             ((c_mid,), f32), ((c_mid,), f32), ((c_mid,), f32)])
+    if kernel == "dgrad_conv1x1":
+        return (lambda dy, y, w, x, s, t: pc.dgrad_conv1x1(
+            dy, y, w, x, scale=s, shift=t, relu=True),
+            [((m, c_out), bf), ((m, c_out), bf), ((c_mid, c_out), bf),
+             ((m, c_mid), bf), ((c_mid,), f32), ((c_mid,), f32)])
+    assert kernel == "wgrad_conv1x1"
+    return (lambda dy, y, x, s, t: pc.wgrad_conv1x1(
+        dy, y, x, scale=s, shift=t, relu=True),
+        [((m, c_out), bf), ((m, c_out), bf), ((m, c_mid), bf),
+         ((c_mid,), f32), ((c_mid,), f32)])
+
+
+@pytest.mark.parametrize("stage", sorted(STAGES))
+@pytest.mark.parametrize("kernel", ["fused_conv1x1", "fused_conv3x3",
+                                    "dgrad_conv1x1", "wgrad_conv1x1"])
+def test_pallas_kernel_compiles_for_v5e(kernel, stage, one_chip,
+                                        monkeypatch):
+    import jax
+
+    from deeplearning4j_tpu.nn.helpers import pallas_conv as pc
+
+    # jax.default_backend() is "cpu" here, so the kernels would pick
+    # interpret mode; the chip's compiler must get the Mosaic lowering
+    monkeypatch.setattr(pc, "_interpret", lambda: False)
+    fn, specs = _kernel_case(kernel, stage)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_step_s8",
+                                     "decode_prefill_c16",
+                                     "decode_page_copy"])
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"],
+                         ids=["f32", "bf16"])
+def test_decode_program_compiles_for_v5e(compute_dtype, program,
+                                         one_chip):
+    """The three programs DecodeEngine dispatches, lowered from
+    `DecodeProgram.lint_records()` (the cache paths the engine itself
+    uses) with the example arguments turned into shapes on the
+    described chip."""
+    import jax
+
+    from deeplearning4j_tpu.engine.decode_program import DecodeProgram
+    from deeplearning4j_tpu.zoo.decoder import CausalTransformer
+
+    model = CausalTransformer(compute_dtype=compute_dtype,
+                              **DECODER).init()
+    prog = DecodeProgram(model, max_slots=8, page_size=16)
+    rec = {r.name: r for r in prog.lint_records()}[program]
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip),
+        rec.example_args)
+    compiled = rec.fn.lower(*shapes).compile()
+    mem = compiled.memory_analysis()
+    assert mem is not None
+    # the donated page pool is updated in place: its bytes are aliased
+    assert mem.alias_size_in_bytes >= np.prod(prog.kv_shape) * 4

@@ -509,7 +509,6 @@ def _worker_env(device_count=4):
     shard 30 rows (32//3 * 3) — pass 2 there so dp=6 divides 30."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_PLATFORM_NAME"] = "cpu"
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={device_count}"
     env.pop("JAX_COORDINATOR_ADDRESS", None)

@@ -19,7 +19,6 @@ REPO = os.path.join(os.path.dirname(__file__), "..")
 def _worker_env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_PLATFORM_NAME"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     # fresh world per subprocess (the parent's jax state is irrelevant)
     env.pop("JAX_COORDINATOR_ADDRESS", None)

@@ -17,7 +17,6 @@ HELPER = os.path.join(os.path.dirname(__file__), "helpers",
 def _worker_env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_PLATFORM_NAME"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env.pop("JAX_COORDINATOR_ADDRESS", None)
     return env

@@ -610,7 +610,7 @@ def test_batcher_death_fails_all_inflight_and_flips_healthz(tmp_path):
         server.stop()
 
 
-def test_http_error_taxonomy(tmp_path):
+def test_http_error_classes(tmp_path):
     """Satellite: 404 unknown route, 400 malformed payload, 500 model
     crash, 503 shutdown — with error_class in every body."""
     from deeplearning4j_tpu.parallel.serving import ModelClient, ModelServer

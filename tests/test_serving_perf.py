@@ -299,7 +299,7 @@ def test_completion_stage_death_fails_callers_and_flips_health():
 # ====================================== CPU serving-perf smoke test
 def test_pipelined_throughput_beats_blocking_dispatch():
     """CI smoke: on a stub net with an artificial per-dispatch RTT
-    (the PERF.md 4-6 ms tunnel round trip), the pipelined data plane
+    (5 ms, a synthetic figure), the pipelined data plane
     must out-throughput serialized dispatch-then-fetch. Catches a
     regression to blocking dispatch."""
     import concurrent.futures as cf
