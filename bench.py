@@ -358,7 +358,8 @@ def bench_pipeline(pipeline: bool, steps=48, etl_ms=12.0, batch=512,
 
     tm = TrainingMaster(net, pipeline=pipeline)
     tm.fit(slow_batch, 2)                 # compile warm-up, unprofiled
-    tm.phase_profiler = StepPhaseProfiler()
+    # a sync a step: this drill compares shares against device_compute
+    tm.phase_profiler = StepPhaseProfiler(sync_every=1)
     t0 = time.perf_counter()
     tm.fit(slow_batch, 2 + steps, start_step=2)
     dt = time.perf_counter() - t0

@@ -231,37 +231,49 @@ class DecodeProgram:
         def decode_fn(params, pool, tokens, positions, cell_page,
                       cell_off, write_page, write_off):
             cache.record_trace(trace_key)
-            # logical positions grow unbounded past max_ctx (ring
-            # wrap); the learned positional table wraps with them
-            x = (params["tok_emb"][tokens]
-                 + params["pos_emb"][positions % max_ctx])
+            # The named scopes say what the work is, with no layer
+            # index (a reader sums over layers): they are what the
+            # device trace's operations are summed by
+            # (benchmark/timeline.py), so they outlive a change to how
+            # the work is done.
+            with jax.named_scope("embed"):
+                # logical positions grow unbounded past max_ctx (ring
+                # wrap); the learned positional table wraps with them
+                x = (params["tok_emb"][tokens]
+                     + params["pos_emb"][positions % max_ctx])
             live = jnp.minimum(positions + 1, self.window)
             cp = cell_page[:, None, :]        # [S, 1, C] vs hidx
             co = cell_off[:, None, :]
             for li, lp in enumerate(params["layers"]):
-                q, k, v = decode_qkv(lp, x, n_heads)
+                with jax.named_scope("qkv"):
+                    q, k, v = decode_qkv(lp, x, n_heads)
                 # scatter: pool[li, io, wp[s], h, wo[s]] = k[s, h] —
                 # the write cell is host-chosen (suppressed rows
                 # target scratch), advanced indices broadcast per slot
-                pool = pool.at[li, 0, write_page, :, write_off].set(k)
-                pool = pool.at[li, 1, write_page, :, write_off].set(v)
+                with jax.named_scope("kv_write"):
+                    pool = pool.at[li, 0, write_page, :,
+                                   write_off].set(k)
+                    pool = pool.at[li, 1, write_page, :,
+                                   write_off].set(v)
                 # gather: [S, H, C, D] head-major window cells in
                 # logical order — the virtual-memory read
-                kg = pool[li, 0][cp, hidx, co]
-                vg = pool[li, 1][cp, hidx, co]
+                with jax.named_scope("kv_read"):
+                    kg = pool[li, 0][cp, hidx, co]
+                    vg = pool[li, 1][cp, hidx, co]
                 x = block_decode_finish(lp, x, q, kg, vg, live)
-            xf = layer_norm(x, params["lnf_g"], params["lnf_b"])
-            logits = lm_logits(xf, params["tok_emb"])
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            # per-slot finite-logits verdict (the NonFiniteGuard
-            # discipline applied to serving): ONE fused reduction over
-            # the logits the step already materialized, so slot health
-            # rides the same dispatch — a False row means this slot's
-            # numerics are poison and its emitted token must not be
-            # trusted (DecodeEngine quarantines the slot AND its
-            # private pages, purges its trie entries, and replays the
-            # request on a healthy slot)
-            ok = jnp.all(jnp.isfinite(logits), axis=-1)
+            with jax.named_scope("head"):
+                xf = layer_norm(x, params["lnf_g"], params["lnf_b"])
+                logits = lm_logits(xf, params["tok_emb"])
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                # per-slot finite-logits verdict (the NonFiniteGuard
+                # discipline applied to serving): ONE fused reduction
+                # over the logits the step already materialized, so
+                # slot health rides the same dispatch — a False row
+                # means this slot's numerics are poison and its emitted
+                # token must not be trusted (DecodeEngine quarantines
+                # the slot AND its private pages, purges its trie
+                # entries, and replays the request on a healthy slot)
+                ok = jnp.all(jnp.isfinite(logits), axis=-1)
             return pool, nxt, ok
 
         return jax.jit(decode_fn, donate_argnums=(1,))
@@ -291,8 +303,9 @@ class DecodeProgram:
         def chunk_fn(params, pool, tokens, start, cell_page, cell_off,
                      write_page):
             cache.record_trace(trace_key)
-            x = (params["tok_emb"][tokens]
-                 + params["pos_emb"][start + jnp.arange(t)])
+            with jax.named_scope("embed"):
+                x = (params["tok_emb"][tokens]
+                     + params["pos_emb"][start + jnp.arange(t)])
             cp = cell_page[None, :]
             co = cell_off[None, :]
             for li, lp in enumerate(params["layers"]):
@@ -306,11 +319,14 @@ class DecodeProgram:
                 # at earlier blocks' pages or scratch, and the
                 # advanced `offs` index lands [T, H, D] rows in the
                 # head-major page without an authored transpose.
-                q, k, v = decode_qkv(lp, x, n_heads)
-                pool = pool.at[li, 0, write_page, :, offs].set(k)
-                pool = pool.at[li, 1, write_page, :, offs].set(v)
-                kg = pool[li, 0][cp, hidx, co]      # [H, C, D]
-                vg = pool[li, 1][cp, hidx, co]
+                with jax.named_scope("qkv"):
+                    q, k, v = decode_qkv(lp, x, n_heads)
+                with jax.named_scope("kv_write"):
+                    pool = pool.at[li, 0, write_page, :, offs].set(k)
+                    pool = pool.at[li, 1, write_page, :, offs].set(v)
+                with jax.named_scope("kv_read"):
+                    kg = pool[li, 0][cp, hidx, co]      # [H, C, D]
+                    vg = pool[li, 1][cp, hidx, co]
                 x = block_chunk_prefill(lp, x, n_heads, kg, vg, start,
                                         qkv=(q, k, v))
             return pool
@@ -329,10 +345,11 @@ class DecodeProgram:
 
         def copy_fn(pool, src, dst):
             cache.record_trace(trace_key)
-            page = jax.lax.dynamic_slice(
-                pool, (0, 0, src, 0, 0, 0), shape)
-            return jax.lax.dynamic_update_slice(
-                pool, page, (0, 0, dst, 0, 0, 0))
+            with jax.named_scope("kv_copy"):
+                page = jax.lax.dynamic_slice(
+                    pool, (0, 0, src, 0, 0, 0), shape)
+                return jax.lax.dynamic_update_slice(
+                    pool, page, (0, 0, dst, 0, 0, 0))
 
         return jax.jit(copy_fn, donate_argnums=(0,))
 

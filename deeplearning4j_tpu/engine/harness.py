@@ -60,9 +60,10 @@ class StepHarness:
         self.supervisor = supervisor
         self.tracer = tracer
         self.acc = accumulator or _obs.StepAccumulator()
-        # opt-in phase attribution: True builds the default profiler;
-        # its emission rides THIS harness's accumulator so the phase
-        # histograms cost container appends, not registry locks
+        # opt-in phase attribution: True builds the default profiler
+        # (marks only, no device sync); its emission rides THIS
+        # harness's accumulator so the phase histograms cost container
+        # appends, not registry locks
         if phase_profiler is True:
             from deeplearning4j_tpu.observability.perf import (
                 StepPhaseProfiler,

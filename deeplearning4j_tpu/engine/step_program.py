@@ -70,9 +70,10 @@ def make_loss_and_apply(net, fused: bool = True):
 
     def _apply(items, lr, step):
         from deeplearning4j_tpu.nn.updater import fused_apply
-        if fused:
-            return fused_apply(items, lr, step)
-        return _unfused_apply(items, lr, step)
+        with jax.named_scope("updater"):
+            if fused:
+                return fused_apply(items, lr, step)
+            return _unfused_apply(items, lr, step)
 
     if is_graph:
         layer_names = [n.name for n in net.topo if n.kind == "layer"]

@@ -161,13 +161,18 @@ def block_chunk_prefill(lp: dict, x, n_heads: int, k_cells, v_cells,
     scatter-then-gather order that keeps the pool update in place);
     `k_cells`/`v_cells`/`n_prior` carry the prior context per
     `chunk_prefill_attention`."""
+    import jax
+
     if qkv is None:
-        h = layer_norm(x, lp["ln1_g"], lp["ln1_b"])
-        qkv = qkv_heads(lp, h, n_heads)
+        with jax.named_scope("qkv"):
+            qkv = decode_qkv(lp, x, n_heads)
     q, k, v = qkv
-    att = chunk_prefill_attention(q, k, v, k_cells, v_cells, n_prior)
-    x = x + _merge_heads(att) @ lp["wo"]
-    x = x + mlp_block(lp, layer_norm(x, lp["ln2_g"], lp["ln2_b"]))
+    with jax.named_scope("attn"):
+        att = chunk_prefill_attention(q, k, v, k_cells, v_cells,
+                                      n_prior)
+        x = x + _merge_heads(att) @ lp["wo"]
+    with jax.named_scope("mlp"):
+        x = x + mlp_block(lp, layer_norm(x, lp["ln2_g"], lp["ln2_b"]))
     return x
 
 
@@ -187,9 +192,13 @@ def block_decode_finish(lp: dict, x, q, k_cells, v_cells, live):
     against the gathered window cells [S, H, cells, Dh] (current
     position's K/V already written at cell live[s]-1) and run the
     residual + feed-forward tail. Returns x' [S, d_model]."""
-    att = paged_decode_attention(q, k_cells, v_cells, live)
-    x = x + _merge_heads(att) @ lp["wo"]
-    x = x + mlp_block(lp, layer_norm(x, lp["ln2_g"], lp["ln2_b"]))
+    import jax
+
+    with jax.named_scope("attn"):
+        att = paged_decode_attention(q, k_cells, v_cells, live)
+        x = x + _merge_heads(att) @ lp["wo"]
+    with jax.named_scope("mlp"):
+        x = x + mlp_block(lp, layer_norm(x, lp["ln2_g"], lp["ln2_b"]))
     return x
 
 

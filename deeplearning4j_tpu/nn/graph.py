@@ -23,9 +23,24 @@ from deeplearning4j_tpu.nn.conf.graph_conf import (
     ComputationGraphConfiguration,
     GraphNode,
 )
-from deeplearning4j_tpu.nn.conf.graph_vertices import LastTimeStepVertex
+from deeplearning4j_tpu.nn.conf.graph_vertices import (
+    ElementWiseVertex,
+    LastTimeStepVertex,
+)
 from deeplearning4j_tpu.nn.jit_cache import JitCache, policy_name
-from deeplearning4j_tpu.nn.layers.core import BaseOutputLayer
+from deeplearning4j_tpu.nn.layers.conv import (
+    Convolution1DLayer,
+    ConvolutionLayer,
+    Subsampling1DLayer,
+    SubsamplingLayer,
+)
+from deeplearning4j_tpu.nn.layers.core import (
+    ActivationLayer,
+    BaseOutputLayer,
+    DenseLayer,
+    GlobalPoolingLayer,
+)
+from deeplearning4j_tpu.nn.layers.norm import BatchNormalization
 from deeplearning4j_tpu.nn.layers.recurrent import (
     LSTM,
     GravesBidirectionalLSTM,
@@ -53,6 +68,29 @@ def _as_multi(data) -> Tuple[List, List, Optional[List], Optional[List]]:
                              else [v]) if v is not None else None
         return as_list(x), as_list(y), as_list(fm), as_list(lm)
     return [data], None, None, None
+
+
+_SCOPE_KINDS = (
+    ("conv", (ConvolutionLayer, Convolution1DLayer)),
+    ("dense", (DenseLayer, BaseOutputLayer)),
+    ("bn", (BatchNormalization,)),
+    ("act", (ActivationLayer,)),
+    ("pool", (SubsamplingLayer, Subsampling1DLayer, GlobalPoolingLayer)),
+)
+
+
+def node_scope(node: GraphNode) -> str:
+    """`<kind>/<vertex name>`: the `jax.named_scope` a vertex's
+    operations run under, forward and (through `transpose(jvp(...))`)
+    backward. The kind says what the work is (conv, dense, bn, act,
+    add, pool, other), so that a device trace sums by kind whatever
+    fusions the compiler makes (benchmark/timeline.py)."""
+    if isinstance(node.obj, ElementWiseVertex) and node.obj.op == "add":
+        return f"add/{node.name}"
+    for kind, classes in _SCOPE_KINDS:
+        if isinstance(node.obj, classes):
+            return f"{kind}/{node.name}"
+    return f"other/{node.name}"
 
 
 class ComputationGraph:
@@ -230,38 +268,40 @@ class ComputationGraph:
         """Execute ONE node with resolved inputs, writing its activation,
         mask, state, and carry. Shared by the default loop above and the
         fused executor's fallback branch (nn/helpers/fused_graph.py)."""
-        if node.kind == "layer":
-            x = xs[0]
-            m = in_masks[0]
-            if node.preprocessor is not None:
-                x = node.preprocessor.preprocess(x)
-                m = node.preprocessor.feed_forward_mask(m, None)
-            layer = node.obj
-            is_rnn = isinstance(layer, (LSTM, GravesBidirectionalLSTM))
-            if is_rnn:
-                carry = (None if rnn_carries is None
-                         else rnn_carries.get(node.name))
-                out, nc = layer.apply(params[node.name], x, train=train,
-                                      rng=rng_i, state=carry, mask=m)
-                new_carries[node.name] = nc
-                new_states[node.name] = states[node.name]
+        with jax.named_scope(node_scope(node)):
+            if node.kind == "layer":
+                x = xs[0]
+                m = in_masks[0]
+                if node.preprocessor is not None:
+                    x = node.preprocessor.preprocess(x)
+                    m = node.preprocessor.feed_forward_mask(m, None)
+                layer = node.obj
+                if isinstance(layer, (LSTM, GravesBidirectionalLSTM)):
+                    carry = (None if rnn_carries is None
+                             else rnn_carries.get(node.name))
+                    out, nc = layer.apply(
+                        params[node.name], x, train=train, rng=rng_i,
+                        state=carry, mask=m)
+                    new_carries[node.name] = nc
+                    new_states[node.name] = states[node.name]
+                else:
+                    st = states[node.name] if states[node.name] else None
+                    out, ns = layer.apply(
+                        params[node.name], x, train=train, rng=rng_i,
+                        state=st, mask=m)
+                    new_states[node.name] = (ns if ns is not None
+                                             else states[node.name])
+                acts[node.name] = out
+                masks[node.name] = layer.feed_forward_mask(m, None)
             else:
-                st = states[node.name] if states[node.name] else None
-                out, ns = layer.apply(params[node.name], x, train=train,
-                                      rng=rng_i, state=st, mask=m)
-                new_states[node.name] = (ns if ns is not None
-                                         else states[node.name])
-            acts[node.name] = out
-            masks[node.name] = layer.feed_forward_mask(m, None)
-        else:
-            v = node.obj
-            if isinstance(v, LastTimeStepVertex):
-                m = (masks.get(v.mask_input)
-                     if v.mask_input else in_masks[0])
-                acts[node.name] = v.apply(xs, mask=m)
-            else:
-                acts[node.name] = v.apply(xs)
-            masks[node.name] = v.feed_forward_mask(in_masks, None)
+                v = node.obj
+                if isinstance(v, LastTimeStepVertex):
+                    m = (masks.get(v.mask_input)
+                         if v.mask_input else in_masks[0])
+                    acts[node.name] = v.apply(xs, mask=m)
+                else:
+                    acts[node.name] = v.apply(xs)
+                masks[node.name] = v.feed_forward_mask(in_masks, None)
 
     # ------------------------------------------------------------------ loss
     def _output_layer_nodes(self) -> List[GraphNode]:
@@ -284,35 +324,40 @@ class ComputationGraph:
         acts, new_states, new_carries = self._forward(
             params, states, inputs, train=train, rng=rng,
             input_masks=input_masks, rnn_carries=rnn_carries)
-        total = 0.0
-        for oi, node in enumerate(out_nodes):
-            # recompute the output layer's per-example loss from its input
-            src = node.inputs[0]
-            x = acts[src]
-            if node.preprocessor is not None:
-                x = node.preprocessor.preprocess(x)
-            layer = node.obj
-            if rng is not None:
-                x = layer._maybe_dropout_input(
-                    x, train, jax.random.fold_in(rng, 0x0D0 + oi))
-            y = labels[oi]
-            lm = None if label_masks is None else label_masks[oi]
-            per_ex = layer.per_example_loss_from_input(
-                params[node.name], x, y, mask=lm)
-            if lm is not None:
-                active = lm if lm.ndim == 1 else jnp.any(lm > 0, axis=1)
-                s = jnp.sum(per_ex)
-                total = total + (s / jnp.maximum(jnp.sum(active), 1.0)
-                                 if conf.minibatch else s)
-            elif conf.minibatch:
-                total = total + jnp.mean(per_ex)
-            else:
-                total = total + jnp.sum(per_ex)
-        reg = 0.0
-        for node in self.topo:
-            if node.kind == "layer":
-                reg = reg + node.obj.regularization_loss(params[node.name])
-        return total + reg, (new_states, new_carries)
+        with jax.named_scope("loss"):
+            total = 0.0
+            for oi, node in enumerate(out_nodes):
+                # recompute the output layer's per-example loss from its
+                # input
+                src = node.inputs[0]
+                x = acts[src]
+                if node.preprocessor is not None:
+                    x = node.preprocessor.preprocess(x)
+                layer = node.obj
+                if rng is not None:
+                    x = layer._maybe_dropout_input(
+                        x, train, jax.random.fold_in(rng, 0x0D0 + oi))
+                y = labels[oi]
+                lm = None if label_masks is None else label_masks[oi]
+                per_ex = layer.per_example_loss_from_input(
+                    params[node.name], x, y, mask=lm)
+                if lm is not None:
+                    active = (lm if lm.ndim == 1
+                              else jnp.any(lm > 0, axis=1))
+                    s = jnp.sum(per_ex)
+                    total = total + (s / jnp.maximum(jnp.sum(active), 1.0)
+                                     if conf.minibatch else s)
+                elif conf.minibatch:
+                    total = total + jnp.mean(per_ex)
+                else:
+                    total = total + jnp.sum(per_ex)
+            reg = 0.0
+            for node in self.topo:
+                if node.kind == "layer":
+                    reg = reg + node.obj.regularization_loss(
+                        params[node.name])
+            total = total + reg
+        return total, (new_states, new_carries)
 
     # ------------------------------------------------------------ train step
     def _clip_grads(self, grads):
@@ -356,14 +401,15 @@ class ComputationGraph:
                 loss_for_grad, has_aux=True)(
                     params, states, inputs, labels, rng, fmasks, lmasks,
                     carries if with_carries else None)
-            grads = self._clip_grads(grads)
-            lr = schedule_lr(conf, step) * lr_scale
             frozen = {n.name for n in self.topo
                       if n.kind == "layer" and n.obj.frozen}
-            np_list, nu_list = fused_apply(
-                [(updaters[name], lr_factors[name], name in frozen,
-                  params[name], grads[name], upd_states[name])
-                 for name in layer_names], lr, step)
+            with jax.named_scope("updater"):
+                grads = self._clip_grads(grads)
+                lr = schedule_lr(conf, step) * lr_scale
+                np_list, nu_list = fused_apply(
+                    [(updaters[name], lr_factors[name], name in frozen,
+                      params[name], grads[name], upd_states[name])
+                     for name in layer_names], lr, step)
             new_params = dict(zip(layer_names, np_list))
             new_upd = dict(zip(layer_names, nu_list))
             return new_params, new_upd, new_states, new_carries, loss
@@ -407,10 +453,13 @@ class ComputationGraph:
                 loss_for_grad, has_aux=True)(
                     flat, states, inputs, labels, rng, fmasks, lmasks,
                     carries if with_carries else None)
-            g = self._clip_grads(g)
-            lr = schedule_lr(conf, step) * lr_scale
-            deltas, new_u = chain.updater.update(g, uflat, flat, lr, step)
-            return flat + deltas, new_u, new_states, new_carries, loss
+            with jax.named_scope("updater"):
+                g = self._clip_grads(g)
+                lr = schedule_lr(conf, step) * lr_scale
+                deltas, new_u = chain.updater.update(g, uflat, flat, lr,
+                                                     step)
+                new_flat = flat + deltas
+            return new_flat, new_u, new_states, new_carries, loss
 
         return jax.jit(step_fn, donate_argnums=(
             (0, 1, 2, 9) if with_carries else (0, 1, 2)))

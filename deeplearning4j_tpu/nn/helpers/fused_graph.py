@@ -166,6 +166,8 @@ def fused_forward(net, params, states, inputs, *, train, rng,
     plan is active. Non-planned nodes execute through the SAME node
     executor as the default path (ComputationGraph._exec_node) —
     including masks, preprocessors, and RNN carries."""
+    from deeplearning4j_tpu.nn.graph import node_scope
+
     plan: Plan = net._fusion_plan
     topo = net.topo
     by_name = {n.name: n for n in topo}
@@ -182,7 +184,8 @@ def fused_forward(net, params, states, inputs, *, train, rng,
     def resolve(name):
         """Materialized tensor for a node (cached)."""
         if name not in acts:
-            acts[name] = _materialize(virts[name])
+            with jax.named_scope(node_scope(by_name[name])):
+                acts[name] = _materialize(virts[name])
         return acts[name]
 
     def expr_of(name) -> _Expr:
@@ -210,9 +213,15 @@ def fused_forward(net, params, states, inputs, *, train, rng,
             bn_layer = by_name[spec.bn_name].obj
             stats_k = (max(1, int(getattr(bn_layer, "stat_sample", 1)))
                        if train else 0)
-            y, ssum, ssq, u = fused_conv(
-                x, p["W"], p["b"], s1, t1, x2, s2, t2,
-                spec.stride, spec.padding, e.relu, stats_k, plan.impl)
+            # the producers' deferred batch-norm apply, relu and add
+            # run as this convolution's prologue and the consumer's
+            # statistics as its epilogue: inside this scope, under
+            # `bn/apply` and `bn/stats` (fused_ops.py)
+            with jax.named_scope(node_scope(node)):
+                y, ssum, ssq, u = fused_conv(
+                    x, p["W"], p["b"], s1, t1, x2, s2, t2,
+                    spec.stride, spec.padding, e.relu, stats_k,
+                    plan.impl)
             raws[name] = y
             stats[name] = (ssum, ssq)
             if src not in acts and (e.relu or len(e.terms) > 1
@@ -227,29 +236,31 @@ def fused_forward(net, params, states, inputs, *, train, rng,
             gamma = params[name]["gamma"]
             beta = params[name]["beta"]
             st = states[name]
-            if train:
-                ssum, ssq = stats[conv_src]
-                raw = raws[conv_src]
-                k = int(getattr(layer, "stat_sample", 1))
-                nb = (raw.shape[0] - 1) // max(k, 1) + 1  # sampled rows
-                count = nb * raw.shape[1] * raw.shape[2]
-                scale, shift, mean, var = bn_affine(
-                    gamma, beta, ssum, ssq, count, layer.eps)
-                if st is not None:
-                    d = layer.decay
-                    sd = st["mean"].dtype
-                    new_states[name] = {
-                        "mean": d * st["mean"] + (1.0 - d)
-                        * jax.lax.stop_gradient(mean).astype(sd),
-                        "var": d * st["var"] + (1.0 - d)
-                        * jax.lax.stop_gradient(var).astype(sd),
-                    }
+            with jax.named_scope(node_scope(node)):
+                if train:
+                    ssum, ssq = stats[conv_src]
+                    raw = raws[conv_src]
+                    k = int(getattr(layer, "stat_sample", 1))
+                    # sampled rows
+                    nb = (raw.shape[0] - 1) // max(k, 1) + 1
+                    count = nb * raw.shape[1] * raw.shape[2]
+                    scale, shift, mean, var = bn_affine(
+                        gamma, beta, ssum, ssq, count, layer.eps)
+                    if st is not None:
+                        d = layer.decay
+                        sd = st["mean"].dtype
+                        new_states[name] = {
+                            "mean": d * st["mean"] + (1.0 - d)
+                            * jax.lax.stop_gradient(mean).astype(sd),
+                            "var": d * st["var"] + (1.0 - d)
+                            * jax.lax.stop_gradient(var).astype(sd),
+                        }
+                    else:
+                        new_states[name] = st
                 else:
+                    scale, shift = bn_affine_inference(
+                        gamma, beta, st["mean"], st["var"], layer.eps)
                     new_states[name] = st
-            else:
-                scale, shift = bn_affine_inference(
-                    gamma, beta, st["mean"], st["var"], layer.eps)
-                new_states[name] = st
             virts[name] = _Expr([(raws[conv_src], scale, shift)])
             masks[name] = in_mask
             continue

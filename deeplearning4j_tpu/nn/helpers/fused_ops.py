@@ -52,17 +52,30 @@ def _conv(u, w, stride, padding):
         dimension_numbers=_DIMS_NHWC)
 
 
+# Inside a fused convolution's `conv/<vertex>` scope (fused_graph.py)
+# the batch-norm work it carries has scopes of its own, so that a device
+# trace can tell it from the convolution: `bn/apply` for the producers'
+# deferred scale-and-shift, add and relu (forward and backward),
+# `bn/stats` for the consumer's channel statistics, and
+# `other/bias_grad` for the bias's gradient, a reduction over the whole
+# output cotangent that XLA fuses with the statistics' backward. The
+# innermost scope is the one a reader counts (benchmark/timeline.py).
+_BN_APPLY, _BN_STATS, _BIAS_GRAD = "bn/apply", "bn/stats", "other/bias_grad"
+
+
 def _prologue(x, scale, shift, x2, scale2, shift2, relu):
-    u = x
-    if scale is not None:
-        u = u * scale.astype(x.dtype) + shift.astype(x.dtype)
-    if x2 is not None:
-        if scale2 is not None:
-            u = u + (x2 * scale2.astype(x.dtype) + shift2.astype(x.dtype))
-        else:
-            u = u + x2
-    if relu:
-        u = jnp.maximum(u, 0)
+    with jax.named_scope(_BN_APPLY):
+        u = x
+        if scale is not None:
+            u = u * scale.astype(x.dtype) + shift.astype(x.dtype)
+        if x2 is not None:
+            if scale2 is not None:
+                u = u + (x2 * scale2.astype(x.dtype)
+                         + shift2.astype(x.dtype))
+            else:
+                u = u + x2
+        if relu:
+            u = jnp.maximum(u, 0)
     return u
 
 
@@ -100,10 +113,11 @@ def _fwd_impl(x, w, b, scale, shift, x2, scale2, shift2,
     if b is not None:
         y = y + b.astype(y.dtype)
     if with_stats:
-        ys = _stat_rows(y, int(with_stats))
-        yf = ys.astype(jnp.float32)
-        ssum = jnp.sum(yf, axis=(0, 1, 2))
-        ssq = jnp.sum(yf * yf, axis=(0, 1, 2))
+        with jax.named_scope(_BN_STATS):
+            ys = _stat_rows(y, int(with_stats))
+            yf = ys.astype(jnp.float32)
+            ssum = jnp.sum(yf, axis=(0, 1, 2))
+            ssq = jnp.sum(yf * yf, axis=(0, 1, 2))
     else:
         n = y.shape[-1]
         ssum = jnp.zeros((n,), jnp.float32)
@@ -150,44 +164,47 @@ def _fused_conv_bwd(stride, padding, relu, with_stats, impl, res, cts):
     # correction without re-reading the full y.
     ybar = dy
     if with_stats:
-        k = int(with_stats)
-        if k <= 1:
-            ybar = (ybar.astype(jnp.float32) + dssum
-                    + 2.0 * y.astype(jnp.float32) * dssq).astype(dtype)
-        else:
-            ys = _stat_rows(y, k)
-            corr = (dssum + 2.0 * ys.astype(jnp.float32) * dssq
-                    ).astype(dtype)
-            hi = y.shape[0] - ys.shape[0]
-            pad_cfg = [(0, hi, 0)] + [(0, 0, 0)] * (y.ndim - 1)
-            ybar = ybar + lax.pad(corr, jnp.zeros((), dtype), pad_cfg)
+        with jax.named_scope(_BN_STATS):
+            k = int(with_stats)
+            if k <= 1:
+                ybar = (ybar.astype(jnp.float32) + dssum
+                        + 2.0 * y.astype(jnp.float32) * dssq).astype(dtype)
+            else:
+                ys = _stat_rows(y, k)
+                corr = (dssum + 2.0 * ys.astype(jnp.float32) * dssq
+                        ).astype(dtype)
+                hi = y.shape[0] - ys.shape[0]
+                pad_cfg = [(0, hi, 0)] + [(0, 0, 0)] * (y.ndim - 1)
+                ybar = ybar + lax.pad(corr, jnp.zeros((), dtype), pad_cfg)
 
     # recompute u (never materialized in fwd residuals)
     u = _prologue(x, scale, shift, x2, scale2, shift2, relu)
-    db = (jnp.sum(ybar.astype(jnp.float32), axis=(0, 1, 2))
-          if b is not None else None)
+    with jax.named_scope(_BIAS_GRAD):
+        db = (jnp.sum(ybar.astype(jnp.float32), axis=(0, 1, 2))
+              if b is not None else None)
 
     du = jax.vjp(lambda uu: _conv(uu, w, stride, padding), u)[1](ybar)[0]
     dw = jax.vjp(lambda ww: _conv(u, ww, stride, padding), w)[1](ybar)[0]
 
-    if du_out is not None:
-        du = du + du_out.astype(du.dtype)
-    if relu:
-        du = du * (u > 0).astype(dtype)
+    with jax.named_scope(_BN_APPLY):
+        if du_out is not None:
+            du = du + du_out.astype(du.dtype)
+        if relu:
+            du = du * (u > 0).astype(dtype)
 
-    def branch_grads(xb, sb):
-        if sb is None:
-            return du, None, None
-        ds = jnp.sum(xb.astype(jnp.float32) * du.astype(jnp.float32),
-                     axis=(0, 1, 2))
-        dt = jnp.sum(du.astype(jnp.float32), axis=(0, 1, 2))
-        return du * sb.astype(dtype), ds, dt
+        def branch_grads(xb, sb):
+            if sb is None:
+                return du, None, None
+            ds = jnp.sum(xb.astype(jnp.float32) * du.astype(jnp.float32),
+                         axis=(0, 1, 2))
+            dt = jnp.sum(du.astype(jnp.float32), axis=(0, 1, 2))
+            return du * sb.astype(dtype), ds, dt
 
-    dx, dscale, dshift = branch_grads(x, scale)
-    if x2 is not None:
-        dx2, dscale2, dshift2 = branch_grads(x2, scale2)
-    else:
-        dx2 = dscale2 = dshift2 = None
+        dx, dscale, dshift = branch_grads(x, scale)
+        if x2 is not None:
+            dx2, dscale2, dshift2 = branch_grads(x2, scale2)
+        else:
+            dx2 = dscale2 = dshift2 = None
     return dx, dw, db, dscale, dshift, dx2, dscale2, dshift2
 
 

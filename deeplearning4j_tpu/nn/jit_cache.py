@@ -55,12 +55,25 @@ def place_compile_cache() -> str:
     program places the cache. Otherwise `<checkout>/.jax_cache`
     (git-ignored). The path is part of every entry's key, so it is
     fixed: never built from a temp name, a pid, a version or a time.
+    An entry's key takes in the program's metadata: the names that
+    `jax.named_scope` gives the operations are what a device trace is
+    summed by (benchmark/timeline.py), and jax leaves them out of the
+    key by default, so that an executable cached before a scope was
+    added or renamed would come back with its old names. An operation's
+    location is then cut to its own source line, without its callers'
+    (one program reached from two call sites would be two entries).
+    Not by `jax_include_full_tracebacks_in_locations=False`: that takes
+    the scopes out of the compiled operations' names (measured on the
+    chip, PERF.md PR 26).
     Returns the directory in use."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

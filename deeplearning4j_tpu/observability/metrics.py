@@ -103,7 +103,6 @@ REGISTERED_METRICS = frozenset({
     "dl4j_decode_active_slots",
     "dl4j_decode_tokens_total",
     "dl4j_decode_tokens_per_s",
-    "dl4j_decode_prefill_seconds",
     "dl4j_decode_slot_evictions_total",
     # paged KV virtual memory (prefix trie / chunked prefill / ring wrap)
     "dl4j_decode_prefix_hits_total",

@@ -17,14 +17,19 @@ all riding the PR 5 telemetry substrate:
                        2004.13336) work both lean on to decide WHERE
                        to optimize. `perf_report()` lands the numbers
                        as registry gauges and a dict.
-  StepPhaseProfiler    decomposes every training step into named
-                       phases (data_wait / h2d / dispatch /
-                       device_compute / host_sync / checkpoint /
-                       telemetry) from perf_counter marks the fit
-                       loops already pay for; emits
+  StepPhaseProfiler    the one step-phase recorder of both planes:
+                       decomposes every step into named phases from
+                       perf_counter marks. A fit loop's phases
+                       (data_wait / h2d / dispatch / device_compute /
+                       host_sync / checkpoint / telemetry) land as
                        `dl4j_train_phase_seconds{phase=...}` through
-                       the owning loop's StepAccumulator so the
-                       overhead stays under the PR 5 <2% bar.
+                       the loop's StepAccumulator; `DecodeEngine`
+                       always owns one for `step_once` (no registry
+                       write, totals in `stats()["phases"]`). Every
+                       step also leaves one record in the process's
+                       step timeline (`get_timeline()`), which
+                       `observability.tracing.clock_offset` lays over
+                       a device trace.
   recompile forensics  lives in nn/jit_cache.py (signature + duration
                        ring per new trace, `dl4j_jit_compiles_total`);
                        `CostModel.register_jit_entry` attaches cost
@@ -48,6 +53,7 @@ from __future__ import annotations
 import json
 import os
 import time
+from collections import defaultdict, deque
 from typing import Dict, List, Optional, Tuple
 
 from deeplearning4j_tpu.observability import metrics as _obs
@@ -318,43 +324,98 @@ PHASES = ("data_wait", "h2d", "dispatch", "device_compute",
 _PHASE_KEYS = {p: ("dl4j_train_phase_seconds", (("phase", p),))
                for p in PHASES}
 
+# The process's step timeline: the last steps of every profiler, and
+# the engine's request records, in the order they ended. Held at module
+# level (as `metrics.get_registry()` holds the registry) so that a
+# reader reaches it after the engine or the fit loop that wrote it is
+# gone. Two kinds of record, both plain tuples of perf_counter seconds:
+#   (owner, step, t_begin, marks, t_end)   marks: [(phase, t_start), ..]
+#   (owner, "request", step at submit, t_submit, t_placed, t_first_token)
+TIMELINE_CAPACITY = 4096
+_TIMELINE: deque = deque(maxlen=TIMELINE_CAPACITY)
+# both host clocks read back to back, once: what converts a record's
+# perf_counter seconds to the Unix time a device trace's
+# `profile_start_time` is in
+CLOCK_ANCHOR = (time.perf_counter_ns(), time.time_ns())
+
+
+def get_timeline() -> deque:
+    """The bounded ring of step and request records (newest last)."""
+    return _TIMELINE
+
+
+def perf_to_unix_ns(t_perf_s: float) -> int:
+    """A record's perf_counter seconds as Unix nanoseconds."""
+    return int(t_perf_s * 1e9) - CLOCK_ANCHOR[0] + CLOCK_ANCHOR[1]
+
+
+def phase_spans(marks, t_end: float):
+    """[(phase, start, end)] of one step's marks: a phase runs from its
+    mark to the next mark, the last to the step's end."""
+    return [(ph, t, marks[i + 1][1] if i + 1 < len(marks) else t_end)
+            for i, (ph, t) in enumerate(marks)]
+
+
+def record_request(owner: str, step_at_submit: int, t_submit: float,
+                   t_placed: float, t_first_token: float) -> None:
+    """One record per request, written at its first token."""
+    _TIMELINE.append((owner, "request", step_at_submit, t_submit,
+                      t_placed, t_first_token))
+
 
 class StepPhaseProfiler:
-    """Attribute every training step's wall time to named phases.
+    """Attribute every step's wall time to named phases.
 
-    The owning fit loop calls `begin_step()` once per step, `mark(p)`
-    at each phase boundary (phase p runs from its mark to the next
-    mark), optionally `sync(device_value)` right after dispatch — when
-    this step samples a device sync (`sync_every`), the blocked
+    The owning loop calls `begin_step()` once per step, `mark(p)` at
+    each phase boundary (phase p runs from its mark to the next mark),
+    optionally `sync(device_value)` right after dispatch — when this
+    step samples a device sync (`sync_every` > 0), the blocked
     `block_until_ready` interval becomes the device_compute phase —
-    and `end_step()` in its finally. Durations land as
+    and `end_step()` in its finally. Cumulative totals stay on the
+    instance for `report()`, every step leaves one record in the
+    process's timeline (`get_timeline()`), and with a tracer attached
+    each phase records a `phase:<name>` span on the shared timeline.
+    With `emit_metrics` (a fit loop's profiler) durations also land as
     `dl4j_train_phase_seconds{phase=...}` through the loop's
     StepAccumulator (container appends per step, one guarded registry
-    write per flush — the PR 5 <2% discipline), cumulative totals stay
-    on the instance for `report()`, and with a tracer attached each
-    phase records a span on the shared timeline.
+    write per flush — the PR 5 <2% discipline); the decode engine's
+    profiler writes nothing to the registry.
 
     NOT thread-safe — one owner loop per instance, like the
     accumulator it feeds."""
 
     def __init__(self, accumulator=None, tracer=None,
-                 sync_every: int = 1):
+                 sync_every: int = 0, owner: str = "train",
+                 emit_metrics: bool = True):
         self.accumulator = accumulator
         self.tracer = tracer
-        # sync_every=N blocks on the device value every Nth step (0 =
-        # never): device_compute becomes visible at 1/N the host-sync
-        # cost; un-synced steps leave device time inside dispatch.
+        # sync_every=N blocks on the device value every Nth step:
+        # device_compute becomes visible at 1/N the host-sync cost;
+        # un-synced steps leave device time inside dispatch. 0 (the
+        # default) never syncs: a sync a step serializes host and
+        # device (125 ms a ResNet50 step for 107.5, PERF.md)
         self.sync_every = max(0, int(sync_every))
-        self.totals: Dict[str, float] = {p: 0.0 for p in PHASES}
+        self.owner = owner
+        self.emit_metrics = emit_metrics
+        self.totals: Dict[str, float] = defaultdict(
+            float, {p: 0.0 for p in PHASES} if emit_metrics else {})
         self.wall_s = 0.0
         self.steps = 0
         self._marks: List[Tuple[str, float]] = []
         self._t_begin: Optional[float] = None
+        self._t_last_end: Optional[float] = None
         self._step = None
 
-    def begin_step(self, step=None) -> None:
-        self._t_begin = time.perf_counter()
-        self._marks = []
+    def begin_step(self, step=None, since_last: str = "") -> None:
+        """A step starts now. With `since_last`, it starts where this
+        profiler's last step ended, and the time since is the phase of
+        that name (the engine's `between_steps`: the caller's turn)."""
+        if since_last and self._t_last_end is not None:
+            self._t_begin = self._t_last_end
+            self._marks = [(since_last, self._t_last_end)]
+        else:
+            self._t_begin = time.perf_counter()
+            self._marks = []
         self._step = step
 
     def mark(self, phase: str) -> None:
@@ -381,38 +442,55 @@ class StepPhaseProfiler:
         except Exception:   # noqa: BLE001 - profiling is best-effort
             pass
 
-    def end_step(self) -> None:
+    def end_step(self, step=None) -> None:
+        """The step ends now. `step` names it where `begin_step` could
+        not yet (the engine counts a step only once it has run)."""
         if self._t_begin is None:
             return
         t_end = time.perf_counter()
         marks = self._marks
+        if step is None:
+            step = self._step
+        totals = self.totals
+        if marks:
+            each = iter(marks)
+            prev, t_prev = next(each)
+            for ph, t in each:
+                totals[prev] += t - t_prev
+                prev, t_prev = ph, t
+            totals[prev] += t_end - t_prev
+        _TIMELINE.append((self.owner, step, self._t_begin, marks, t_end))
+        if self.emit_metrics:
+            self._emit(marks, t_end)
+        tr = self.tracer
+        if tr is not None:
+            for ph, t, t_next in phase_spans(marks, t_end):
+                tr.record(f"phase:{ph}", t, t_next, cat="phase",
+                          args={"step": step})
+        # the profiler's own emission cost is telemetry time too —
+        # attribute it so coverage stays honest, not flattering
+        t_done = time.perf_counter()
+        totals["telemetry"] += t_done - t_end
+        self.wall_s += t_done - self._t_begin
+        self.steps += 1
+        self._t_begin = None
+        self._t_last_end = t_done
+        self._marks = []
+
+    def _emit(self, marks, t_end: float) -> None:
+        """One observation per phase of the step (a phase marked twice
+        counts once, with both intervals)."""
         durs: Dict[str, float] = {}
-        for i, (ph, t) in enumerate(marks):
-            t_next = marks[i + 1][1] if i + 1 < len(marks) else t_end
+        for ph, t, t_next in phase_spans(marks, t_end):
             durs[ph] = durs.get(ph, 0.0) + max(0.0, t_next - t)
         acc = self.accumulator
-        tr = self.tracer
         for ph, d in durs.items():
-            self.totals[ph] = self.totals.get(ph, 0.0) + d
             key = _PHASE_KEYS.get(ph)
             if acc is not None and key is not None:
                 acc.observe_keyed(key, d)
             else:
                 _obs.observe("dl4j_train_phase_seconds", d,
                              labels={"phase": ph})
-        if tr is not None:
-            for i, (ph, t) in enumerate(marks):
-                t_next = marks[i + 1][1] if i + 1 < len(marks) else t_end
-                tr.record(f"phase:{ph}", t, t_next, cat="phase",
-                          args={"step": self._step})
-        # the profiler's own emission cost is telemetry time too —
-        # attribute it so coverage stays honest, not flattering
-        t_done = time.perf_counter()
-        self.totals["telemetry"] += t_done - t_end
-        self.wall_s += t_done - self._t_begin
-        self.steps += 1
-        self._t_begin = None
-        self._marks = []
 
     def report(self) -> dict:
         """Cumulative per-phase seconds + shares and the coverage
@@ -524,7 +602,8 @@ def aggregate_prometheus_text(sources) -> str:
 
 __all__ = [
     "PEAKS", "PHASES",
-    "CostModel", "StepPhaseProfiler",
+    "CostModel", "StepPhaseProfiler", "TIMELINE_CAPACITY",
+    "get_timeline", "perf_to_unix_ns", "phase_spans", "record_request",
     "device_peaks", "extract_cost",
     "matmul_flops", "conv2d_flops", "train_step_flops_from_params",
     "dump_snapshot", "aggregate_snapshots", "aggregate_prometheus_text",

@@ -18,7 +18,10 @@ tracks, thread-name metadata, and "s"/"f" flow events binding every
 cross-thread parent→child edge so the handoff renders as an arrow, not
 a coincidence. A `jax.profiler` device trace captured in the same run
 (ProfilerListener) is registered on this timeline as a span carrying
-its trace_dir, so host spans and the device profile can be correlated.
+its trace_dir. The device trace runs on a clock of its own:
+`clock_offset()` measures what lies between it and this one from the
+step phases both saw, and `phases_over()` then puts each idle gap of
+the device down to the host phases that overlap it.
 
 Continuous export: `start_background_flush(path, interval_s)` runs a
 daemon thread that periodically DRAINS the ring buffer to a JSONL file
@@ -53,6 +56,8 @@ import uuid
 from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
+
+from deeplearning4j_tpu.observability.perf import phase_spans
 
 
 def new_trace_id() -> str:
@@ -452,3 +457,72 @@ def merge_chrome_traces(docs, path: Optional[str] = None,
         with open(path, "w") as f:
             json.dump(doc, f)
     return doc
+
+
+# --------------------------------------------- one clock with the device
+def _phase_ends(source, phase: str) -> List[float]:
+    """When each `phase` ended, in perf_counter nanoseconds and in
+    order: from step records of `observability.perf.get_timeline()`
+    (`(owner, step, t_begin, marks, t_end)`), or from a Tracer's
+    `phase:<name>` spans."""
+    if isinstance(source, Tracer):
+        ends = [(source._t0 + (s["t0_us"] + s["dur_us"]) * 1e-6) * 1e9
+                for s in source.spans()
+                if s["name"] == f"phase:{phase}"]
+        return sorted(ends)
+    return [t1 * 1e9 for _, _, _, marks, t_end in source
+            for name, _, t1 in phase_spans(marks, t_end) if name == phase]
+
+
+def clock_offset(source, executions, phase: str = "fetch") -> dict:
+    """The measurement by which host spans and a device profile
+    can be correlated: the offset between the host's perf_counter
+    clock and the clock of a `jax.profiler` device trace.
+
+    `source`: the step records of the traced slice (or the Tracer that
+    holds their `phase:<name>` spans); `executions`: `(start_ns,
+    end_ns)` of the program's executions on the trace's `XLA Modules`
+    line, in order; `phase`: the host phase that ends when an
+    execution does (the engine's `fetch`, which blocks on the decode
+    step's outputs; training's `host_sync` where the loop fetches a
+    value every step). The k-th phase end is anchored to the end of
+    the k-th execution, counted from the slice's end (the profiler's
+    start may cut the first); the offset is the median of host end
+    less device end, so it takes in the runtime's way from the
+    device's completion to the host's return. `spread_ns` is the
+    widest less the narrowest difference over the slice: under half a
+    millisecond the two timelines are one, wider and a reader must say
+    so in place of a join it cannot stand behind.
+
+    Returns {"offset_ns", "spread_ns", "n"}; `n` 0 where either side
+    is empty. host_ns = device_ns + offset_ns."""
+    ends = _phase_ends(source, phase)
+    n = min(len(ends), len(executions))
+    if not n:
+        return {"offset_ns": 0.0, "spread_ns": float("inf"), "n": 0}
+    diffs = sorted(h - d[1] for h, d in zip(ends[-n:], executions[-n:]))
+    mid = diffs[n // 2] if n % 2 else (diffs[n // 2 - 1]
+                                        + diffs[n // 2]) / 2.0
+    return {"offset_ns": mid, "spread_ns": diffs[-1] - diffs[0], "n": n}
+
+
+def phases_over(records, intervals, offset_ns: float) -> Dict[str, float]:
+    """Put device-clock `intervals` (`(start_ns, end_ns)`: the idle
+    gaps of a traced slice) down to the host phases that overlap them,
+    after `clock_offset`. Returns seconds by phase name; what no
+    record covers is under `"(no record)"`."""
+    # (start_ns, end_ns, phase) on the host's clock
+    spans = [(t0 * 1e9, t1 * 1e9, name) for _, _, _, marks, t_end in records
+             for name, t0, t1 in phase_spans(marks, t_end)]
+    out: Dict[str, float] = {}
+    for lo, hi in intervals:
+        lo, hi = lo + offset_ns, hi + offset_ns
+        left = hi - lo
+        for s0, s1, name in spans:
+            over = min(hi, s1) - max(lo, s0)
+            if over > 0:
+                out[name] = out.get(name, 0.0) + over * 1e-9
+                left -= over
+        if left > 1.0:
+            out["(no record)"] = out.get("(no record)", 0.0) + left * 1e-9
+    return out

@@ -119,6 +119,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.observability import metrics as _obs
+from deeplearning4j_tpu.observability.perf import (
+    StepPhaseProfiler,
+    record_request,
+)
 from deeplearning4j_tpu.resilience.errors import (
     FaultInjectedError,
     GenerationPoisonedError,
@@ -191,6 +195,9 @@ class GenerationHandle:
         self.t_placed: Optional[float] = None
         self.t_first_token: Optional[float] = None
         self.t_last_token: Optional[float] = None
+        # the engine's step count when the request was submitted (set
+        # by `submit`): what places its timeline record among the steps
+        self.step_submit = 0
         # root span of this leg's span tree (engine-owned; None when
         # the engine has no tracer — the default-off zero-cost path)
         self._span = None
@@ -638,7 +645,15 @@ class DecodeEngine:
         # prefill chunks, span ends) ride the _jevents pattern: cheap
         # tuples collected under the step lock, metrics/spans emitted
         # after it.
-        self.tracer = tracer
+        # The step timeline: every `step_once` that runs a decode step
+        # leaves one record of its host phases (observability/perf.py),
+        # always on: a handful of clock reads and one append a step, no
+        # registry write; totals in `stats()["phases"]`. It holds the
+        # engine's tracer (the `tracer` property), so a tracer attached
+        # later (ModelServer's) gets the step track too.
+        self._phases = StepPhaseProfiler(
+            tracer=tracer, owner=f"decode/{model_name}",
+            emit_metrics=False)
         self._lat: List[tuple] = []
         self._ttft_ring: deque = deque(maxlen=512)
         self._itl_ring: deque = deque(maxlen=512)
@@ -652,6 +667,14 @@ class DecodeEngine:
         _LIVE_ENGINES.add(self)
         if journal is not None:
             self.attach_journal(journal)
+
+    @property
+    def tracer(self):
+        return self._phases.tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self._phases.tracer = tracer
 
     # -------------------------------------------------------- lifecycle
     def start(self) -> "DecodeEngine":
@@ -904,6 +927,7 @@ class DecodeEngine:
                                   deadline_s=deadline_s,
                                   request_id=rid, tenant=tenant,
                                   trace=tid)
+        handle.step_submit = self._steps
         if self.tracer is not None:
             # the leg's root span: opened on the submitting thread (an
             # enclosing server span parents it implicitly), closed by
@@ -1078,6 +1102,8 @@ class DecodeEngine:
                                   parent=handle._span, args=targs)
             elif kind == "ttft":
                 dt = b - handle.t_submit
+                record_request(self._phases.owner, handle.step_submit,
+                               handle.t_submit, a, b)
                 self._ttft_ring.append(dt)
                 _obs.observe("dl4j_decode_ttft_seconds", dt,
                              labels={"tenant": tenant})
@@ -1093,9 +1119,13 @@ class DecodeEngine:
                     tracer.record("token", a, b, cat="decode",
                                   parent=handle._span, args=targs)
             elif kind == "chunk":
+                # the dispatch of an asynchronous program, a few
+                # milliseconds; the chunk's device time is the device
+                # trace's (`prefill_chunk_ms.serve`)
                 if tracer is not None:
-                    tracer.record("prefill_chunk", a, b, cat="decode",
-                                  parent=handle._span, args=targs)
+                    tracer.record("prefill_chunk_dispatch", a, b,
+                                  cat="decode", parent=handle._span,
+                                  args=targs)
             elif kind == "end":
                 self._end_span(handle, a)
 
@@ -1153,12 +1183,14 @@ class DecodeEngine:
         Telemetry (fault points aside, counters, gauges) fires OUTSIDE
         the step lock — emission is never a blocking op under a
         lock."""
+        pp = self._phases
+        pp.begin_step(since_last="between_steps")
+        pp.mark("sweep")
         try:
             _fire("serving.slot_evict")
             evict = False
         except FaultInjectedError:
             evict = True
-        prefill_s: List[float] = []
         quar_before = self._quarantines
         replays_before = self._replays
         chunks_before = self._prefill_chunks
@@ -1167,21 +1199,27 @@ class DecodeEngine:
         with self._step_lock:
             n_deadline, n_cancel = self._sweep_deadlines()
             evicted = self._evict_lowest_active() if evict else 0
-            admitted, emitted = self._admit_pending(prefill_s)
+            pp.mark("admit")
+            admitted, emitted = self._admit_pending()
             # slots still mid-prefill sit out the decode dispatch
             # (their rows compute scratch-backed garbage the harvest
             # ignores); everyone else needs a writable cell for the
             # current position — alloc / ring wrap / copy-on-write
+            pp.mark("prepare_cells")
             self._prepare_write_cells()
             decoding = self._active & (self._fill_next < 0)
             stepped = bool(decoding.any())
             if stepped:
+                pp.mark("tables")
                 cp, co, wp, wo = self._step_tables(decoding)
+                pp.mark("dispatch")
                 self.kv, nxt, ok = self.program.step(
                     self.kv, self._tokens, self._positions, cp, co,
                     wp, wo)
+                pp.mark("fetch")    # the host blocked on the device
                 nxt_host = np.asarray(nxt)
                 ok_host = np.asarray(ok)
+                pp.mark("harvest")
                 try:
                     # `decode.nonfinite` chaos site: force a poison
                     # verdict on the lowest decoding slot — the NaN
@@ -1203,6 +1241,7 @@ class DecodeEngine:
             lat, self._lat = self._lat, []
             dump_reason, self._flight_dump_reason = (
                 self._flight_dump_reason, None)
+        pp.mark("emit")
         chunks = self._prefill_chunks - chunks_before
         if chunks:
             _obs.count("dl4j_decode_prefill_chunks_total", n=chunks)
@@ -1223,15 +1262,18 @@ class DecodeEngine:
         replays = self._replays - replays_before
         if replays:
             _obs.count("dl4j_decode_replays_total", n=replays)
-        for dt in prefill_s:
-            _obs.observe("dl4j_decode_prefill_seconds", dt)
         if emitted:
             _obs.count("dl4j_decode_tokens_total", n=emitted)
         self._emit_latency(lat)
         if dump_reason is not None:
             self._flight.dump(dump_reason)
         self._publish_gauges()
+        pp.mark("journal")
         self._write_journal(jevents)
+        if stepped:
+            # one record an engine step; a call that ran none (idle, or
+            # chunks only) is left to the next step's `between_steps`
+            pp.end_step(step=self._steps)
         return bool(stepped or admitted or chunks or evicted
                     or n_deadline or n_cancel)
 
@@ -1284,7 +1326,7 @@ class DecodeEngine:
         self._cancelled += n_cancel
         return n_deadline, n_cancel
 
-    def _admit_pending(self, prefill_s: List[float]):
+    def _admit_pending(self):
         """Spend this step's chunk budget: advance in-flight chunked
         prefills first (oldest slot first — a resident prompt finishes
         before a new one starts competing), then place waiting
@@ -1299,7 +1341,7 @@ class DecodeEngine:
             if budget <= 0:
                 break
             if self._active[s] and self._fill_next[s] >= 0:
-                budget -= self._advance_fill(s, prefill_s)
+                budget -= self._advance_fill(s)
         while budget > 0:
             free = [s for s in range(self.max_slots)
                     if not self._active[s] and not self._quarantined[s]]
@@ -1311,8 +1353,7 @@ class DecodeEngine:
                 handle, replay = self._pending.popleft()
                 self._placing += 1
             try:
-                budget -= self._place(handle, replay, free[0],
-                                      prefill_s)
+                budget -= self._place(handle, replay, free[0])
             finally:
                 with self._cond:
                     self._placing -= 1
@@ -1320,8 +1361,7 @@ class DecodeEngine:
         return admitted, emitted
 
     def _place(self, handle: GenerationHandle,
-               replay: Optional[List[int]], slot: int,
-               prefill_s: List[float]) -> int:
+               replay: Optional[List[int]], slot: int) -> int:
         """Make `handle` resident on `slot`: map its longest cached
         prefix from the trie (refcounted read-only pages — the
         shared-prefix capacity win), then start chunked prefill of
@@ -1363,9 +1403,9 @@ class DecodeEngine:
             self._fill_done(slot)
             return 0
         self._fill_next[slot] = covered
-        return self._advance_fill(slot, prefill_s)
+        return self._advance_fill(slot)
 
-    def _advance_fill(self, slot: int, prefill_s: List[float]) -> int:
+    def _advance_fill(self, slot: int) -> int:
         """Dispatch ONE prompt chunk for a filling slot (page_size
         tokens into one freshly allocated page). Returns the chunk
         dispatches spent; 0 means the pool is exhausted beyond
@@ -1385,10 +1425,9 @@ class DecodeEngine:
         self.kv = self.program.prefill_chunk(
             self.kv, prompt[start:start + ps], start, cp, co, page)
         self._prefill_chunks += 1
-        t1 = time.perf_counter()
-        prefill_s.append(t1 - t0)
         if self.tracer is not None:
-            self._lat.append(("chunk", handle, t0, t1))
+            self._lat.append(("chunk", handle, t0,
+                              time.perf_counter()))
         self._flight.note("chunk", self._steps, slot=slot, start=start)
         nxt = start + ps
         if nxt >= len(prompt):
@@ -1737,6 +1776,7 @@ class DecodeEngine:
             "trace_counts": self.program.trace_stats()["trace_counts"],
             "dispatches": self.program.trace_stats().get("dispatches"),
             "latency": self.latency_stats(),
+            "phases": self._phases.report(),
             "flight": self._flight.stats(),
             "tracing": (self.tracer.stats()
                         if self.tracer is not None else None),

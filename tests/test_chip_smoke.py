@@ -126,7 +126,9 @@ def test_script_refuses_the_cpu():
 def test_compile_cache_is_placed_once(env_dir, monkeypatch):
     """JAX_COMPILATION_CACHE_DIR set: jax honours it alone and the
     code sets no directory. Unset: `<checkout>/.jax_cache`, a fixed
-    path."""
+    path. Either way the key takes in the programs' metadata (the
+    scope names a device trace is read by), with locations cut to one
+    frame."""
     import jax
 
     from deeplearning4j_tpu.nn.jit_cache import place_compile_cache
@@ -134,15 +136,17 @@ def test_compile_cache_is_placed_once(env_dir, monkeypatch):
     calls = []
     monkeypatch.setattr(jax.config, "update",
                         lambda key, value: calls.append((key, value)))
+    keyed = [("jax_compilation_cache_include_metadata_in_key", True),
+             ("jax_traceback_in_locations_limit", 1)]
     if env_dir is None:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         assert place_compile_cache() == str(ROOT / ".jax_cache")
-        assert calls == [("jax_compilation_cache_dir",
-                          str(ROOT / ".jax_cache"))]
+        assert calls == keyed + [("jax_compilation_cache_dir",
+                                  str(ROOT / ".jax_cache"))]
     else:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
         assert place_compile_cache() == env_dir
-        assert calls == []
+        assert calls == keyed
 
 
 @pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),

@@ -411,16 +411,23 @@ def test_generate_over_http_npz_json_and_429(program):
 def test_decode_metrics_registered_and_emitted(program):
     """The decode metric domain, pinned like every other domain:
     dl4j_decode_active_slots, dl4j_decode_tokens_total,
-    dl4j_decode_tokens_per_s, dl4j_decode_prefill_seconds,
+    dl4j_decode_tokens_per_s, dl4j_decode_prefill_chunks_total,
     dl4j_decode_slot_evictions_total registered; traffic emits them;
-    the fault point serving.slot_evict is registered."""
+    the fault point serving.slot_evict is registered. (PR 26 took
+    dl4j_decode_prefill_seconds away: it timed an asynchronous
+    dispatch; chunks are counted, and their device time is the device
+    trace's.)"""
     names = {"dl4j_decode_active_slots", "dl4j_decode_tokens_total",
-             "dl4j_decode_tokens_per_s", "dl4j_decode_prefill_seconds",
+             "dl4j_decode_tokens_per_s",
+             "dl4j_decode_prefill_chunks_total",
              "dl4j_decode_slot_evictions_total"}
+    assert "dl4j_decode_prefill_seconds" not in REGISTERED_METRICS
     assert names <= set(REGISTERED_METRICS)
     assert "serving.slot_evict" in REGISTERED_POINTS
     reg = get_registry()
     tokens_before = reg.counter_value("dl4j_decode_tokens_total")
+    chunks_before = reg.counter_value(
+        "dl4j_decode_prefill_chunks_total")
     evicts_before = reg.counter_value(
         "dl4j_decode_slot_evictions_total")
     reqs = _requests(4, seed=6)
@@ -431,9 +438,10 @@ def test_decode_metrics_registered_and_emitted(program):
         == tokens_before + emitted
     assert reg.counter_value("dl4j_decode_slot_evictions_total") \
         == evicts_before + 1
+    assert reg.counter_value("dl4j_decode_prefill_chunks_total") \
+        == chunks_before + eng.stats()["prefill_chunks"]
     snap = reg.snapshot()
-    assert snap["histograms"]["dl4j_decode_prefill_seconds"]["count"] \
-        > 0
+    assert "dl4j_decode_prefill_seconds" not in snap["histograms"]
     gauges = snap["gauges"]
     assert "dl4j_decode_active_slots" in gauges
     assert "dl4j_decode_tokens_per_s" in gauges
@@ -484,7 +492,7 @@ def test_metrics_exposed_on_http_scrape(program):
         client.generate([2, 4, 6], max_new_tokens=3, model="decoder")
         text = client.metrics_text()
         assert "dl4j_decode_tokens_total" in text
-        assert "dl4j_decode_prefill_seconds_bucket" in text
+        assert "dl4j_decode_prefill_chunks_total" in text
     finally:
         server.stop()
 

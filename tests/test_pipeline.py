@@ -363,7 +363,9 @@ def test_phase_attribution_data_wait_collapses():
 
         tm = TrainingMaster(_heavy_net(), pipeline=pipeline)
         tm.fit(slow_batch, 2)   # compile warm-up outside the profile
-        tm.phase_profiler = StepPhaseProfiler()
+        # a sync a step: the step's compute has to show as its own
+        # phase for the shares to compare (no longer the default)
+        tm.phase_profiler = StepPhaseProfiler(sync_every=1)
         tm.fit(slow_batch, 10, start_step=2)
         rep = tm.training_stats()["phases"]
         shares = {p: v["share"] for p, v in rep["phases"].items()}

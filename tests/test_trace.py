@@ -126,7 +126,9 @@ def test_engine_spans_and_trace_id_minting(program):
     assert len(toks) == 6
     assert toks[0]["args"].get("first") is True
     assert _spans(doc, name="admission_wait", trace=h.trace)
-    assert _spans(doc, name="prefill_chunk", trace=h.trace)
+    # the span times the chunk program's dispatch, and says so
+    assert _spans(doc, name="prefill_chunk_dispatch", trace=h.trace)
+    assert not _spans(doc, name="prefill_chunk", trace=h.trace)
     # a caller-supplied id wins over minting
     h2 = eng.submit([1, 2, 3], max_new_tokens=2,
                     trace="cafe0000cafe0000")
