@@ -10,8 +10,8 @@ constraint that makes one-program XLA serving work at all, per
                 consume each slot's current token at its current
                 LOGICAL position, scatter that position's K/V into a
                 host-chosen (page, offset) write cell, gather each
-                slot's attention window through per-cell
-                (page, offset) index arrays in logical token order,
+                slot's attention window a whole page at a time
+                through its [pages_per_slot] page ids in ring order,
                 attend under per-slot live masks, emit each slot's
                 greedy next token. Requests joining/leaving, prefix
                 pages being shared, copy-on-write forks, and ring wrap
@@ -21,7 +21,7 @@ constraint that makes one-program XLA serving work at all, per
   chunk prefill ONE program per page_size chunk: process one
                 page-aligned slice of a prompt in parallel — causal
                 within the chunk, attending to the prior context
-                through the same gathered-cell indirection — and park
+                through the same gathered-page indirection — and park
                 its K/V into one physical page. A prompt is a sequence
                 of chunk dispatches interleaved between decode steps,
                 so a long prompt never stalls resident generations,
@@ -36,15 +36,18 @@ Processing Primitives, arXiv 2104.05755 — the page indirection is a
 hand-fused gather/scatter pair): ONE preallocated buffer
 ``[n_layers, 2, n_pages, n_heads, page_size, head_dim]`` — page-major
 so one page id addresses every layer's K and V rows at once (one
-page-table entry per page, not per layer), HEAD-MAJOR within a page
-so gathered cells arrive [..., n_heads, cells, head_dim] and both
-attention contractions batch over leading (slot, head) dims (the
+page-table entry per page, not per layer, and one page of one layer
+is [n_heads, page_size, head_dim] contiguous: the unit the window is
+read in), HEAD-MAJOR within a page so a gathered window arrives
+[..., pages, n_heads, page_size, head_dim] and both attention
+contractions batch over (slot, head) with no authored transpose (the
 first slot-major attempt made XLA transpose 40% of program traffic
 per step — caught by prog-transpose-churn, documented in PERF.md),
 head_dim innermost for lane alignment. Page 0 is SCRATCH: the write
-target for inactive/suppressed rows and the gather target for dead
-cells — never mapped live, and its (possibly garbage) bytes are
-zeroed out inside the attention primitives before any contraction.
+target for inactive/suppressed rows and the gather target for pages
+with no live cell — never mapped live, and its (possibly garbage)
+bytes are zeroed out inside the attention primitives before any
+contraction.
 
 All three programs DONATE the pool: updates are in-place, the caller
 rebinds — program-lint's prog-unhonored-donation rule verifies the
@@ -52,12 +55,16 @@ executable alias map actually honors it (a silent copy of this buffer
 per token is the regression the rule exists to catch; all three join
 the --programs representative set).
 
-Bitwise contract: the host passes cell index arrays in LOGICAL token
-order, so the engine under any page-table history (shared prefixes,
-CoW forks, ring wrap, eviction replay) presents the attention
-reduction with identical operand values in identical order to the
-sequential oracle's — the FP-associativity discipline that makes
-"bitwise equal to the oracle" achievable at all.
+Bitwise contract: the host passes each slot's page ids in RING order
+(`window_pages`), so cell c = ring * page_size + offset of the
+gathered window holds the position congruent to c modulo the window —
+a function of the position alone. Before a wrap that is logical
+token order; after one it is a rotation of it. Either way the engine
+under any page-table history (shared prefixes, CoW forks, ring wrap,
+eviction replay) presents the attention reduction with identical
+operand values in identical order to the sequential oracle's — the
+FP-associativity discipline that makes "bitwise equal to the oracle"
+achievable at all.
 
 Forensics / policy / MFU ride the exact StepProgram rails: programs
 live in the model's JitCache (record_trace inside traced bodies,
@@ -90,7 +97,7 @@ class DecodeProgram:
     Holds NO request state — serving/continuous.py's DecodeEngine owns
     slots, the page table, the prefix trie, and refcounts; this class
     owns shapes, compilation, the pool layout, and the host-side
-    window-cell arithmetic both the engine and the oracle share."""
+    window-page translation both the engine and the oracle share."""
 
     def __init__(self, model, max_slots: int = 8, page_size: int = 16,
                  n_pages: Optional[int] = None):
@@ -154,27 +161,25 @@ class DecodeProgram:
                 f"window {self.window}")
         return list(range(int(from_token), prompt_len, self.page_size))
 
-    def window_cells(self, table: Sequence[Optional[int]],
-                     pos: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Host-side virtual→physical translation: the per-cell
-        (page, offset) arrays for one slot's attention window at
-        logical position `pos`, in LOGICAL token order — cell j holds
-        position pos+1-live+j, where live = min(pos+1, window). Dead
-        cells (j >= live) point at the scratch page. `table` is the
-        slot's ring page table (pages_per_slot entries); entries for
-        live positions must be mapped. Shared by the engine and the
-        sequential oracle — the single definition of reduction order
-        the bitwise contract rests on."""
-        c, ps, p = self.window, self.page_size, self.pages_per_slot
-        cell_page = np.full(c, SCRATCH_PAGE, np.int32)
-        cell_off = np.zeros(c, np.int32)
-        live = min(pos + 1, c)
-        if live > 0:
-            qs = np.arange(pos + 1 - live, pos + 1)
-            rings = (qs // ps) % p
-            cell_page[:live] = [table[r] for r in rings]
-            cell_off[:live] = qs % ps
-        return cell_page, cell_off
+    def window_pages(self, table: Sequence[Optional[int]],
+                     pos: int) -> np.ndarray:
+        """Host-side virtual→physical translation: the
+        [pages_per_slot] page ids of one slot's attention window at
+        logical position `pos`, in RING order — cell
+        c = ring * page_size + offset of the gathered window holds the
+        position q with q % window == c, so the live cells are
+        c < live = min(pos + 1, window): logical token order until the
+        ring wraps, a rotation of it (every cell live) after. Ring
+        entries with no live cell point at the scratch page. `table`
+        is the slot's ring page table (pages_per_slot entries);
+        entries for live positions must be mapped. Shared by the
+        engine and the sequential oracle — the single definition of
+        reduction order the bitwise contract rests on."""
+        live = min(pos + 1, self.window)
+        n = -(-live // self.page_size)     # pages holding a live cell
+        page_ids = np.full(self.pages_per_slot, SCRATCH_PAGE, np.int32)
+        page_ids[:n] = table[:n]
+        return page_ids
 
     # ------------------------------------------------------- compile
     def decode_key(self):
@@ -225,11 +230,9 @@ class DecodeProgram:
         n_heads = model.n_heads
         max_ctx = model.max_ctx
         cache = model._jit_cache
-        # broadcast head index for the [S, H, C, D] head-major gather
-        hidx = np.arange(n_heads)[None, :, None]
 
-        def decode_fn(params, pool, tokens, positions, cell_page,
-                      cell_off, write_page, write_off):
+        def decode_fn(params, pool, tokens, positions, page_ids,
+                      write_page, write_off):
             cache.record_trace(trace_key)
             # The named scopes say what the work is, with no layer
             # index (a reader sums over layers): they are what the
@@ -242,8 +245,6 @@ class DecodeProgram:
                 x = (params["tok_emb"][tokens]
                      + params["pos_emb"][positions % max_ctx])
             live = jnp.minimum(positions + 1, self.window)
-            cp = cell_page[:, None, :]        # [S, 1, C] vs hidx
-            co = cell_off[:, None, :]
             for li, lp in enumerate(params["layers"]):
                 with jax.named_scope("qkv"):
                     q, k, v = decode_qkv(lp, x, n_heads)
@@ -255,11 +256,16 @@ class DecodeProgram:
                                    write_off].set(k)
                     pool = pool.at[li, 1, write_page, :,
                                    write_off].set(v)
-                # gather: [S, H, C, D] head-major window cells in
-                # logical order — the virtual-memory read
+                # gather: [S, P, H, page_size, D], each slot's window
+                # a whole page at a time in ring order — the
+                # virtual-memory read. ONE gather over the whole pool
+                # (layer and K/V plane are constant indices of it):
+                # `pool[li, 0][page_ids]` makes the chip's compiler
+                # copy the layer's plane out first, 0.8 ms a layer
+                # beside the 0.6 ms the gather itself takes (PERF.md)
                 with jax.named_scope("kv_read"):
-                    kg = pool[li, 0][cp, hidx, co]
-                    vg = pool[li, 1][cp, hidx, co]
+                    kg = pool[li, 0, page_ids]
+                    vg = pool[li, 1, page_ids]
                 x = block_decode_finish(lp, x, q, kg, vg, live)
             with jax.named_scope("head"):
                 xf = layer_norm(x, params["lnf_g"], params["lnf_b"])
@@ -281,7 +287,7 @@ class DecodeProgram:
     def _build_chunk(self, trace_key: str):
         """Compile the chunk-prefill program: one page_size slice of a
         prompt, causal within the chunk, prior context via gathered
-        cells, K/V parked into ONE physical page (`write_page` is a
+        pages, K/V parked into ONE physical page (`write_page` is a
         traced scalar — no recompile per page). Pad rows beyond
         `length` write page cells the live masks never expose; they
         are overwritten cell-by-cell as decoding advances."""
@@ -297,26 +303,22 @@ class DecodeProgram:
         n_heads = model.n_heads
         t = self.page_size
         cache = model._jit_cache
-        hidx = np.arange(n_heads)[:, None]   # [H, 1] vs [1, C] cells
         offs = np.arange(t)                  # the page's cell offsets
 
-        def chunk_fn(params, pool, tokens, start, cell_page, cell_off,
-                     write_page):
+        def chunk_fn(params, pool, tokens, start, page_ids, write_page):
             cache.record_trace(trace_key)
             with jax.named_scope("embed"):
                 x = (params["tok_emb"][tokens]
                      + params["pos_emb"][start + jnp.arange(t)])
-            cp = cell_page[None, :]
-            co = cell_off[None, :]
             for li, lp in enumerate(params["layers"]):
                 # project + PARK the chunk's K/V before gathering the
                 # prior cells — the same scatter-then-gather order as
                 # the decode step, which is what lets XLA update the
                 # donated pool in place (a gather of the PRE-scatter
                 # pool forced two full-pool copies). Safe because the
-                # prior cells can never alias `write_page`: prefill
-                # never wraps (prompt <= window), so cell arrays point
-                # at earlier blocks' pages or scratch, and the
+                # prior pages can never alias `write_page`: prefill
+                # never wraps (prompt <= window), so the page ids name
+                # earlier blocks' pages or scratch, and the
                 # advanced `offs` index lands [T, H, D] rows in the
                 # head-major page without an authored transpose.
                 with jax.named_scope("qkv"):
@@ -325,8 +327,8 @@ class DecodeProgram:
                     pool = pool.at[li, 0, write_page, :, offs].set(k)
                     pool = pool.at[li, 1, write_page, :, offs].set(v)
                 with jax.named_scope("kv_read"):
-                    kg = pool[li, 0][cp, hidx, co]      # [H, C, D]
-                    vg = pool[li, 1][cp, hidx, co]
+                    kg = pool[li, 0, page_ids]      # [P, H, ps, D]
+                    vg = pool[li, 1, page_ids]
                 x = block_chunk_prefill(lp, x, n_heads, kg, vg, start,
                                         qkv=(q, k, v))
             return pool
@@ -354,18 +356,19 @@ class DecodeProgram:
         return jax.jit(copy_fn, donate_argnums=(0,))
 
     # ----------------------------------------------------------- run
-    def step(self, kv, tokens, positions, cell_page, cell_off,
-             write_page, write_off):
+    def step(self, kv, tokens, positions, page_ids, write_page,
+             write_off):
         """One decode step over all slots. `tokens`/`positions`/
         `write_page`/`write_off` are host [max_slots] int arrays and
-        `cell_page`/`cell_off` host [max_slots, window] int arrays
-        (the engine's translated page table); returns
+        `page_ids` a host [max_slots, pages_per_slot] int array (one
+        `window_pages` row per slot: the engine's translated page
+        table); returns
         (new_kv, next_tokens, finite_ok) with `kv` donated — the
         caller MUST rebind. `finite_ok` is the per-slot finite-logits
         verdict ([max_slots] bool): a False row's token is numeric
         poison. Inactive/suppressed rows write scratch and gather
-        scratch-backed dead cells (zeroed in-kernel) — the host
-        decides whose outputs are real."""
+        scratch pages (zeroed in-kernel) — the host decides whose
+        outputs are real."""
         import jax.numpy as jnp
 
         fn = self._decode_program()
@@ -373,18 +376,18 @@ class DecodeProgram:
         return fn(self.model.params, kv,
                   jnp.asarray(tokens, jnp.int32),
                   jnp.asarray(positions, jnp.int32),
-                  jnp.asarray(cell_page, jnp.int32),
-                  jnp.asarray(cell_off, jnp.int32),
+                  jnp.asarray(page_ids, jnp.int32),
                   jnp.asarray(write_page, jnp.int32),
                   jnp.asarray(write_off, jnp.int32))
 
     def prefill_chunk(self, kv, chunk: Sequence[int], start: int,
-                      cell_page, cell_off, write_page: int):
+                      page_ids, write_page: int):
         """Prefill one page-aligned prompt chunk (positions
         start..start+len(chunk)-1, padded to page_size) into physical
         page `write_page`, attending to the prior context through
-        `cell_page`/`cell_off` ([window] arrays, cells >= start dead).
-        `kv` is donated — rebind."""
+        `page_ids` (`window_pages(table, start - 1)`: the
+        [pages_per_slot] ids, cells >= start dead). `kv` is donated —
+        rebind."""
         import jax.numpy as jnp
 
         chunk = np.asarray(chunk, np.int32).ravel()
@@ -394,8 +397,7 @@ class DecodeProgram:
         self._dispatches["chunk"] += 1
         return fn(self.model.params, kv, jnp.asarray(padded),
                   jnp.int32(start),
-                  jnp.asarray(cell_page, jnp.int32),
-                  jnp.asarray(cell_off, jnp.int32),
+                  jnp.asarray(page_ids, jnp.int32),
                   jnp.int32(write_page))
 
     def copy_page(self, kv, src: int, dst: int):
@@ -416,17 +418,14 @@ class DecodeProgram:
         the (donated-through) pool buffer."""
         del buckets
         kv = self.copy_page(kv, SCRATCH_PAGE, SCRATCH_PAGE)
-        cp, co = self.window_cells([SCRATCH_PAGE] * self.pages_per_slot,
-                                   -1)
-        kv = self.prefill_chunk(kv, [0] * self.page_size, 0, cp, co,
+        s, p = self.max_slots, self.pages_per_slot
+        zs = np.zeros(s, np.int32)
+        kv = self.prefill_chunk(kv, [0] * self.page_size, 0,
+                                np.full(p, SCRATCH_PAGE, np.int32),
                                 SCRATCH_PAGE)
-        s, c = self.max_slots, self.window
-        kv, _, _ = self.step(kv, np.zeros(s, np.int32),
-                             np.zeros(s, np.int32),
-                             np.zeros((s, c), np.int32),
-                             np.zeros((s, c), np.int32),
-                             np.zeros(s, np.int32),
-                             np.zeros(s, np.int32))
+        kv, _, _ = self.step(kv, zs, zs,
+                             np.full((s, p), SCRATCH_PAGE, np.int32),
+                             zs, zs)
         return kv
 
     def trace_stats(self) -> dict:
@@ -457,10 +456,10 @@ class DecodeProgram:
 
         model = self.model
         kv = self.init_kv()
-        s, c = self.max_slots, self.window
+        s, p = self.max_slots, self.pages_per_slot
         source = "deeplearning4j_tpu/engine/decode_program.py"
         zs = jnp.zeros(s, jnp.int32)
-        zc = jnp.zeros(c, jnp.int32)
+        zp = jnp.zeros(p, jnp.int32)
         step_fn = self._decode_program()
         chunk_fn = self._chunk_program()
         copy_fn = self._copy_program()
@@ -469,8 +468,7 @@ class DecodeProgram:
                 name=f"decode_step_s{s}",
                 fn=getattr(step_fn, "__wrapped__", step_fn),
                 example_args=(model.params, kv, zs, zs,
-                              jnp.zeros((s, c), jnp.int32),
-                              jnp.zeros((s, c), jnp.int32), zs, zs),
+                              jnp.zeros((s, p), jnp.int32), zs, zs),
                 donate_argnums=(1,),
                 precision_policy=self.precision_policy, source=source,
                 consumed_outputs=(0, 1, 2)),
@@ -479,7 +477,7 @@ class DecodeProgram:
                 fn=getattr(chunk_fn, "__wrapped__", chunk_fn),
                 example_args=(model.params, kv,
                               jnp.zeros(self.page_size, jnp.int32),
-                              jnp.int32(0), zc, zc, jnp.int32(1)),
+                              jnp.int32(0), zp, jnp.int32(1)),
                 donate_argnums=(1,),
                 precision_policy=self.precision_policy, source=source,
                 consumed_outputs=(0,)),
@@ -503,18 +501,16 @@ class DecodeProgram:
 
         cache = self.model._jit_cache
         kv = self.init_kv()
-        s, c = self.max_slots, self.window
+        s, p = self.max_slots, self.pages_per_slot
         zs = jnp.zeros(s, jnp.int32)
         entry = cost_model.register_jit_entry(
             cache, self.decode_key(), self.model.params, kv, zs, zs,
-            jnp.zeros((s, c), jnp.int32),
-            jnp.zeros((s, c), jnp.int32), zs, zs)
+            jnp.zeros((s, p), jnp.int32), zs, zs)
         if bucket_len:
             self._chunk_program()
             cost_model.register_jit_entry(
                 cache, self.chunk_key(), self.model.params,
                 self.init_kv(),
                 jnp.zeros(self.page_size, jnp.int32), jnp.int32(0),
-                jnp.zeros(c, jnp.int32), jnp.zeros(c, jnp.int32),
-                jnp.int32(1))
+                jnp.zeros(p, jnp.int32), jnp.int32(1))
         return entry

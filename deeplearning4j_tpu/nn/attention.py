@@ -5,17 +5,16 @@ that exists in TWO compiled shapes over ONE set of weights:
 
   chunk prefill   one page_size-aligned slice [T, d_model] of a prompt
              processed in parallel: causal within the chunk, attending
-             to the PRIOR context through gathered page cells, emitting
+             to the PRIOR context through gathered pages, emitting
              the chunk's K/V so the caller parks them in a physical
              page. Chunks interleave with decode steps, so a long
              prompt never stalls resident generations.
   decode     ONE new position per slot, batched over the engine's
-             [max_slots] axis, attending against page cells GATHERED
-             in logical token order — the per-cell (page, offset)
-             indirection that makes the cache a virtual address space:
-             shared prefix pages, copy-on-write forks, and ring wrap
-             past max_ctx are all host page-table edits, never a new
-             compiled shape.
+             [max_slots] axis, attending against whole pages GATHERED
+             in ring order — the page-table indirection that makes
+             the cache a virtual address space: shared prefix pages,
+             copy-on-write forks, and ring wrap past max_ctx are all
+             host page-table edits, never a new compiled shape.
 
 Both build from the same per-layer parameter dict (see
 zoo/decoder.CausalTransformer), so the math of a position is defined
@@ -23,23 +22,27 @@ once; engine/decode_program.py owns where K/V land in the page pool.
 
 Layout discipline (Tensor Processing Primitives, arXiv 2104.05755):
 head_dim rides innermost everywhere (the contraction axis of both
-attention matmuls stays in the minor/lane dimension), and gathered
-cells arrive HEAD-MAJOR [..., n_heads, cells, head_dim] so both cache
-contractions keep (slot, head) as leading batch dims — XLA contracts
-in place instead of materializing a transposed cache copy per step
+attention matmuls stays in the minor/lane dimension), and the window
+arrives as the pool stores it, [..., pages, n_heads, page_size,
+head_dim]: a page is HEAD-MAJOR, so both cache contractions batch
+over (slot, head) and take pages and offsets as free or contracted
+dims where they lie — no transposed copy of the window is authored
 (the transpose-churn finding the program lint raised against the
 first slot-major layout — PERF.md "Decode program layout").
 
 Bitwise discipline: attention is commutative but NOT associative over
 keys, so the engine and the sequential oracle must present identical
-operand values in an identical reduction order. Gathering cells in
-LOGICAL token order (cell j = j-th oldest position in the window) is
-that mechanism — a wrapped ring, a shared prefix page, and a fresh
-contiguous fill all reduce over the same [cells] axis in the same
-order. Dead cells are zeroed BEFORE the score contraction (not just
-masked after): a dead cell points at the shared scratch page, whose
-bytes other slots scribble, and 0·garbage is the only value that can
-never leak — exp(MASK_VALUE - max) underflows the weight to exactly
+operand values in an identical reduction order. Gathering pages in
+RING order (cell c = page * page_size + offset holds the position
+congruent to c modulo the window — a function of the position alone,
+and logical token order until the ring wraps) is that mechanism — a
+wrapped ring, a shared prefix page, and a fresh contiguous fill all
+reduce over the same [cells] axis in the same order, whatever
+physical pages the table names. Dead cells are zeroed BEFORE the
+score contraction (not just masked after): a dead cell lies in the
+unwritten tail of a page or on the shared scratch page, whose bytes
+other slots scribble, and 0·garbage is the only value that can never
+leak — exp(MASK_VALUE - max) underflows the weight to exactly
 0.0, and the zeroed value keeps 0·NaN out of the weighted sum.
 
 Everything here is pure jax on traced values — no host syncs, no
@@ -75,62 +78,70 @@ def qkv_heads(lp: dict, x, n_heads: int):
     return split(lp["wq"]), split(lp["wk"]), split(lp["wv"])
 
 
-def paged_decode_attention(q, k_cells, v_cells, live):
-    """Single-position attention against GATHERED page cells (the
-    DECODE shape): `q` is [S, n_heads, head_dim] (one new position per
-    slot), `k_cells`/`v_cells` are HEAD-MAJOR
-    [S, n_heads, cells, head_dim] — the slot's window gathered from
-    the physical page pool in LOGICAL token order (cell j = j-th
-    oldest live position), with the new position's K/V already written
-    at cell live[s]-1. `live[s]` counts the slot's readable cells;
-    cells beyond it point at the scratch page and are zeroed before
-    the score contraction (see the module docstring). Head-major cell
-    layout is load-bearing: BOTH contractions run with (slot, head) as
-    leading batch dims and the contraction axis minor, so XLA never
-    materializes a transposed copy of the gathered cells (the 40%
-    transpose-churn the program lint flagged on the first slot-major
-    attempt — PERF.md). Returns [S, n_heads, head_dim]."""
+def paged_decode_attention(q, k_pages, v_pages, live):
+    """Single-position attention against GATHERED pages (the DECODE
+    shape): `q` is [S, n_heads, head_dim] (one new position per slot),
+    `k_pages`/`v_pages` are [S, pages, n_heads, page_size, head_dim] —
+    the slot's window gathered from the physical page pool a whole
+    page at a time, in RING order: cell c = page * page_size + offset
+    holds the position congruent to c modulo the window, with the new
+    position's K/V already written at its cell. `live[s]` counts the
+    slot's readable cells; cells beyond it (the unwritten tail of the
+    newest page, and whole pages that point at scratch) are zeroed
+    before the score contraction (see the module docstring). Both
+    contractions take the page layout as it is gathered — (slot, head)
+    batch dims, head_dim minor, pages and offsets free or contracted
+    in place — so no transposed copy of the window is authored (the
+    40% transpose-churn the program lint flagged on the first
+    slot-major attempt — PERF.md). Returns [S, n_heads, head_dim]."""
     import jax.numpy as jnp
 
-    c = k_cells.shape[2]
+    s, p, h, t, _ = k_pages.shape
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
-    mask = jnp.arange(c)[None, :] < live[:, None]          # [S, C]
-    m4 = mask[:, None, :, None]
-    k_cells = jnp.where(m4, k_cells, 0.0)
-    v_cells = jnp.where(m4, v_cells, 0.0)
-    scores = jnp.einsum("shd,shcd->shc", q, k_cells) * scale
-    scores = jnp.where(mask[:, None, :], scores, MASK_VALUE)
-    w = _softmax(scores)
-    return jnp.einsum("shc,shcd->shd", w, v_cells)
+    mask = jnp.reshape(jnp.arange(p * t)[None, :] < live[:, None],
+                       (s, p, t))                          # [S, P, T]
+    m5 = mask[:, :, None, :, None]
+    k_pages = jnp.where(m5, k_pages, 0.0)
+    v_pages = jnp.where(m5, v_pages, 0.0)
+    scores = jnp.einsum("shd,sphtd->shpt", q, k_pages) * scale
+    scores = jnp.where(mask[:, None], scores, MASK_VALUE)
+    # one softmax over the window's cells in ring-cell order
+    w = _softmax(jnp.reshape(scores, (s, h, p * t)))
+    return jnp.einsum("shpt,sphtd->shd",
+                      jnp.reshape(w, (s, h, p, t)), v_pages)
 
 
-def chunk_prefill_attention(q, k, v, k_cells, v_cells, n_prior):
+def chunk_prefill_attention(q, k, v, k_pages, v_pages, n_prior):
     """One prompt chunk attending jointly to its PRIOR context and to
     itself (the CHUNK-PREFILL shape): `q`/`k`/`v` are [T, n_heads,
-    head_dim] for chunk positions n_prior..n_prior+T-1; `k_cells`/
-    `v_cells` are HEAD-MAJOR [n_heads, cells, head_dim] — the already-
-    prefilled positions 0..n_prior-1 gathered from their pages in
-    logical order (cells >= n_prior are scratch: zeroed + masked).
-    ONE softmax spans [prior cells ; chunk] so the reduction order is
-    fixed regardless of how the prior pages were produced — computed
-    by an earlier chunk, or mapped read-only from the prefix trie.
-    Returns [T, n_heads, head_dim]."""
+    head_dim] for chunk positions n_prior..n_prior+T-1; `k_pages`/
+    `v_pages` are [pages, n_heads, page_size, head_dim] — the already-
+    prefilled positions 0..n_prior-1 gathered a whole page at a time
+    in ring order, which before a wrap (and a prompt never wraps) is
+    logical order: cell c holds position c (cells >= n_prior are
+    scratch: zeroed + masked). ONE softmax spans [prior cells ; chunk]
+    so the reduction order is fixed regardless of how the prior pages
+    were produced — computed by an earlier chunk, or mapped read-only
+    from the prefix trie. Returns [T, n_heads, head_dim]."""
     import jax.numpy as jnp
 
     t = q.shape[0]
-    c = k_cells.shape[1]
+    p, h, ps, _ = k_pages.shape
+    c = p * ps
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
-    prior = jnp.arange(c) < n_prior                        # [C]
-    m3 = prior[None, :, None]
-    k_cells = jnp.where(m3, k_cells, 0.0)
-    v_cells = jnp.where(m3, v_cells, 0.0)
-    sp = jnp.einsum("thd,hcd->htc", q, k_cells) * scale    # [H, T, C]
-    sp = jnp.where(prior[None, None, :], sp, MASK_VALUE)
+    prior = jnp.reshape(jnp.arange(c) < n_prior, (p, ps))  # [P, ps]
+    m4 = prior[:, None, :, None]
+    k_pages = jnp.where(m4, k_pages, 0.0)
+    v_pages = jnp.where(m4, v_pages, 0.0)
+    sp = jnp.einsum("thd,phcd->htpc", q, k_pages) * scale
+    sp = jnp.reshape(jnp.where(prior[None, None], sp, MASK_VALUE),
+                     (h, t, c))                            # [H, T, C]
     si = jnp.einsum("thd,uhd->htu", q, k) * scale          # [H, T, T]
     causal = jnp.tril(jnp.ones((t, t), bool))
     si = jnp.where(causal[None, :, :], si, MASK_VALUE)
     w = _softmax(jnp.concatenate([sp, si], axis=-1))
-    return (jnp.einsum("htc,hcd->thd", w[..., :c], v_cells)
+    wp = jnp.reshape(w[..., :c], (h, t, p, ps))
+    return (jnp.einsum("htpc,phcd->thd", wp, v_pages)
             + jnp.einsum("htu,uhd->thd", w[..., c:], v))
 
 
@@ -150,7 +161,7 @@ def mlp_block(lp: dict, x):
     return h @ lp["w2"] + lp["b2"]
 
 
-def block_chunk_prefill(lp: dict, x, n_heads: int, k_cells, v_cells,
+def block_chunk_prefill(lp: dict, x, n_heads: int, k_pages, v_pages,
                         n_prior, qkv=None):
     """One decoder block over a prompt CHUNK: x [T, d_model] -> x'.
     The chunk's q/k/v are pre-attention projections of the ln1 stream
@@ -159,7 +170,7 @@ def block_chunk_prefill(lp: dict, x, n_heads: int, k_cells, v_cells,
     The caller usually passes `qkv` precomputed via `decode_qkv` (it
     parks k/v into a physical page BEFORE attention — the
     scatter-then-gather order that keeps the pool update in place);
-    `k_cells`/`v_cells`/`n_prior` carry the prior context per
+    `k_pages`/`v_pages`/`n_prior` carry the prior context per
     `chunk_prefill_attention`."""
     import jax
 
@@ -168,7 +179,7 @@ def block_chunk_prefill(lp: dict, x, n_heads: int, k_cells, v_cells,
             qkv = decode_qkv(lp, x, n_heads)
     q, k, v = qkv
     with jax.named_scope("attn"):
-        att = chunk_prefill_attention(q, k, v, k_cells, v_cells,
+        att = chunk_prefill_attention(q, k, v, k_pages, v_pages,
                                       n_prior)
         x = x + _merge_heads(att) @ lp["wo"]
     with jax.named_scope("mlp"):
@@ -187,15 +198,15 @@ def decode_qkv(lp: dict, x, n_heads: int):
     return qkv_heads(lp, h, n_heads)
 
 
-def block_decode_finish(lp: dict, x, q, k_cells, v_cells, live):
+def block_decode_finish(lp: dict, x, q, k_pages, v_pages, live):
     """Second half of a decode-shape block: attend `q` [S, H, Dh]
-    against the gathered window cells [S, H, cells, Dh] (current
-    position's K/V already written at cell live[s]-1) and run the
-    residual + feed-forward tail. Returns x' [S, d_model]."""
+    against the gathered window pages [S, pages, H, page_size, Dh]
+    (current position's K/V already written at its ring cell) and run
+    the residual + feed-forward tail. Returns x' [S, d_model]."""
     import jax
 
     with jax.named_scope("attn"):
-        att = paged_decode_attention(q, k_cells, v_cells, live)
+        att = paged_decode_attention(q, k_pages, v_pages, live)
         x = x + _merge_heads(att) @ lp["wo"]
     with jax.named_scope("mlp"):
         x = x + mlp_block(lp, layer_norm(x, lp["ln2_g"], lp["ln2_b"]))

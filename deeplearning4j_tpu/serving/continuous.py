@@ -618,6 +618,8 @@ class DecodeEngine:
         self._prefix_page_hits = 0     # pages mapped from the trie
         self._ctx_wraps = 0            # page recycles past the window
         self._cow_copies = 0
+        self._kv_pages_gathered = 0    # pages the decode steps read,
+        self._kv_pages_live = 0        # and those with a live cell
         self._evictions = 0
         self._completed = 0
         self._quarantines = 0
@@ -1211,10 +1213,10 @@ class DecodeEngine:
             stepped = bool(decoding.any())
             if stepped:
                 pp.mark("tables")
-                cp, co, wp, wo = self._step_tables(decoding)
+                page_ids, wp, wo = self._step_tables(decoding)
                 pp.mark("dispatch")
                 self.kv, nxt, ok = self.program.step(
-                    self.kv, self._tokens, self._positions, cp, co,
+                    self.kv, self._tokens, self._positions, page_ids,
                     wp, wo)
                 pp.mark("fetch")    # the host blocked on the device
                 nxt_host = np.asarray(nxt)
@@ -1420,10 +1422,10 @@ class DecodeEngine:
         t0 = time.perf_counter()
         ring = (start // ps) % self.program.pages_per_slot
         self._table[slot][ring] = page
-        cp, co = self.program.window_cells(self._table[slot],
-                                           start - 1)
+        page_ids = self.program.window_pages(self._table[slot],
+                                             start - 1)
         self.kv = self.program.prefill_chunk(
-            self.kv, prompt[start:start + ps], start, cp, co, page)
+            self.kv, prompt[start:start + ps], start, page_ids, page)
         self._prefill_chunks += 1
         if self.tracer is not None:
             self._lat.append(("chunk", handle, t0,
@@ -1526,30 +1528,34 @@ class DecodeEngine:
                 self._cow_copies += 1
 
     def _step_tables(self, decoding: np.ndarray):
-        """Translate the page table into the decode dispatch's cell
-        index arrays: [S, window] (page, offset) pairs in logical
-        token order per slot, plus each slot's write cell
-        (first-token steps and non-decoding rows write scratch)."""
+        """Translate the page table into the decode dispatch's index
+        arrays: [S, pages_per_slot] page ids in ring order per slot
+        (`window_pages`; non-decoding rows gather scratch), plus each
+        slot's write cell (first-token steps and non-decoding rows
+        write scratch). Counts what the step will read: every entry is
+        a page gathered, an entry off scratch a page with a live
+        cell."""
         from deeplearning4j_tpu.engine.decode_program import (
             SCRATCH_PAGE,
         )
 
         s_n = self.max_slots
-        c = self.program.window
         ps = self.program.page_size
         p = self.program.pages_per_slot
-        cp = np.full((s_n, c), SCRATCH_PAGE, np.int32)
-        co = np.zeros((s_n, c), np.int32)
+        page_ids = np.full((s_n, p), SCRATCH_PAGE, np.int32)
         wp = np.full(s_n, SCRATCH_PAGE, np.int32)
         wo = np.zeros(s_n, np.int32)
         for s in np.flatnonzero(decoding):
             pos = int(self._positions[s])
-            cp[s], co[s] = self.program.window_cells(self._table[s],
-                                                     pos)
+            page_ids[s] = self.program.window_pages(self._table[s],
+                                                    pos)
             if not self._first_step[s]:
                 wp[s] = self._table[s][(pos // ps) % p]
                 wo[s] = pos % ps
-        return cp, co, wp, wo
+        self._kv_pages_gathered += page_ids.size
+        self._kv_pages_live += int(
+            np.count_nonzero(page_ids != SCRATCH_PAGE))
+        return page_ids, wp, wo
 
     def _harvest(self, nxt_host: np.ndarray,
                  decoding: np.ndarray) -> int:
@@ -1759,6 +1765,10 @@ class DecodeEngine:
             "prefill_chunks": self._prefill_chunks,
             "ctx_wraps": self._ctx_wraps,
             "cow_copies": self._cow_copies,
+            # what the decode steps read of the pool, in pages: all
+            # they gathered, and those that held a live cell
+            "kv_pages_gathered": self._kv_pages_gathered,
+            "kv_pages_live": self._kv_pages_live,
             "trie_blocks": (len(self._trie)
                             if self._trie is not None else 0),
             "steps": self._steps,
@@ -1819,21 +1829,20 @@ def sequential_decode(program, prompt: Sequence[int],
         ring = (start // ps) % pps
         if table[ring] is None:
             table[ring] = alloc()
-        cp, co = program.window_cells(table, start - 1)
         kv = program.prefill_chunk(kv, prompt[start:start + ps],
-                                   start, cp, co, table[ring])
+                                   start,
+                                   program.window_pages(table, start - 1),
+                                   table[ring])
     out: List[int] = []
     pos = len(prompt) - 1
     tok = prompt[-1]
     suppress = True  # first step: the prefill already wrote this cell
     s_n = program.max_slots
-    c = program.window
     tokens = np.zeros(s_n, np.int32)
     positions = np.zeros(s_n, np.int32)
     while len(out) < max_new_tokens and (eos_id is None or not out
                                          or out[-1] != eos_id):
-        cp = np.full((s_n, c), SCRATCH_PAGE, np.int32)
-        co = np.zeros((s_n, c), np.int32)
+        page_ids = np.full((s_n, pps), SCRATCH_PAGE, np.int32)
         wp = np.full(s_n, SCRATCH_PAGE, np.int32)
         wo = np.zeros(s_n, np.int32)
         ring = (pos // ps) % pps
@@ -1844,8 +1853,8 @@ def sequential_decode(program, prompt: Sequence[int],
             wo[slot] = pos % ps
         tokens[slot] = tok
         positions[slot] = pos
-        cp[slot], co[slot] = program.window_cells(table, pos)
-        kv, nxt, _ = program.step(kv, tokens, positions, cp, co,
+        page_ids[slot] = program.window_pages(table, pos)
+        kv, nxt, _ = program.step(kv, tokens, positions, page_ids,
                                   wp, wo)
         tok = int(np.asarray(nxt)[slot])
         out.append(tok)

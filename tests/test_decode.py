@@ -134,28 +134,45 @@ def test_kv_pool_is_page_and_head_major(program):
     assert program.init_kv().shape == program.kv_shape
 
 
-def test_window_cells_logical_order(program):
-    """Host-side virtual->physical translation: cell j is the j-th
-    oldest live position — the single reduction-order definition the
-    bitwise contract rests on — and dead cells park on scratch."""
+def test_window_pages_ring_order(program):
+    """Host-side virtual->physical translation: the slot's page ids in
+    ring order, so cell c = ring * page_size + offset of the gathered
+    window holds the position congruent to c modulo the window — the
+    single reduction-order definition the bitwise contract rests on —
+    and ring entries with no live cell park on scratch."""
     from deeplearning4j_tpu.engine.decode_program import SCRATCH_PAGE
 
     pps = program.pages_per_slot
     table = [10 + r for r in range(pps)]
-    # mid-fill: positions 0..20 live
-    cp, co = program.window_cells(table, 20)
-    assert list(cp[:21]) == [10 + (q // PAGE) % pps for q in range(21)]
-    assert list(co[:21]) == [q % PAGE for q in range(21)]
-    assert set(cp[21:]) == {SCRATCH_PAGE} and set(co[21:]) == {0}
-    # wrapped: position CTX + 3 — the window slides, logical order
-    # starts at the oldest RETAINED position
-    cp, co = program.window_cells(table, CTX + 3)
-    qs = list(range(CTX + 4 - CTX, CTX + 4))
-    assert list(cp) == [10 + (q // PAGE) % pps for q in qs]
-    assert list(co) == [q % PAGE for q in qs]
+
+    def position_of(cell, pos):
+        # the newest position <= pos that the ring keeps in `cell`
+        return pos - (pos - cell) % CTX
+
+    # mid-fill: positions 0..20 live — logical order, cell c holds
+    # position c, and the pages past the newest cell are scratch
+    ids = program.window_pages(table, 20)
+    assert ids.shape == (pps,) and ids.dtype == np.int32
+    live = 21
+    assert [position_of(c, 20) for c in range(live)] == list(range(21))
+    assert list(ids[:3]) == [10 + (q // PAGE) % pps for q in (0, 8, 16)]
+    assert set(ids[3:]) == {SCRATCH_PAGE}
+    # wrapped: position CTX + 3 — every cell live, the table as it is;
+    # the window is the last CTX positions, rotated: cells 0..3 hold
+    # the newest four, cell 4 the oldest RETAINED position
+    ids = program.window_pages(table, CTX + 3)
+    assert list(ids) == table
+    held = [position_of(c, CTX + 3) for c in range(CTX)]
+    assert sorted(held) == list(range(4, CTX + 4))
+    assert held[:5] == [CTX, CTX + 1, CTX + 2, CTX + 3, 4]
+    assert all(ids[c // PAGE] == table[(q // PAGE) % pps]
+               for c, q in enumerate(held))
     # nothing live yet (the first chunk's prior context)
-    cp, co = program.window_cells(table, -1)
-    assert set(cp) == {SCRATCH_PAGE}
+    ids = program.window_pages(table, -1)
+    assert set(ids) == {SCRATCH_PAGE}
+    # a live position's page must be mapped
+    with pytest.raises(TypeError):
+        program.window_pages([None] * pps, 3)
 
 
 def test_sequential_oracle_contract(program):
@@ -519,6 +536,39 @@ def test_program_lint_decode_records_clean():
     assert all(r.donate_argnums for r in records)
     findings = program_lint.run(records)
     assert findings == [], "; ".join(f.render() for f in findings)
+
+
+def test_pool_is_gathered_by_whole_pages():
+    """The structural pin on the lowered decode and chunk programs:
+    every gather from the page pool takes slices of one whole
+    [n_heads, page_size, head_dim] page of one layer's K or V plane —
+    one address a page — and none takes a single row (the per-cell
+    gather was bound by its 524,288 addresses a layer, not by its
+    bytes: PERF.md)."""
+    import re
+
+    import jax
+
+    model = CausalTransformer(vocab_size=VOCAB, d_model=32, n_heads=4,
+                              n_layers=2, max_ctx=CTX, seed=3).init()
+    prog = DecodeProgram(model, max_slots=SLOTS, page_size=PAGE)
+    pool_type = "x".join(map(str, prog.kv_shape)) + "xf32"
+    page = (1, 1, 1, model.n_heads, PAGE, model.head_dim)
+    gather = re.compile(
+        r'"stablehlo\.gather".*slice_sizes = array<i64: ([0-9, ]+)>'
+        r".* : \(tensor<" + pool_type + ">")
+    step, chunk, _ = prog.lint_records()
+    # a chunk only parks K/V: its last layer's attention feeds nothing
+    # and is traced away with its two reads
+    for rec, reads in ((step, 2 * model.n_layers),
+                       (chunk, 2 * (model.n_layers - 1))):
+        text = jax.jit(rec.fn, donate_argnums=rec.donate_argnums).lower(
+            *rec.example_args).as_text()
+        sizes = [tuple(int(n) for n in m.group(1).split(","))
+                 for m in map(gather.search, text.splitlines()) if m]
+        # K and V of every layer, and nothing else reads the pool
+        assert len(sizes) == reads, (rec.name, sizes)
+        assert set(sizes) == {page}, (rec.name, sizes)
 
 
 def test_decode_records_in_default_program_set():

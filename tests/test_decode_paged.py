@@ -144,45 +144,47 @@ def test_cow_divergence_mid_page(program):
 def test_ring_wrap_vs_contiguous_window_oracle(program):
     """Drive the SAME compiled step two ways: (a) the engine's ring
     table (pages_per_slot pages recycled in place), (b) a
-    never-recycling oracle that allocates a FRESH page per logical
-    block in a large pool and gathers only the window. Identical cell
-    values in identical logical order => bitwise equal tokens — page
-    recycling IS sliding-window attention."""
+    never-recycling oracle that takes a FRESH page per logical block
+    in a large pool: before a block's first write it `copy_page`s the
+    block it displaces from the ring (the positions that block still
+    holds inside the window) and maps the fresh page in its place.
+    Identical cell values in identical ring order => bitwise equal
+    tokens — page recycling IS sliding-window attention."""
     model = program.model
     big = DecodeProgram(model, max_slots=1, page_size=PAGE,
                         n_pages=64)   # never recycles within the run
     big.warmup(big.init_kv())
     prompt = [5, 3, 8, 13, 21, 34, 55, 29, 26, 12]
     n_new = CTX + 25                  # deep into wrap territory
-    ps, pps, c = PAGE, big.pages_per_slot, big.window
+    ps, pps = PAGE, big.pages_per_slot
 
     # (b) contiguous oracle: logical table grows forever
     kv = big.init_kv()
     logical = {}                      # block index -> physical page
-    nxt_page = 1
+    copies = 0
 
     def page_for(block):
-        nonlocal nxt_page
+        """The block's own page, taken at its first write."""
+        nonlocal kv, copies
         if block not in logical:
-            logical[block] = nxt_page
-            nxt_page += 1
+            logical[block] = len(logical) + 1
+            if block >= pps:          # it displaces block - pps
+                kv = big.copy_page(kv, logical[block - pps],
+                                   logical[block])
+                copies += 1
         return logical[block]
 
-    def cells(pos):
-        cp = np.full(c, SCRATCH_PAGE, np.int32)
-        co = np.zeros(c, np.int32)
-        live = min(pos + 1, c)
-        for j, q in enumerate(range(pos + 1 - live, pos + 1)):
-            cp[j] = logical[q // ps]
-            co[j] = q % ps
-        return cp, co
+    def ring_table(pos):
+        """Ring entry r -> the page of the newest block <= pos's that
+        maps to it."""
+        top = pos // ps
+        return [logical.get(top - (top - r) % pps) for r in range(pps)]
 
     for start in big.chunk_starts(len(prompt)):
         wp = page_for(start // ps)
-        cp, co = cells(start - 1) if start else (
-            np.full(c, SCRATCH_PAGE, np.int32), np.zeros(c, np.int32))
         kv = big.prefill_chunk(kv, prompt[start:start + ps], start,
-                               cp, co, wp)
+                               big.window_pages(ring_table(start),
+                                                start - 1), wp)
     oracle_toks = []
     pos, tok, suppress = len(prompt) - 1, prompt[-1], True
     while len(oracle_toks) < n_new:
@@ -191,15 +193,16 @@ def test_ring_wrap_vs_contiguous_window_oracle(program):
         if not suppress:
             wp[0] = page_for(pos // ps)
             wo[0] = pos % ps
-        cp, co = cells(pos)
+        ids = big.window_pages(ring_table(pos), pos)
         kv, nxt, _ = big.step(kv, np.array([tok], np.int32),
                               np.array([pos], np.int32),
-                              cp[None], co[None], wp, wo)
+                              ids[None], wp, wo)
         tok = int(np.asarray(nxt)[0])
         oracle_toks.append(tok)
         pos += 1
         suppress = False
     assert len(logical) > pps          # the oracle really outgrew a ring
+    assert copies == len(logical) - pps
 
     # (a) the engine: ring table, pages recycled in place
     eng = DecodeEngine(program=big)
@@ -209,6 +212,103 @@ def test_ring_wrap_vs_contiguous_window_oracle(program):
     assert eng.stats()["ctx_wraps"] >= 1
     # positions wrapped past the window but the stream finished whole
     assert len(h.tokens_so_far()) == n_new
+
+
+def _window_forward(params, tokens, n_heads, window, max_ctx):
+    """The plain reference: a float32 sliding-window causal decoder
+    forward over the whole sequence at once, written with jax.numpy
+    alone — position i attends to positions i - window < j <= i, the
+    learned positional table wraps with the position. Returns the
+    logits of every position."""
+    import jax
+    import jax.numpy as jnp
+
+    def norm(x, g, b):
+        mu = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+        return (x - mu) / jnp.sqrt(var + 1e-5) * g + b
+
+    n = len(tokens)
+    at = jnp.arange(n)
+    x = params["tok_emb"][jnp.asarray(tokens)] + params["pos_emb"][
+        at % max_ctx]
+    seen = (at[None, :] <= at[:, None]) & (at[None, :] > at[:, None]
+                                           - window)
+    for lp in params["layers"]:
+        h = norm(x, lp["ln1_g"], lp["ln1_b"])
+        q, k, v = (jnp.reshape(h @ lp[w], (n, n_heads, -1))
+                   for w in ("wq", "wk", "wv"))
+        s = jnp.einsum("ihd,jhd->hij", q, k) / jnp.sqrt(
+            jnp.float32(q.shape[-1]))
+        w = jax.nn.softmax(jnp.where(seen[None], s, -jnp.inf), axis=-1)
+        att = jnp.reshape(jnp.einsum("hij,jhd->ihd", w, v), (n, -1))
+        x = x + att @ lp["wo"]
+        h = norm(x, lp["ln2_g"], lp["ln2_b"])
+        x = x + jax.nn.gelu(h @ lp["w1"] + lp["b1"],
+                            approximate=True) @ lp["w2"] + lp["b2"]
+    return norm(x, params["lnf_g"], params["lnf_b"]) @ params["tok_emb"].T
+
+
+def test_engine_matches_plain_sliding_window_forward(program):
+    """Against mathematics, not against the same programs: a run that
+    prefills several chunks, decodes, and wraps the ring emits the
+    tokens a plain float32 sliding-window causal forward picks — the
+    page gather in ring order changes where a cell sits in the
+    reduction, never which cells are in it."""
+    rng = random.Random(41)
+    prompt = [rng.randrange(VOCAB) for _ in range(2 * PAGE + 5)]
+    n_new = CTX + 2 * PAGE + 3           # wraps, and recycles 2 pages
+    eng = DecodeEngine(program=program)
+    h = eng.submit(prompt, n_new)
+    toks = _drain(eng, [h])[0]
+    st = eng.stats()
+    assert st["prefill_chunks"] == 3 and st["ctx_wraps"] >= 2
+    assert len(toks) == n_new
+    model = program.model
+    logits = np.asarray(_window_forward(
+        model.params, prompt + toks[:-1], model.n_heads, CTX,
+        model.max_ctx))[len(prompt) - 1:]
+    assert logits.shape == (n_new, VOCAB)
+    # the served token's logit lies within 1e-4 of the reference's
+    # best at every step, and is the reference's own pick
+    best = logits.max(axis=-1)
+    served = logits[np.arange(n_new), toks]
+    assert float(np.max(best - served)) <= 1e-4
+    assert toks == [int(t) for t in logits.argmax(axis=-1)]
+
+
+def test_kv_page_counters_follow_a_hand_worked_schedule(program):
+    """`stats()["kv_pages_gathered"]` counts every page id a decode
+    step hands the program (slots x pages_per_slot a step) and
+    `["kv_pages_live"]` those off scratch: pages holding a live cell
+    of a decoding slot. Worked by hand for two requests."""
+    pps = program.pages_per_slot
+    eng = DecodeEngine(program=program, prefix_cache=False,
+                       max_prefills_per_step=1)
+    assert eng.stats()["kv_pages_gathered"] == 0
+    assert eng.stats()["kv_pages_live"] == 0
+    # A: 10 tokens (2 chunks), 3 new. One chunk a step, so step 1
+    # prefills chunk 0 and no slot decodes; step 2 prefills chunk 1
+    # and A decodes at position 9 (2 pages live), then 10 and 11
+    a = eng.submit(list(range(1, 11)), 3)
+    assert eng.step_once()
+    assert eng.stats()["steps"] == 0          # nothing decoded yet
+    assert eng.stats()["kv_pages_gathered"] == 0
+    for k in (1, 2, 3):
+        assert eng.step_once()
+        st = eng.stats()
+        assert st["steps"] == k
+        assert st["kv_pages_gathered"] == k * SLOTS * pps
+        assert st["kv_pages_live"] == 2 * k
+    assert a.done
+    # B: 2 * PAGE - 1 tokens, 4 new: positions 14, 15 (2 pages live),
+    # then 16, 17 (a third page)
+    b = eng.submit(list(range(3, 2 * PAGE + 2)), 4)
+    _drain(eng, [b])
+    st = eng.stats()
+    assert st["steps"] == 7
+    assert st["kv_pages_gathered"] == 7 * SLOTS * pps
+    assert st["kv_pages_live"] == 6 + 2 + 2 + 3 + 3
 
 
 # ========================================== durability on paged cache
