@@ -60,6 +60,13 @@ FULL = {
     "serve": dict(vocab_size=512, d_model=128, n_heads=4, n_layers=4,
                   max_ctx=128),
     "mesh": dict(batch=128, hw=224, n_classes=1000, steps=4),
+    # the tiny preset of the latent-attention, sparse-expert block
+    # (tests/test_latent_moe.py), 4 of its 8 experts held, in bfloat16
+    "latent": dict(vocab_size=512, hidden=64, n_heads=4, q_lora_rank=24,
+                   kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8,
+                   v_head_dim=16, dense_ff=128, moe_ff=32, n_experts=8,
+                   top_k=2, experts_held=(0, 1, 2, 5), n_dense_layers=1,
+                   n_moe_layers=2, max_ctx=128, param_dtype="bfloat16"),
 }
 
 
@@ -454,6 +461,55 @@ def phase_serve(compute_dtype, **model_cfg) -> dict:
             "first_tokens": outs[0][:8]}
 
 
+def phase_latent(**model_cfg) -> dict:
+    """The second model family through the same `DecodeProgram` and
+    engine: latent page pool, absorbed and expanded attention, the
+    expert layer with its counters; engine against oracle, bitwise."""
+    import jax
+
+    from deeplearning4j_tpu.engine.decode_program import DecodeProgram
+    from deeplearning4j_tpu.serving.continuous import (
+        DecodeEngine,
+        sequential_decode,
+    )
+    from deeplearning4j_tpu.zoo.latent_moe import LatentMoETransformer
+
+    max_new = 24
+    model = LatentMoETransformer(seed=SEED, **model_cfg).init()
+    prog = DecodeProgram(model, max_slots=8, page_size=16)
+    eng = DecodeEngine(program=prog)
+    eng.kv = prog.warmup(eng.kv)
+    jax.block_until_ready(eng.kv)
+    warm_counts = prog.trace_stats()["trace_counts"]
+    mixed, twins = _prompts(model.vocab_size, prog.page_size,
+                            model.max_ctx)
+    eng.start()
+    try:
+        handles = [eng.submit(p, max_new) for p in mixed + twins[:1]]
+        outs = [h.result(timeout_s=300) for h in handles]
+        outs.append(eng.submit(twins[1], max_new).result(timeout_s=300))
+    finally:
+        eng.stop()
+    stats = eng.stats()
+    check(prog.trace_stats()["trace_counts"] == warm_counts,
+          f"traffic retraced: {prog.trace_stats()['trace_counts']}")
+    for prompt, got in zip(mixed + twins, outs):
+        want = sequential_decode(prog, prompt, max_new)[1]
+        check(got == want, f"engine {got} != oracle {want} for a "
+              f"{len(prompt)}-token prompt")
+    check(stats["prefix_requests_hit"] >= 1,
+          f"no prefix hit: {stats['prefix_requests_hit']}")
+    pairs = stats["moe_assignments"]
+    check(0 < stats["moe_assignments_held"] < pairs,
+          f"held {stats['moe_assignments_held']} of {pairs} pairs")
+    return {"pool": list(prog.kv_shape), "pool_dtype": model.kv_dtype,
+            "requests": len(outs), "decode_steps": stats["steps"],
+            "moe_assignments": stats["moe_assignments"],
+            "moe_assignments_held": stats["moe_assignments_held"],
+            "prefix_requests_hit": stats["prefix_requests_hit"],
+            "trace_counts": warm_counts, "first_tokens": outs[0][:8]}
+
+
 # -------------------------------------------------------------------- mesh
 # Why the three runs may differ at all: they are three programs. On four
 # devices each holds 32 of the 128 rows, so every batch reduction (the
@@ -593,6 +649,7 @@ def main(argv=None, sizes=FULL) -> int:
         for compute_dtype in (None, "bfloat16"):
             run_phase("serve", phase_serve, compute_dtype=compute_dtype,
                       **sizes["serve"])
+        run_phase("latent", phase_latent, **sizes["latent"])
     report("done", seconds=round(time.perf_counter() - t_start, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -31,23 +31,40 @@ constraint that makes one-program XLA serving work at all, per
                 page (all layers, K and V) inside the donated pool —
                 what a slot pays to diverge from a shared page.
 
-Physical pool layout (the tensor-layout discipline of Tensor
-Processing Primitives, arXiv 2104.05755 — the page indirection is a
-hand-fused gather/scatter pair): ONE preallocated buffer
-``[n_layers, 2, n_pages, n_heads, page_size, head_dim]`` — page-major
-so one page id addresses every layer's K and V rows at once (one
-page-table entry per page, not per layer, and one page of one layer
-is [n_heads, page_size, head_dim] contiguous: the unit the window is
-read in), HEAD-MAJOR within a page so a gathered window arrives
-[..., pages, n_heads, page_size, head_dim] and both attention
-contractions batch over (slot, head) with no authored transpose (the
-first slot-major attempt made XLA transpose 40% of program traffic
-per step — caught by prog-transpose-churn, documented in PERF.md),
-head_dim innermost for lane alignment. Page 0 is SCRATCH: the write
-target for inactive/suppressed rows and the gather target for pages
-with no live cell — never mapped live, and its (possibly garbage)
-bytes are zeroed out inside the attention primitives before any
-contraction.
+What is the same for every model lives here: the three programs and
+their compile-once keys, the page indirection (`window_pages`, scratch
+page 0), donation, trace counters, the greedy token and its finite
+verdict, and the named scopes of the work every model does (`embed`,
+`kv_write`, `kv_read`, `head`, `kv_copy`). What a model is comes from
+the model, and nothing here asks which one it is:
+
+  kv_shape(n_pages, page_size), kv_dtype, kv_page_axis
+        the pool: one buffer whose `kv_page_axis` is the physical page
+        index, so one page id addresses every layer's cells of a page
+        (one page-table entry per page, not per layer). GPT-2 keeps
+        ``[L, 2, pages, H, page, D]`` float32, head-major within a
+        page (nn/attention.py says why); a latent-attention model one
+        row a token, ``[L, pages, page, row]`` (nn/latent_attention.py).
+  embed(params, tokens, positions) -> x
+  project(lp, x, positions) -> (q, cell)
+        a layer's query and what it caches of these positions
+  write_cells(pool, li, cell, page, offset) -> pool
+  read_window(pool, li, page_ids) -> window
+        the scatter and the gather in the pool's own layout; `page`
+        and `offset` are per slot in the decode step, one page and its
+        offsets in a chunk
+  decode_finish(lp, x, q, window, live, active) -> (x, counts | None)
+  chunk_finish(lp, x, q, cell, window, start) -> x
+        attention over the gathered window and the layer's feed-forward
+        half; `counts` is an int32 vector of `step_counters` (the
+        model's names for it) over the `active` rows, summed here over
+        layers and steps and read through `counters()`
+  head(params, x) -> logits
+
+Page 0 is SCRATCH: the write target for inactive/suppressed rows and
+the gather target for pages with no live cell — never mapped live, and
+its (possibly garbage) bytes are zeroed out inside the attention
+primitives before any contraction.
 
 All three programs DONATE the pool: updates are in-place, the caller
 rebinds — program-lint's prog-unhonored-donation rule verifies the
@@ -74,7 +91,8 @@ entries so MFU gauges and compile-event cost digests follow.
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+import threading
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -92,8 +110,8 @@ SCRATCH_PAGE = 0
 
 
 class DecodeProgram:
-    """One CausalTransformer's compiled chunk-prefill/decode/page-copy
-    programs over a fixed slot batch and a fixed physical page pool.
+    """One model's compiled chunk-prefill/decode/page-copy programs
+    over a fixed slot batch and a fixed physical page pool.
     Holds NO request state — serving/continuous.py's DecodeEngine owns
     slots, the page table, the prefix trie, and refcounts; this class
     owns shapes, compilation, the pool layout, and the host-side
@@ -130,13 +148,17 @@ class DecodeProgram:
         # engine's stats (and the tracing story) can report how many
         # device dispatches a generation actually cost
         self._dispatches = {"step": 0, "chunk": 0, "copy": 0}
+        # the model's per-step counts (`step_counters`): totals on the
+        # host, and the device vectors of steps not yet added to them
+        self._counter_lock = threading.Lock()
+        self._counter_totals = np.zeros(len(model.step_counters),
+                                        np.int64)
+        self._counter_pending: List = []
 
     # ---------------------------------------------------------- layout
     @property
     def kv_shape(self) -> Tuple[int, ...]:
-        m = self.model
-        return (m.n_layers, 2, self.n_pages, m.n_heads, self.page_size,
-                m.head_dim)
+        return tuple(self.model.kv_shape(self.n_pages, self.page_size))
 
     def init_kv(self):
         """The preallocated physical page pool (zeros; cells are
@@ -144,7 +166,7 @@ class DecodeProgram:
         readable otherwise)."""
         import jax.numpy as jnp
 
-        return jnp.zeros(self.kv_shape, jnp.float32)
+        return jnp.zeros(self.kv_shape, self.model.kv_dtype)
 
     def chunk_starts(self, prompt_len: int,
                      from_token: int = 0) -> List[int]:
@@ -219,16 +241,7 @@ class DecodeProgram:
         import jax
         import jax.numpy as jnp
 
-        from deeplearning4j_tpu.nn.attention import (
-            block_decode_finish,
-            decode_qkv,
-            layer_norm,
-            lm_logits,
-        )
-
         model = self.model
-        n_heads = model.n_heads
-        max_ctx = model.max_ctx
         cache = model._jit_cache
 
         def decode_fn(params, pool, tokens, positions, page_ids,
@@ -238,38 +251,33 @@ class DecodeProgram:
             # index (a reader sums over layers): they are what the
             # device trace's operations are summed by
             # (benchmark/timeline.py), so they outlive a change to how
-            # the work is done.
+            # the work is done. The model's functions name their own
+            # (`qkv`, `attn`, `mlp`, ...).
             with jax.named_scope("embed"):
-                # logical positions grow unbounded past max_ctx (ring
-                # wrap); the learned positional table wraps with them
-                x = (params["tok_emb"][tokens]
-                     + params["pos_emb"][positions % max_ctx])
+                x = model.embed(params, tokens, positions)
             live = jnp.minimum(positions + 1, self.window)
+            # a row whose window maps no page is an empty slot: its
+            # garbage is not counted
+            active = page_ids[:, 0] != SCRATCH_PAGE
+            counts = []
             for li, lp in enumerate(params["layers"]):
-                with jax.named_scope("qkv"):
-                    q, k, v = decode_qkv(lp, x, n_heads)
-                # scatter: pool[li, io, wp[s], h, wo[s]] = k[s, h] —
-                # the write cell is host-chosen (suppressed rows
-                # target scratch), advanced indices broadcast per slot
+                q, cell = model.project(lp, x, positions)
+                # scatter: the write cell is host-chosen (suppressed
+                # rows target scratch), advanced indices broadcast per
+                # slot
                 with jax.named_scope("kv_write"):
-                    pool = pool.at[li, 0, write_page, :,
-                                   write_off].set(k)
-                    pool = pool.at[li, 1, write_page, :,
-                                   write_off].set(v)
-                # gather: [S, P, H, page_size, D], each slot's window
-                # a whole page at a time in ring order — the
-                # virtual-memory read. ONE gather over the whole pool
-                # (layer and K/V plane are constant indices of it):
-                # `pool[li, 0][page_ids]` makes the chip's compiler
-                # copy the layer's plane out first, 0.8 ms a layer
-                # beside the 0.6 ms the gather itself takes (PERF.md)
+                    pool = model.write_cells(pool, li, cell, write_page,
+                                             write_off)
+                # gather: each slot's window a whole page at a time in
+                # ring order — the virtual-memory read
                 with jax.named_scope("kv_read"):
-                    kg = pool[li, 0, page_ids]
-                    vg = pool[li, 1, page_ids]
-                x = block_decode_finish(lp, x, q, kg, vg, live)
+                    window = model.read_window(pool, li, page_ids)
+                x, c = model.decode_finish(lp, x, q, window, live,
+                                           active)
+                if c is not None:
+                    counts.append(c)
             with jax.named_scope("head"):
-                xf = layer_norm(x, params["lnf_g"], params["lnf_b"])
-                logits = lm_logits(xf, params["tok_emb"])
+                logits = model.head(params, x)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 # per-slot finite-logits verdict (the NonFiniteGuard
                 # discipline applied to serving): ONE fused reduction
@@ -280,6 +288,8 @@ class DecodeProgram:
                 # the slot AND its private pages, purges its trie
                 # entries, and replays the request on a healthy slot)
                 ok = jnp.all(jnp.isfinite(logits), axis=-1)
+            if counts:
+                return pool, nxt, ok, sum(counts)
             return pool, nxt, ok
 
         return jax.jit(decode_fn, donate_argnums=(1,))
@@ -294,64 +304,55 @@ class DecodeProgram:
         import jax
         import jax.numpy as jnp
 
-        from deeplearning4j_tpu.nn.attention import (
-            block_chunk_prefill,
-            decode_qkv,
-        )
-
         model = self.model
-        n_heads = model.n_heads
         t = self.page_size
         cache = model._jit_cache
         offs = np.arange(t)                  # the page's cell offsets
 
         def chunk_fn(params, pool, tokens, start, page_ids, write_page):
             cache.record_trace(trace_key)
+            positions = start + jnp.arange(t)
             with jax.named_scope("embed"):
-                x = (params["tok_emb"][tokens]
-                     + params["pos_emb"][start + jnp.arange(t)])
+                x = model.embed(params, tokens, positions)
             for li, lp in enumerate(params["layers"]):
-                # project + PARK the chunk's K/V before gathering the
-                # prior cells — the same scatter-then-gather order as
+                # project + PARK the chunk's cells before gathering the
+                # prior ones — the same scatter-then-gather order as
                 # the decode step, which is what lets XLA update the
                 # donated pool in place (a gather of the PRE-scatter
                 # pool forced two full-pool copies). Safe because the
                 # prior pages can never alias `write_page`: prefill
                 # never wraps (prompt <= window), so the page ids name
-                # earlier blocks' pages or scratch, and the
-                # advanced `offs` index lands [T, H, D] rows in the
-                # head-major page without an authored transpose.
-                with jax.named_scope("qkv"):
-                    q, k, v = decode_qkv(lp, x, n_heads)
+                # earlier blocks' pages or scratch.
+                q, cell = model.project(lp, x, positions)
                 with jax.named_scope("kv_write"):
-                    pool = pool.at[li, 0, write_page, :, offs].set(k)
-                    pool = pool.at[li, 1, write_page, :, offs].set(v)
+                    pool = model.write_cells(pool, li, cell, write_page,
+                                             offs)
                 with jax.named_scope("kv_read"):
-                    kg = pool[li, 0, page_ids]      # [P, H, ps, D]
-                    vg = pool[li, 1, page_ids]
-                x = block_chunk_prefill(lp, x, n_heads, kg, vg, start,
-                                        qkv=(q, k, v))
+                    window = model.read_window(pool, li, page_ids)
+                x = model.chunk_finish(lp, x, q, cell, window, start)
             return pool
 
         return jax.jit(chunk_fn, donate_argnums=(1,))
 
     def _build_copy(self, trace_key: str):
         """Compile the copy-on-write primitive: duplicate one physical
-        page (every layer, K and V) inside the donated pool."""
+        page (every layer's cells of it) inside the donated pool."""
         import jax
 
         cache = self.model._jit_cache
-        m = self.model
-        shape = (m.n_layers, 2, 1, m.n_heads, self.page_size,
-                 m.head_dim)
+        axis = self.model.kv_page_axis
+        shape = list(self.kv_shape)
+        shape[axis] = 1
+
+        def at(page):
+            return tuple(page if i == axis else 0
+                         for i in range(len(shape)))
 
         def copy_fn(pool, src, dst):
             cache.record_trace(trace_key)
             with jax.named_scope("kv_copy"):
-                page = jax.lax.dynamic_slice(
-                    pool, (0, 0, src, 0, 0, 0), shape)
-                return jax.lax.dynamic_update_slice(
-                    pool, page, (0, 0, dst, 0, 0, 0))
+                page = jax.lax.dynamic_slice(pool, at(src), shape)
+                return jax.lax.dynamic_update_slice(pool, page, at(dst))
 
         return jax.jit(copy_fn, donate_argnums=(0,))
 
@@ -373,12 +374,37 @@ class DecodeProgram:
 
         fn = self._decode_program()
         self._dispatches["step"] += 1
-        return fn(self.model.params, kv,
-                  jnp.asarray(tokens, jnp.int32),
-                  jnp.asarray(positions, jnp.int32),
-                  jnp.asarray(page_ids, jnp.int32),
-                  jnp.asarray(write_page, jnp.int32),
-                  jnp.asarray(write_off, jnp.int32))
+        out = fn(self.model.params, kv,
+                 jnp.asarray(tokens, jnp.int32),
+                 jnp.asarray(positions, jnp.int32),
+                 jnp.asarray(page_ids, jnp.int32),
+                 jnp.asarray(write_page, jnp.int32),
+                 jnp.asarray(write_off, jnp.int32))
+        if len(out) > 3:
+            self._note_counts(out[3])
+        return out[:3]
+
+    def _note_counts(self, counts) -> None:
+        """The step's counts ride its own fetch: their copy to the host
+        starts with the dispatch, and they are added to the totals one
+        step late, when the caller has long had that step's tokens."""
+        counts.copy_to_host_async()
+        with self._counter_lock:
+            self._counter_pending.append(counts)
+            self._add_counts(keep=1)
+
+    def _add_counts(self, keep: int) -> None:
+        while len(self._counter_pending) > keep:
+            self._counter_totals += np.asarray(
+                self._counter_pending.pop(0))
+
+    def counters(self) -> Dict[str, int]:
+        """The model's `step_counters`, summed over every decode step
+        dispatched so far (waits for the newest if it still runs)."""
+        with self._counter_lock:
+            self._add_counts(keep=0)
+            return {k: int(v) for k, v in zip(self.model.step_counters,
+                                              self._counter_totals)}
 
     def prefill_chunk(self, kv, chunk: Sequence[int], start: int,
                       page_ids, write_page: int):
@@ -402,7 +428,7 @@ class DecodeProgram:
 
     def copy_page(self, kv, src: int, dst: int):
         """Copy-on-write: duplicate physical page `src` into `dst`
-        (all layers, K and V). `kv` is donated — rebind."""
+        (every layer's cells of it). `kv` is donated — rebind."""
         import jax.numpy as jnp
 
         fn = self._copy_program()
@@ -441,8 +467,7 @@ class DecodeProgram:
         """ProgramRecords for the decode step, the chunk prefill, and
         the page copy — built through the same cache paths the engine
         uses (policy registered), traced/lowered by the lint but never
-        executed. Donation on the [n_layers, 2, n_pages, n_heads,
-        page_size, head_dim] pool is DECLARED on every record
+        executed. Donation on the page pool is DECLARED on every record
         (donate_argnums) so prog-unhonored-donation verifies the
         executable alias map genuinely aliases the pool in place — a
         silently-copied pool would double decode memory AND pay a
@@ -471,7 +496,8 @@ class DecodeProgram:
                               jnp.zeros((s, p), jnp.int32), zs, zs),
                 donate_argnums=(1,),
                 precision_policy=self.precision_policy, source=source,
-                consumed_outputs=(0, 1, 2)),
+                consumed_outputs=tuple(range(
+                    3 + bool(model.step_counters)))),
             ProgramRecord(
                 name=f"decode_prefill_c{self.page_size}",
                 fn=getattr(chunk_fn, "__wrapped__", chunk_fn),

@@ -66,6 +66,33 @@ def layer_norm(x, gain, bias, eps: float = 1e-5):
     return (x - mu) / jnp.sqrt(var + eps) * gain + bias
 
 
+def rms_norm(x, gain, eps: float = 1e-5):
+    """RMSNorm over the trailing axis, in float32 whatever comes in."""
+    import jax.numpy as jnp
+
+    x = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x / jnp.sqrt(ms + eps) * gain
+
+
+def mm(x, w):
+    """x @ w with the operands in the weight's stored dtype and the
+    sum in float32: bfloat16 weights make it the MXU's native product,
+    float32 weights (the CPU tests) leave it a float32 one."""
+    import jax.numpy as jnp
+
+    return jnp.matmul(x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """W_down(silu(W_gate x) * W_up x): the feed-forward half of the
+    gated blocks, dense layer and single expert alike."""
+    import jax
+
+    return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+
+
 def qkv_heads(lp: dict, x, n_heads: int):
     """Project hidden states to per-head q/k/v: [..., d_model] ->
     three [..., n_heads, head_dim] tensors (head_dim innermost)."""

@@ -1,0 +1,101 @@
+"""A sparse-expert layer that is told which experts it holds.
+
+Expert parallelism spreads a layer's routed experts over chips; each
+chip routes every token over ALL experts (the router keeps its full
+width and its experts per token) and computes the part of the result
+that the experts it holds give, plus the shared expert that every chip
+computes alike. This module is that one chip's layer: no exchange, and
+nothing that stands in for the absent chips — what their experts would
+have added is simply not in the result.
+
+    s = sigmoid(x W_g)                     all experts, float32
+    top-k of s;  w = scale * s_top / sum(s_top)
+    y = sum_{i in top-k, held} w_i E_i(x)  +  E_shared(x)
+    E(x) = W_down(silu(W_gate x) * W_up x)
+
+Per-row independence (the property the decode oracle's byte identity
+rests on, engine/decode_program.py): no token is dropped and no
+capacity is shared. Every held expert runs over every row and a row's
+weight for an expert it did not choose is exactly 0, so a row's result
+is a function of that row alone, whatever the other rows route to.
+With 16 experts of 94 MB held and 32 rows a step, the experts' weights
+are what a step moves; the rows a skinny product wastes cost nothing
+beside them.
+"""
+
+from __future__ import annotations
+
+# over the active rows of a step, summed over the expert layers: the
+# token-expert pairs routed (all experts), those that fell on a held
+# expert, the most loaded held expert's, and the held experts that got
+# at least one
+COUNTERS = ("moe_assignments", "moe_assignments_held",
+            "moe_max_held_load", "moe_experts_hit")
+
+
+def route(x, router_w, top_k: int, scale: float):
+    """(expert ids [.., k], weights [.., k]) of each row: sigmoid
+    scores over all experts in float32 at the highest matmul precision
+    (2M parameters: nothing beside the experts, and a near-tie between
+    the k-th and the next expert should turn on the stream's rounding,
+    not on the router's own), the k largest, renormalised and scaled."""
+    import jax
+    import jax.numpy as jnp
+
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top_s, top_i = jax.lax.top_k(scores, top_k)
+    return top_i, scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+
+
+def held_weights(top_i, top_w, held):
+    """[.., k] routing -> [.., len(held)]: each row's weight for each
+    held expert, 0 where the row did not choose it."""
+    import jax.numpy as jnp
+
+    ids = jnp.asarray(held, jnp.int32)
+    hit = top_i[..., :, None] == ids                    # [.., k, E]
+    return jnp.sum(jnp.where(hit, top_w[..., :, None], 0.0), axis=-2)
+
+
+def expert_layer(lp: dict, x, held, top_k: int, scale: float,
+                 active=None):
+    """x [N, h] (normed, float32) -> (y [N, h], counts). `lp` has the
+    router `router` [h, n_experts], the held experts stacked in the
+    order of `held` (`eg`, `eu` [E, h, f]; `ed` [E, f, h]) and the
+    shared expert (`sg`, `su`, `sd`). `counts` is the int32 vector of
+    COUNTERS over the rows `active` marks (None: no counts)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.attention import gated_mlp
+
+    with jax.named_scope("moe/router"):
+        top_i, top_w = route(x, lp["router"], top_k, scale)
+        w = held_weights(top_i, top_w, held)            # [N, E]
+    with jax.named_scope("moe/experts"):
+        xe = x.astype(lp["eg"].dtype)
+        f32 = jnp.float32
+        g = jnp.einsum("nh,ehf->enf", xe, lp["eg"],
+                       preferred_element_type=f32)
+        u = jnp.einsum("nh,ehf->enf", xe, lp["eu"],
+                       preferred_element_type=f32)
+        act = (jax.nn.silu(g) * u).astype(lp["ed"].dtype)
+        ye = jnp.einsum("enf,efh->enh", act, lp["ed"],
+                        preferred_element_type=f32)
+        # the weighted sum elementwise in float32: a float32 dot
+        # would round its operands to bfloat16 on the chip
+        y = jnp.sum(ye * jnp.transpose(w)[:, :, None], axis=0)
+    with jax.named_scope("moe/shared"):
+        y = y + gated_mlp(x, lp["sg"], lp["su"], lp["sd"])
+    if active is None:
+        return y, None
+    with jax.named_scope("moe/router"):
+        load = jnp.sum((w > 0) & active[:, None], axis=0,
+                       dtype=jnp.int32)                  # [E]
+        counts = jnp.stack([
+            jnp.sum(active, dtype=jnp.int32) * top_k,
+            jnp.sum(load), jnp.max(load),
+            jnp.sum(load > 0, dtype=jnp.int32)])
+    return y, counts

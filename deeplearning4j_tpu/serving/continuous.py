@@ -1769,6 +1769,9 @@ class DecodeEngine:
             # they gathered, and those that held a live cell
             "kv_pages_gathered": self._kv_pages_gathered,
             "kv_pages_live": self._kv_pages_live,
+            # what the model counts in its own decode steps (an expert
+            # layer's routed pairs); no key where it counts nothing
+            **self.program.counters(),
             "trie_blocks": (len(self._trie)
                             if self._trie is not None else 0),
             "steps": self._steps,

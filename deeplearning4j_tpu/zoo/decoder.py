@@ -11,10 +11,12 @@ NeuralNetConfiguration — generation is served, not fit: the model owns
 a plain parameter pytree plus the package-standard `JitCache`
 (recompile forensics, precision-policy registration), and
 engine/decode_program.DecodeProgram compiles its prefill/decode
-programs from the nn/attention.py primitives. Greedy (argmax)
-sampling keeps every emitted token a deterministic function of the
-prompt — the property the byte-identical slot-churn oracle in
-tests/test_decode.py pins.
+programs from the model's own description of itself (the block at the
+end of the class: the page pool's shape, and the per-layer functions
+over the nn/attention.py primitives). Greedy (argmax) sampling keeps
+every emitted token a deterministic function of the prompt — the
+property the byte-identical slot-churn oracle in tests/test_decode.py
+pins.
 
 Dims default MXU-friendly (d_model/head_dim multiples of 8, vocab a
 pow2) but stay CPU-lintable; `compute_dtype` mirrors the rest of the
@@ -100,6 +102,71 @@ class CausalTransformer:
         params["layers"] = tuple(layers)
         self.params = params
         return self
+
+    # ----------------------------------- what DecodeProgram builds from
+    # (the contract is in engine/decode_program.py's docstring)
+    kv_dtype = np.float32
+    kv_page_axis = 2
+    step_counters = ()
+
+    def kv_shape(self, n_pages: int, page_size: int):
+        """Page-major so one page id addresses every layer's K and V;
+        head-major within a page, head_dim innermost (the layout notes
+        are in decode_program.py and nn/attention.py)."""
+        return (self.n_layers, 2, n_pages, self.n_heads, page_size,
+                self.head_dim)
+
+    def embed(self, params, tokens, positions):
+        # logical positions grow unbounded past max_ctx (ring wrap);
+        # the learned positional table wraps with them
+        return (params["tok_emb"][tokens]
+                + params["pos_emb"][positions % self.max_ctx])
+
+    def project(self, lp, x, positions):
+        """(q, (k, v)) of the positions in `x`; learned positions are
+        in the stream already."""
+        import jax
+
+        from deeplearning4j_tpu.nn.attention import decode_qkv
+
+        with jax.named_scope("qkv"):
+            q, k, v = decode_qkv(lp, x, self.n_heads)
+        return q, (k, v)
+
+    def write_cells(self, pool, li, cell, page, offset):
+        """pool[li, io, page, h, offset] = k or v: the advanced indices
+        (`page` per slot or one page, `offset` per slot or the page's
+        offsets) broadcast, and land [.., H, D] rows in the head-major
+        page without an authored transpose."""
+        k, v = cell
+        pool = pool.at[li, 0, page, :, offset].set(k)
+        return pool.at[li, 1, page, :, offset].set(v)
+
+    def read_window(self, pool, li, page_ids):
+        """[.., P, H, page_size, D] each of K and V. ONE gather over
+        the whole pool (layer and K/V plane are constant indices of
+        it): `pool[li, 0][page_ids]` makes the chip's compiler copy
+        the layer's plane out first, 0.8 ms a layer beside the 0.6 ms
+        the gather itself takes (PERF.md)."""
+        return pool[li, 0, page_ids], pool[li, 1, page_ids]
+
+    def decode_finish(self, lp, x, q, window, live, active):
+        from deeplearning4j_tpu.nn.attention import block_decode_finish
+
+        del active
+        return block_decode_finish(lp, x, q, *window, live), None
+
+    def chunk_finish(self, lp, x, q, cell, window, start):
+        from deeplearning4j_tpu.nn.attention import block_chunk_prefill
+
+        return block_chunk_prefill(lp, x, self.n_heads, *window, start,
+                                   qkv=(q,) + cell)
+
+    def head(self, params, x):
+        from deeplearning4j_tpu.nn.attention import layer_norm, lm_logits
+
+        return lm_logits(layer_norm(x, params["lnf_g"], params["lnf_b"]),
+                         params["tok_emb"])
 
     # ----------------------------------------------------------- facts
     def num_params(self) -> int:

@@ -1,0 +1,258 @@
+"""LatentMoETransformer: a decoder of the kind deployed since 2024 —
+multi-head latent attention over a low-rank cache, rotary positions,
+RMSNorm before and after each sublayer ("sandwich"), a gated MLP in the
+leading dense layers and a sparse-expert layer (routed experts beside a
+shared one) in the rest, an untied head. The block of DeepSeek-V3 and
+of openPangu-Ultra-MoE-718B (`pangu_ultra_moe`), whose published widths
+the benchmark serves (benchmark/configs/pangu-ultra-moe-718b.json).
+
+    a = x + norm_post_attn(MLA(norm_in(x)))
+    y = a + norm_post_mlp(FFN(norm_pre_mlp(a)))
+
+Like zoo/decoder.CausalTransformer it is served, not fit: a parameter
+pytree, a JitCache, and the description engine/decode_program.py builds
+its three programs from (the block at the end of the class). The
+mathematics is nn/latent_attention.py and nn/moe.py.
+
+The expert layer is told which experts it holds (`experts_held`): the
+router scores all `n_experts`, a token keeps its `top_k`, and the layer
+computes the part of the result the held experts give, plus the shared
+expert — one chip's share of an expert-parallel deployment, run here
+without its exchange. `param_dtype="bfloat16"` stores matrices,
+embedding and the latent page pool in bfloat16 (norm gains stay
+float32); every product then sums in float32 and norms, rotary,
+softmax, router scores and the residual stream are float32.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Optional, Sequence
+
+import numpy as np
+
+from deeplearning4j_tpu.nn.jit_cache import JitCache
+
+
+class LatentMoETransformer:
+    def __init__(self, vocab_size: int = 512, hidden: int = 64,
+                 n_heads: int = 4, q_lora_rank: int = 24,
+                 kv_lora_rank: int = 16, qk_nope_dim: int = 16,
+                 qk_rope_dim: int = 8, v_head_dim: int = 16,
+                 dense_ff: int = 128, moe_ff: int = 32,
+                 n_experts: int = 8, top_k: int = 2,
+                 experts_held: Optional[Sequence[int]] = None,
+                 n_shared: int = 1, routed_scale: float = 1.0,
+                 n_dense_layers: int = 1, n_moe_layers: int = 2,
+                 max_ctx: int = 128, rope_theta: float = 10000.0,
+                 eps: float = 1e-5, seed: int = 123,
+                 param_dtype: str = "float32"):
+        if max_ctx & (max_ctx - 1):
+            raise ValueError(f"max_ctx must be a power of two: {max_ctx}")
+        if qk_rope_dim % 2:
+            raise ValueError(f"rotary pairs need an even qk_rope_dim: "
+                             f"{qk_rope_dim}")
+        held = tuple(range(n_experts)) if experts_held is None \
+            else tuple(int(e) for e in experts_held)
+        if len(set(held)) != len(held) or not all(
+                0 <= e < n_experts for e in held):
+            raise ValueError(f"experts_held {held} are not distinct ids "
+                             f"under n_experts {n_experts}")
+        self.vocab_size, self.hidden = int(vocab_size), int(hidden)
+        self.n_heads = int(n_heads)
+        self.q_lora_rank = int(q_lora_rank)
+        self.kv_lora_rank = int(kv_lora_rank)
+        self.qk_nope_dim, self.qk_rope_dim = int(qk_nope_dim), int(qk_rope_dim)
+        self.v_head_dim = int(v_head_dim)
+        self.dense_ff, self.moe_ff = int(dense_ff), int(moe_ff)
+        self.n_experts, self.top_k = int(n_experts), int(top_k)
+        self.experts_held = held
+        self.n_shared = int(n_shared)
+        self.routed_scale = float(routed_scale)
+        self.n_dense_layers = int(n_dense_layers)
+        self.n_moe_layers = int(n_moe_layers)
+        self.n_layers = self.n_dense_layers + self.n_moe_layers
+        self.max_ctx = int(max_ctx)
+        self.rope_theta, self.eps = float(rope_theta), float(eps)
+        self.seed = int(seed)
+        # matrices, embedding and page pool; "float32" or "bfloat16"
+        self.param_dtype = str(param_dtype)
+        # the precision-policy label DecodeProgram registers
+        self.compute_dtype = None if self.param_dtype == "float32" \
+            else self.param_dtype
+        self.params = None
+        self._jit_cache = JitCache()
+
+    # ----------------------------------------------------------- shapes
+    def param_shapes(self) -> dict:
+        """Leaf shapes; matrices are [in, out], the held experts
+        stacked in the order of `experts_held`."""
+        h, heads = self.hidden, self.n_heads
+        attn = {
+            "norm_in": (h,), "wq_a": (h, self.q_lora_rank),
+            "q_norm": (self.q_lora_rank,),
+            "wq_b": (self.q_lora_rank,
+                     heads * (self.qk_nope_dim + self.qk_rope_dim)),
+            "wkv_a": (h, self.kv_lora_rank + self.qk_rope_dim),
+            "kv_norm": (self.kv_lora_rank,),
+            "wkv_b": (self.kv_lora_rank,
+                      heads * (self.qk_nope_dim + self.v_head_dim)),
+            "wo": (heads * self.v_head_dim, h),
+            "norm_post_attn": (h,), "norm_pre_mlp": (h,),
+            "norm_post_mlp": (h,)}
+        f, e, fs = self.moe_ff, len(self.experts_held), \
+            self.n_shared * self.moe_ff
+        dense = dict(attn, w_gate=(h, self.dense_ff), w_up=(h, self.dense_ff),
+                     w_down=(self.dense_ff, h))
+        moe = dict(attn, router=(h, self.n_experts), eg=(e, h, f),
+                   eu=(e, h, f), ed=(e, f, h), sg=(h, fs), su=(h, fs),
+                   sd=(fs, h))
+        return {"tok_emb": (self.vocab_size, h), "final_norm": (h,),
+                "head": (h, self.vocab_size),
+                "layers": [dict(dense)] * self.n_dense_layers
+                + [dict(moe)] * self.n_moe_layers}
+
+    def init(self) -> "LatentMoETransformer":
+        """Seeded weights: matrices normal / sqrt(fan_in) (every
+        sublayer is normed on both sides, so only the ratio matters),
+        gains 1 + 0.1 n so that no norm is the identity."""
+        import jax
+        import jax.numpy as jnp
+
+        key = jax.random.PRNGKey(self.seed)
+
+        def leaf(k, shape):
+            n = jax.random.normal(k, shape, jnp.float32)
+            if len(shape) == 1:
+                return 1.0 + 0.1 * n
+            return (n / math.sqrt(shape[-2])).astype(self.param_dtype)
+
+        def tree(k, shapes):
+            return {name: leaf(jax.random.fold_in(k, i), shape)
+                    for i, (name, shape) in enumerate(sorted(shapes.items()))}
+
+        shapes = self.param_shapes()
+        layers = shapes.pop("layers")
+        params = tree(key, shapes)
+        params["layers"] = tuple(
+            tree(jax.random.fold_in(key, 100 + i), layer)
+            for i, layer in enumerate(layers))
+        self.params = params
+        return self
+
+    def num_params(self) -> int:
+        import jax
+
+        if self.params is None:
+            return 0
+        return sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree_util.tree_leaves(self.params))
+
+    # ----------------------------------- what DecodeProgram builds from
+    # (the contract is in engine/decode_program.py's docstring)
+    kv_page_axis = 1
+
+    @property
+    def kv_dtype(self):
+        return self.param_dtype
+
+    @property
+    def step_counters(self):
+        from deeplearning4j_tpu.nn.moe import COUNTERS
+
+        return COUNTERS if self.n_moe_layers else ()
+
+    @property
+    def _dims(self):
+        return (self.n_heads, self.qk_nope_dim, self.qk_rope_dim,
+                self.kv_lora_rank)
+
+    @property
+    def _scale(self) -> float:
+        return 1.0 / math.sqrt(self.qk_nope_dim + self.qk_rope_dim)
+
+    def kv_shape(self, n_pages: int, page_size: int):
+        """One latent row a token a layer, the row innermost and in
+        whole lane tiles (nn/latent_attention.py says why)."""
+        from deeplearning4j_tpu.nn.latent_attention import row_width
+
+        return (self.n_layers, n_pages, page_size,
+                row_width(self.kv_lora_rank, self.qk_rope_dim))
+
+    def embed(self, params, tokens, positions):
+        import jax.numpy as jnp
+
+        del positions               # rotary: they enter in `project`
+        return params["tok_emb"][tokens].astype(jnp.float32)
+
+    def project(self, lp, x, positions):
+        from deeplearning4j_tpu.nn.latent_attention import latent_project
+
+        return latent_project(lp, x, positions, self._dims,
+                              self.rope_theta, self.eps)
+
+    def write_cells(self, pool, li, cell, page, offset):
+        return pool.at[li, page, offset].set(cell.astype(pool.dtype))
+
+    def read_window(self, pool, li, page_ids):
+        return pool[li, page_ids]
+
+    def decode_finish(self, lp, x, q, window, live, active):
+        import jax
+
+        from deeplearning4j_tpu.nn import latent_attention as la
+
+        with jax.named_scope("q_proj"):
+            qa = la.absorb_query(lp, q, self._dims, self.v_head_dim)
+        with jax.named_scope("attn"):
+            att = la.latent_decode_attention(qa, window, live, self._scale)
+        with jax.named_scope("attn_out"):
+            att = la.unabsorb_output(lp, att, self._dims, self.v_head_dim)
+        return self._finish(lp, x, att, active)
+
+    def chunk_finish(self, lp, x, q, cell, window, start):
+        import jax
+
+        from deeplearning4j_tpu.nn.latent_attention import (
+            latent_chunk_attention,
+        )
+
+        with jax.named_scope("attn"):
+            att = latent_chunk_attention(
+                lp, q, cell.astype(window.dtype), window, start,
+                self._dims, self.v_head_dim, self._scale)
+        return self._finish(lp, x, att, None)[0]
+
+    def _finish(self, lp, x, att, active):
+        """Merged heads -> the block's output: the output projection,
+        then the feed-forward half, each normed on both sides."""
+        import jax
+
+        from deeplearning4j_tpu.nn.attention import gated_mlp, mm, rms_norm
+        from deeplearning4j_tpu.nn.moe import expert_layer
+
+        with jax.named_scope("attn_out"):
+            x = x + rms_norm(mm(att, lp["wo"]), lp["norm_post_attn"],
+                             self.eps)
+        counts = None
+        if "router" in lp:
+            # the layer names its own scopes; its tail is the expert
+            # layer's time too, so it carries one of them (`moe/*`)
+            y, counts = expert_layer(
+                lp, rms_norm(x, lp["norm_pre_mlp"], self.eps),
+                self.experts_held, self.top_k, self.routed_scale, active)
+            with jax.named_scope("moe/shared"):
+                x = x + rms_norm(y, lp["norm_post_mlp"], self.eps)
+        else:
+            with jax.named_scope("mlp"):
+                y = gated_mlp(rms_norm(x, lp["norm_pre_mlp"], self.eps),
+                              lp["w_gate"], lp["w_up"], lp["w_down"])
+                x = x + rms_norm(y, lp["norm_post_mlp"], self.eps)
+        return x, counts
+
+    def head(self, params, x):
+        from deeplearning4j_tpu.nn.attention import mm, rms_norm
+
+        return mm(rms_norm(x, params["final_norm"], self.eps),
+                  params["head"])
+

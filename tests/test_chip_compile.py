@@ -19,6 +19,8 @@ persistent compile cache is off around these compiles, because such an
 entry cannot be read back without a chip.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -143,3 +145,73 @@ def test_decode_program_compiles_for_v5e(compute_dtype, program,
     assert mem is not None
     # the donated page pool is updated in place: its bytes are aliased
     assert mem.alias_size_in_bytes >= np.prod(prog.kv_shape) * 4
+
+
+@pytest.fixture(scope="module")
+def latent_cell(one_chip):
+    """`pangu-ultra-chat-closed32` as its driver builds it, with shapes
+    on the described chip in the place of 9.84 GB of weights."""
+    import json
+    import os
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.drivers import serve_latent
+    from benchmark.reference import pangu_ultra_moe as ref
+
+    def load(*parts):
+        with open(os.path.join(os.path.dirname(__file__), "..",
+                               "benchmark", *parts)) as f:
+            return json.load(f)
+
+    cell = load("workloads", "pangu-ultra-chat-closed32.json")
+    cfg = load("configs", f"{cell['config']}.json")
+    prog = serve_latent.build(SimpleNamespace(config=cfg, cell=cell))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def leaf(shape):
+        return sds(shape, jnp.float32 if len(shape) == 1 else jnp.bfloat16)
+
+    shapes = ref.param_shapes(cfg)
+    params = {k: leaf(v) for k, v in shapes.items() if k != "layers"}
+    params["layers"] = tuple({k: leaf(v) for k, v in layer.items()}
+                             for layer in shapes["layers"])
+    pool = sds(prog.kv_shape, jnp.bfloat16)
+    s, p, t = prog.max_slots, prog.pages_per_slot, prog.page_size
+    i32 = jnp.int32
+    zs, one = sds((s,), i32), sds((), i32)
+    return prog, {
+        "decode": (prog._decode_program(),
+                   (params, pool, zs, zs, sds((s, p), i32), zs, zs)),
+        "chunk": (prog._chunk_program(),
+                  (params, pool, sds((t,), i32), one, sds((p,), i32), one)),
+        "copy": (prog._copy_program(), (pool, one, one))}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "copy"])
+def test_latent_cell_compiles_for_v5e_with_no_copy_of_the_pool(
+        latent_cell, program):
+    """The cell's three programs at the published widths (4.92B
+    parameters, 32 slots of 4,096 positions). The pool is updated in
+    place and in ONE layout: a row of 576 in place of 640 lanes makes
+    the compiler convert the whole pool in and out of every program
+    (0.76 GB of temporaries and two copies a step; PERF.md, PR 28), and
+    a program that needs more than a gigabyte beside its arguments
+    would not leave the chunk's room beside 10.7 GB of weights and
+    pool."""
+    prog, cases = latent_cell
+    fn, args = cases[program]
+    compiled = getattr(fn, "__wrapped__", fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = int(np.prod(prog.kv_shape)) * 2
+    assert prog.kv_shape == (5, 1025, 128, 640)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
+    layouts = set(re.findall(
+        r"bf16\[5,1025,128,640\]\{([0-9,]+):", compiled.as_text()))
+    assert layouts == {"3,2,1,0"}

@@ -29,6 +29,11 @@ TINY = {
     "serve": dict(vocab_size=64, d_model=32, n_heads=4, n_layers=2,
                   max_ctx=64),
     "mesh": dict(batch=8, hw=32, n_classes=8, steps=2),
+    "latent": dict(vocab_size=64, hidden=32, n_heads=2, q_lora_rank=16,
+                   kv_lora_rank=8, qk_nope_dim=8, qk_rope_dim=4,
+                   v_head_dim=8, dense_ff=64, moe_ff=16, n_experts=4,
+                   top_k=2, experts_held=(0, 2), n_dense_layers=1,
+                   n_moe_layers=1, max_ctx=64, param_dtype="bfloat16"),
 }
 
 
@@ -64,7 +69,8 @@ def test_one_chip_run_at_toy_size(steered, capsys):
     assert steered.main([], sizes=TINY) == 0
     lines = _lines(capsys)
     assert [ln.get("phase") for ln in lines[:-1]] == [
-        "start", "runtime", "kernels", "train", "serve", "serve", "done"]
+        "start", "runtime", "kernels", "train", "serve", "serve", "latent",
+        "done"]
     # the last line is the contract's object and nothing else
     assert lines[-1] == {"ok": True, "device": {
         "platform": "cpu", "kind": "cpu", "count": 8}}
@@ -78,6 +84,9 @@ def test_one_chip_run_at_toy_size(steered, capsys):
         serve = by["serve", dtype]
         assert serve["prefix_requests_hit"] >= 1
         assert serve["audit"]["leaked"] == 0
+    latent = by["latent", None]
+    assert latent["pool_dtype"] == "bfloat16" and len(latent["pool"]) == 4
+    assert 0 < latent["moe_assignments_held"] < latent["moe_assignments"]
 
 
 def test_four_chip_run_at_toy_size(steered, capsys, monkeypatch):
