@@ -42,8 +42,10 @@ the model, and nothing here asks which one it is:
         the pool: one buffer whose `kv_page_axis` is the physical page
         index, so one page id addresses every layer's cells of a page
         (one page-table entry per page, not per layer). GPT-2 keeps
-        ``[L, 2, pages, H, page, D]`` float32, head-major within a
-        page (nn/attention.py says why); a latent-attention model one
+        ``[L, 2, pages, page, H * D]`` float32, one row of whole
+        128-lane tiles a token: with head_dim 64 minor the chip's
+        compiler converted the whole pool in and out of every program
+        (nn/attention.py, PERF.md PR 29); a latent-attention model one
         row a token, ``[L, pages, page, row]`` (nn/latent_attention.py).
   embed(params, tokens, positions) -> x
   project(lp, x, positions) -> (q, cell)

@@ -20,15 +20,18 @@ Both build from the same per-layer parameter dict (see
 zoo/decoder.CausalTransformer), so the math of a position is defined
 once; engine/decode_program.py owns where K/V land in the page pool.
 
-Layout discipline (Tensor Processing Primitives, arXiv 2104.05755):
-head_dim rides innermost everywhere (the contraction axis of both
-attention matmuls stays in the minor/lane dimension), and the window
-arrives as the pool stores it, [..., pages, n_heads, page_size,
-head_dim]: a page is HEAD-MAJOR, so both cache contractions batch
-over (slot, head) and take pages and offsets as free or contracted
-dims where they lie — no transposed copy of the window is authored
-(the transpose-churn finding the program lint raised against the
-first slot-major layout — PERF.md "Decode program layout").
+Layout discipline: the window arrives as the pool stores it, TOKEN
+ROWS [..., cells, n_heads * head_dim] — one row of d_model numbers a
+cached position, pages flattened in ring order — and neither cache
+contraction splits a row into (n_heads, head_dim). Measured on the
+v5e (PERF.md, PR 29): a minor dimension of head_dim 64 fills half of
+a 128-lane tile, so the head-major page that stood here had the
+compiler convert the whole pool in and out of every program (31 of a
+77 ms step) and move the window at half width. Scores are the rows
+times a BLOCK-DIAGONAL query (`_head_blocks`), values the weights
+times the rows with the block diagonal taken after: n_heads times
+the multiply-adds on an idle MXU, and nothing beside the window's
+own bytes read.
 
 Bitwise discipline: attention is commutative but NOT associative over
 keys, so the engine and the sequential oracle must present identical
@@ -105,71 +108,78 @@ def qkv_heads(lp: dict, x, n_heads: int):
     return split(lp["wq"]), split(lp["wk"]), split(lp["wv"])
 
 
-def paged_decode_attention(q, k_pages, v_pages, live):
+def _head_blocks(q):
+    """E [n_heads * head_dim, n_heads] of 1.0 where lane c belongs to
+    head h: `merged q[..., :, None] * E` is the block-diagonal query
+    whose product with a token row is every head's score at once, and
+    `sum(x[..., h, c] * E.T, axis=-2)` takes head h's lanes of x."""
+    import jax.numpy as jnp
+
+    h, d = q.shape[-2:]
+    return (jnp.arange(h * d)[:, None] // d
+            == jnp.arange(h)[None, :]).astype(q.dtype)
+
+
+def paged_decode_attention(q, k_rows, v_rows, live):
     """Single-position attention against GATHERED pages (the DECODE
     shape): `q` is [S, n_heads, head_dim] (one new position per slot),
-    `k_pages`/`v_pages` are [S, pages, n_heads, page_size, head_dim] —
-    the slot's window gathered from the physical page pool a whole
-    page at a time, in RING order: cell c = page * page_size + offset
-    holds the position congruent to c modulo the window, with the new
-    position's K/V already written at its cell. `live[s]` counts the
-    slot's readable cells; cells beyond it (the unwritten tail of the
-    newest page, and whole pages that point at scratch) are zeroed
-    before the score contraction (see the module docstring). Both
-    contractions take the page layout as it is gathered — (slot, head)
-    batch dims, head_dim minor, pages and offsets free or contracted
-    in place — so no transposed copy of the window is authored (the
-    40% transpose-churn the program lint flagged on the first
-    slot-major attempt — PERF.md). Returns [S, n_heads, head_dim]."""
+    `k_rows`/`v_rows` are [S, cells, n_heads * head_dim] — the slot's
+    window gathered from the physical page pool a whole page at a
+    time, in RING order: cell c = page * page_size + offset holds the
+    position congruent to c modulo the window, with the new position's
+    K/V already written at its cell. `live[s]` counts the slot's
+    readable cells; cells beyond it (the unwritten tail of the newest
+    page, and whole pages that point at scratch) are zeroed before the
+    score contraction (see the module docstring). Both contractions
+    run over the rows as stored (module docstring, "Layout
+    discipline"). Returns [S, n_heads * head_dim], heads merged."""
     import jax.numpy as jnp
 
-    s, p, h, t, _ = k_pages.shape
+    n = k_rows.shape[1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
-    mask = jnp.reshape(jnp.arange(p * t)[None, :] < live[:, None],
-                       (s, p, t))                          # [S, P, T]
-    m5 = mask[:, :, None, :, None]
-    k_pages = jnp.where(m5, k_pages, 0.0)
-    v_pages = jnp.where(m5, v_pages, 0.0)
-    scores = jnp.einsum("shd,sphtd->shpt", q, k_pages) * scale
+    mask = jnp.arange(n)[None, :] < live[:, None]          # [S, N]
+    k_rows = jnp.where(mask[:, :, None], k_rows, 0.0)
+    v_rows = jnp.where(mask[:, :, None], v_rows, 0.0)
+    e = _head_blocks(q)
+    qb = merge_heads(q)[:, :, None] * e                    # [S, C, H]
+    scores = jnp.einsum("snc,sch->shn", k_rows, qb) * scale
     scores = jnp.where(mask[:, None], scores, MASK_VALUE)
     # one softmax over the window's cells in ring-cell order
-    w = _softmax(jnp.reshape(scores, (s, h, p * t)))
-    return jnp.einsum("shpt,sphtd->shd",
-                      jnp.reshape(w, (s, h, p, t)), v_pages)
+    w = _softmax(scores)
+    full = jnp.einsum("shn,snc->shc", w, v_rows)           # [S, H, C]
+    return jnp.sum(full * e.T, axis=1)
 
 
-def chunk_prefill_attention(q, k, v, k_pages, v_pages, n_prior):
+def chunk_prefill_attention(q, k, v, k_rows, v_rows, n_prior):
     """One prompt chunk attending jointly to its PRIOR context and to
     itself (the CHUNK-PREFILL shape): `q`/`k`/`v` are [T, n_heads,
-    head_dim] for chunk positions n_prior..n_prior+T-1; `k_pages`/
-    `v_pages` are [pages, n_heads, page_size, head_dim] — the already-
-    prefilled positions 0..n_prior-1 gathered a whole page at a time
-    in ring order, which before a wrap (and a prompt never wraps) is
-    logical order: cell c holds position c (cells >= n_prior are
-    scratch: zeroed + masked). ONE softmax spans [prior cells ; chunk]
-    so the reduction order is fixed regardless of how the prior pages
-    were produced — computed by an earlier chunk, or mapped read-only
-    from the prefix trie. Returns [T, n_heads, head_dim]."""
+    head_dim] for chunk positions n_prior..n_prior+T-1; `k_rows`/
+    `v_rows` are [cells, n_heads * head_dim] — the already-prefilled
+    positions 0..n_prior-1 gathered a whole page at a time in ring
+    order, which before a wrap (and a prompt never wraps) is logical
+    order: cell c holds position c (cells >= n_prior are scratch:
+    zeroed + masked). ONE softmax spans [prior cells ; chunk] so the
+    reduction order is fixed regardless of how the prior pages were
+    produced — computed by an earlier chunk, or mapped read-only from
+    the prefix trie. Returns [T, n_heads * head_dim], heads merged."""
     import jax.numpy as jnp
 
-    t = q.shape[0]
-    p, h, ps, _ = k_pages.shape
-    c = p * ps
+    t, n = q.shape[0], k_rows.shape[0]
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
-    prior = jnp.reshape(jnp.arange(c) < n_prior, (p, ps))  # [P, ps]
-    m4 = prior[:, None, :, None]
-    k_pages = jnp.where(m4, k_pages, 0.0)
-    v_pages = jnp.where(m4, v_pages, 0.0)
-    sp = jnp.einsum("thd,phcd->htpc", q, k_pages) * scale
-    sp = jnp.reshape(jnp.where(prior[None, None], sp, MASK_VALUE),
-                     (h, t, c))                            # [H, T, C]
+    prior = jnp.arange(n) < n_prior                        # [N]
+    k_rows = jnp.where(prior[:, None], k_rows, 0.0)
+    v_rows = jnp.where(prior[:, None], v_rows, 0.0)
+    e = _head_blocks(q)
+    qb = merge_heads(q)[:, :, None] * e                    # [T, C, H]
+    sp = jnp.einsum("nc,tch->htn", k_rows, qb) * scale     # [H, T, N]
+    sp = jnp.where(prior[None, None], sp, MASK_VALUE)
     si = jnp.einsum("thd,uhd->htu", q, k) * scale          # [H, T, T]
     causal = jnp.tril(jnp.ones((t, t), bool))
     si = jnp.where(causal[None, :, :], si, MASK_VALUE)
     w = _softmax(jnp.concatenate([sp, si], axis=-1))
-    wp = jnp.reshape(w[..., :c], (h, t, p, ps))
-    return (jnp.einsum("htpc,phcd->thd", wp, v_pages)
-            + jnp.einsum("htu,uhd->thd", w[..., c:], v))
+    full = jnp.einsum("htn,nc->thc", w[..., :n], v_rows)   # [T, H, C]
+    own = jnp.einsum("htu,uhd->thd", w[..., n:], v)
+    return jnp.sum(full * e.T, axis=1) + merge_heads(own)
 
 
 def _softmax(scores):
@@ -188,7 +198,7 @@ def mlp_block(lp: dict, x):
     return h @ lp["w2"] + lp["b2"]
 
 
-def block_chunk_prefill(lp: dict, x, n_heads: int, k_pages, v_pages,
+def block_chunk_prefill(lp: dict, x, n_heads: int, k_rows, v_rows,
                         n_prior, qkv=None):
     """One decoder block over a prompt CHUNK: x [T, d_model] -> x'.
     The chunk's q/k/v are pre-attention projections of the ln1 stream
@@ -197,7 +207,7 @@ def block_chunk_prefill(lp: dict, x, n_heads: int, k_pages, v_pages,
     The caller usually passes `qkv` precomputed via `decode_qkv` (it
     parks k/v into a physical page BEFORE attention — the
     scatter-then-gather order that keeps the pool update in place);
-    `k_pages`/`v_pages`/`n_prior` carry the prior context per
+    `k_rows`/`v_rows`/`n_prior` carry the prior context per
     `chunk_prefill_attention`."""
     import jax
 
@@ -206,9 +216,9 @@ def block_chunk_prefill(lp: dict, x, n_heads: int, k_pages, v_pages,
             qkv = decode_qkv(lp, x, n_heads)
     q, k, v = qkv
     with jax.named_scope("attn"):
-        att = chunk_prefill_attention(q, k, v, k_pages, v_pages,
+        att = chunk_prefill_attention(q, k, v, k_rows, v_rows,
                                       n_prior)
-        x = x + _merge_heads(att) @ lp["wo"]
+        x = x + att @ lp["wo"]
     with jax.named_scope("mlp"):
         x = x + mlp_block(lp, layer_norm(x, lp["ln2_g"], lp["ln2_b"]))
     return x
@@ -225,25 +235,27 @@ def decode_qkv(lp: dict, x, n_heads: int):
     return qkv_heads(lp, h, n_heads)
 
 
-def block_decode_finish(lp: dict, x, q, k_pages, v_pages, live):
+def block_decode_finish(lp: dict, x, q, k_rows, v_rows, live):
     """Second half of a decode-shape block: attend `q` [S, H, Dh]
-    against the gathered window pages [S, pages, H, page_size, Dh]
+    against the gathered window's token rows [S, cells, H * Dh]
     (current position's K/V already written at its ring cell) and run
     the residual + feed-forward tail. Returns x' [S, d_model]."""
     import jax
 
     with jax.named_scope("attn"):
-        att = paged_decode_attention(q, k_pages, v_pages, live)
-        x = x + _merge_heads(att) @ lp["wo"]
+        att = paged_decode_attention(q, k_rows, v_rows, live)
+        x = x + att @ lp["wo"]
     with jax.named_scope("mlp"):
         x = x + mlp_block(lp, layer_norm(x, lp["ln2_g"], lp["ln2_b"]))
     return x
 
 
-def _merge_heads(att):
+def merge_heads(x):
+    """[..., n_heads, head_dim] -> [..., n_heads * head_dim]: the row
+    a cached position is stored as."""
     import jax.numpy as jnp
 
-    return jnp.reshape(att, att.shape[:-2] + (-1,))
+    return jnp.reshape(x, x.shape[:-2] + (-1,))
 
 
 def lm_logits(x, tok_emb):

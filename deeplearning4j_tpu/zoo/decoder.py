@@ -111,10 +111,11 @@ class CausalTransformer:
 
     def kv_shape(self, n_pages: int, page_size: int):
         """Page-major so one page id addresses every layer's K and V;
-        head-major within a page, head_dim innermost (the layout notes
-        are in decode_program.py and nn/attention.py)."""
-        return (self.n_layers, 2, n_pages, self.n_heads, page_size,
-                self.head_dim)
+        a page is `page_size` TOKEN ROWS of n_heads * head_dim lanes.
+        Rows in whole 128-lane tiles are the one layout the chip's
+        compiler works in: with head_dim 64 minor it converted the
+        whole pool in and out of every program (PERF.md, PR 29)."""
+        return (self.n_layers, 2, n_pages, page_size, self.d_model)
 
     def embed(self, params, tokens, positions):
         # logical positions grow unbounded past max_ctx (ring wrap);
@@ -134,21 +135,31 @@ class CausalTransformer:
         return q, (k, v)
 
     def write_cells(self, pool, li, cell, page, offset):
-        """pool[li, io, page, h, offset] = k or v: the advanced indices
+        """pool[li, io, page, offset] = k or v as one row, heads merged
         (`page` per slot or one page, `offset` per slot or the page's
-        offsets) broadcast, and land [.., H, D] rows in the head-major
-        page without an authored transpose."""
+        offsets: the advanced indices broadcast). A row is written
+        whole, so no cell is scattered into the lanes of another's."""
+        from deeplearning4j_tpu.nn.attention import merge_heads
+
         k, v = cell
-        pool = pool.at[li, 0, page, :, offset].set(k)
-        return pool.at[li, 1, page, :, offset].set(v)
+        pool = pool.at[li, 0, page, offset].set(merge_heads(k))
+        return pool.at[li, 1, page, offset].set(merge_heads(v))
 
     def read_window(self, pool, li, page_ids):
-        """[.., P, H, page_size, D] each of K and V. ONE gather over
-        the whole pool (layer and K/V plane are constant indices of
-        it): `pool[li, 0][page_ids]` makes the chip's compiler copy
-        the layer's plane out first, 0.8 ms a layer beside the 0.6 ms
-        the gather itself takes (PERF.md)."""
-        return pool[li, 0, page_ids], pool[li, 1, page_ids]
+        """[.., cells, n_heads * head_dim] each of K and V: the pages'
+        rows in ring-cell order, as stored (splitting a row into heads
+        is what the compiler materializes at 64 of 128 lanes: PERF.md,
+        PR 29). ONE gather over the whole pool (layer and K/V plane
+        are constant indices of it): `pool[li, 0][page_ids]` makes the
+        chip's compiler copy the layer's plane out first (PR 27)."""
+        import jax.numpy as jnp
+
+        def rows(io):
+            pages = pool[li, io, page_ids]       # [.., P, page_size, C]
+            return jnp.reshape(pages, pages.shape[:-3] + (-1,)
+                               + pages.shape[-1:])
+
+        return rows(0), rows(1)
 
     def decode_finish(self, lp, x, q, window, live, active):
         from deeplearning4j_tpu.nn.attention import block_decode_finish

@@ -147,10 +147,10 @@ def test_decode_program_compiles_for_v5e(compute_dtype, program,
     assert mem.alias_size_in_bytes >= np.prod(prog.kv_shape) * 4
 
 
-@pytest.fixture(scope="module")
-def latent_cell(one_chip):
-    """`pangu-ultra-chat-closed32` as its driver builds it, with shapes
-    on the described chip in the place of 9.84 GB of weights."""
+def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
+    """(program, {kind: (jitted function, argument shapes)}) of a
+    serving cell as its driver builds it, with shapes on the described
+    chip in the place of the weights and the pool."""
     import json
     import os
     from types import SimpleNamespace
@@ -158,29 +158,26 @@ def latent_cell(one_chip):
     import jax
     import jax.numpy as jnp
 
-    from benchmark.drivers import serve_latent
-    from benchmark.reference import pangu_ultra_moe as ref
-
     def load(*parts):
         with open(os.path.join(os.path.dirname(__file__), "..",
                                "benchmark", *parts)) as f:
             return json.load(f)
 
-    cell = load("workloads", "pangu-ultra-chat-closed32.json")
+    cell = load("workloads", f"{name}.json")
     cfg = load("configs", f"{cell['config']}.json")
-    prog = serve_latent.build(SimpleNamespace(config=cfg, cell=cell))
+    prog = driver.build(SimpleNamespace(config=cfg, cell=cell))
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
     def leaf(shape):
-        return sds(shape, jnp.float32 if len(shape) == 1 else jnp.bfloat16)
+        return sds(shape, jnp.float32 if len(shape) == 1 else matrix_dtype)
 
     shapes = ref.param_shapes(cfg)
     params = {k: leaf(v) for k, v in shapes.items() if k != "layers"}
     params["layers"] = tuple({k: leaf(v) for k, v in layer.items()}
                              for layer in shapes["layers"])
-    pool = sds(prog.kv_shape, jnp.bfloat16)
+    pool = sds(prog.kv_shape, prog.model.kv_dtype)
     s, p, t = prog.max_slots, prog.pages_per_slot, prog.page_size
     i32 = jnp.int32
     zs, one = sds((s,), i32), sds((), i32)
@@ -190,6 +187,19 @@ def latent_cell(one_chip):
         "chunk": (prog._chunk_program(),
                   (params, pool, sds((t,), i32), one, sds((p,), i32), one)),
         "copy": (prog._copy_program(), (pool, one, one))}
+
+
+@pytest.fixture(scope="module")
+def latent_cell(one_chip):
+    """`pangu-ultra-chat-closed32` with shapes in the place of 9.84 GB
+    of bfloat16 weights."""
+    import jax.numpy as jnp
+
+    from benchmark.drivers import serve_latent
+    from benchmark.reference import pangu_ultra_moe as ref
+
+    return _cell_programs(one_chip, "pangu-ultra-chat-closed32",
+                          serve_latent, ref, jnp.bfloat16)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk", "copy"])
@@ -215,3 +225,68 @@ def test_latent_cell_compiles_for_v5e_with_no_copy_of_the_pool(
     layouts = set(re.findall(
         r"bf16\[5,1025,128,640\]\{([0-9,]+):", compiled.as_text()))
     assert layouts == {"3,2,1,0"}
+
+
+@pytest.fixture(scope="module")
+def gpt2_cell(one_chip):
+    """`gpt2m-chat-closed32` (24 x 1,024, 16 heads, 32 slots, pages of
+    16, 1,025 pages) with shapes in the place of 1.42 GB of float32
+    weights."""
+    import jax.numpy as jnp
+
+    from benchmark.drivers import serve
+    from benchmark.reference import gpt2 as ref
+
+    return _cell_programs(one_chip, "gpt2m-chat-closed32", serve, ref,
+                          jnp.float32)
+
+
+_ARRAY = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]+)\]\{([0-9,]+)")
+
+
+def _materialized(text):
+    """(opcode, bytes, dims, minor dimension's size) of every array an
+    instruction of the compiled program's ENTRY computation produces.
+    Those are the buffers that exist; what a fusion computes inside
+    itself is in the computations before ENTRY and is not listed."""
+    out = []
+    for line in text[text.index("\nENTRY "):].splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?\S+ = (.*?) ([a-z][a-z\-]*)\(", line)
+        if not m:
+            continue
+        for dtype, dims, layout in _ARRAY.findall(m.group(1)):
+            dims = tuple(int(d) for d in dims.split(","))
+            bits = re.search(r"[0-9]+$", dtype)    # `pred` has none
+            out.append((m.group(2),
+                        int(np.prod(dims)) * (int(bits[0]) if bits else 8)
+                        // 8, dims, dims[int(layout.split(",")[0])]))
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "copy"])
+def test_gpt2_cell_compiles_for_v5e_with_the_pool_as_stored(gpt2_cell,
+                                                            program):
+    """The cell's three programs at the published widths. The pool is
+    token rows of 1,024 lanes, updated in place and in ONE layout, and
+    no instruction of its shape is a `copy`: with head_dim 64 minor
+    (the head-major page, and the same padded to 128) the compiler
+    converted all 3.6 GB in and out of every program, 31 of a 77 ms
+    step and 7 GB of temporaries (PERF.md, PR 29). And no buffer of
+    50 MB or more is narrower than a 128-lane tile: a window split
+    into (heads, 64) is materialized at half width, 48 times a step."""
+    prog, cases = gpt2_cell
+    fn, args = cases[program]
+    compiled = getattr(fn, "__wrapped__", fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert prog.kv_shape == (24, 2, 1025, 16, 1024)
+    assert mem.alias_size_in_bytes >= int(np.prod(prog.kv_shape)) * 4
+    assert mem.temp_size_in_bytes < 1.0e9
+    text = compiled.as_text()
+    layouts = set(re.findall(
+        r"f32\[24,2,1025,16,1024\]\{([0-9,]+):", text))
+    assert layouts == {"4,3,2,1,0"}
+    made = _materialized(text)
+    assert any(dims == prog.kv_shape for _, _, dims, _ in made)
+    assert not [m for m in made
+                if m[0] == "copy" and m[2] == prog.kv_shape]
+    assert not [m for m in made if m[1] >= 50e6 and m[3] < 128]

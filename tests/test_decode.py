@@ -9,7 +9,7 @@ The load-bearing pins:
     trace counters: zero new traces after warmup);
   * KV-cache donation is honored (prog-unhonored-donation over the
     decode/prefill ProgramRecords — no silent per-token copy of the
-    [n_layers, 2, max_slots, n_heads, max_ctx, head_dim] buffer);
+    [n_layers, 2, n_pages, page_size, d_model] buffer);
   * the serving surface: /v1/models/<m>/generate over HTTP on BOTH
     wires (npz with variable-length token outputs, legacy JSON),
     admission 429 + Retry-After on slot exhaustion;
@@ -121,17 +121,20 @@ def test_chunk_schedule_is_page_aligned(program):
         program.chunk_starts(0)
 
 
-def test_kv_pool_is_page_and_head_major(program):
-    """The physical pool: [n_layers, 2, n_pages, n_heads, page_size,
-    head_dim] — page-major (one page id addresses every layer), head-
-    major within a page, head_dim innermost. Default n_pages matches
-    the PR 15 contiguous per-slot HBM budget + the scratch page."""
+def test_kv_pool_is_pages_of_token_rows(program):
+    """The physical pool: [n_layers, 2, n_pages, page_size, n_heads *
+    head_dim] — page-major (one page id addresses every layer), a page
+    `page_size` consecutive token rows, a row every head of one
+    position (the one layout the chip's compiler works in: PERF.md,
+    PR 29). Default n_pages matches the PR 15 contiguous per-slot HBM
+    budget + the scratch page."""
     m = program.model
     assert program.pages_per_slot == CTX // PAGE
     assert program.n_pages == SLOTS * program.pages_per_slot + 1
-    assert program.kv_shape == (m.n_layers, 2, program.n_pages,
-                                m.n_heads, PAGE, m.head_dim)
+    assert program.kv_shape == (m.n_layers, 2, program.n_pages, PAGE,
+                                m.n_heads * m.head_dim)
     assert program.init_kv().shape == program.kv_shape
+    assert m.kv_page_axis == 2 and m.kv_dtype == np.float32
 
 
 def test_window_pages_ring_order(program):
@@ -519,10 +522,10 @@ def test_metrics_exposed_on_http_scrape(program):
 def test_program_lint_decode_records_clean():
     """The decode/prefill programs join the --programs representative
     set CLEAN — in particular prog-unhonored-donation proves the
-    [n_layers, 2, max_slots, n_heads, max_ctx, head_dim] KV cache is
-    genuinely aliased in-place (a silent copy would double decode
-    memory and pay a full-cache copy per token), and
-    prog-transpose-churn stays quiet on the head-major layout."""
+    [n_layers, 2, n_pages, page_size, d_model] KV pool is genuinely
+    aliased in-place (a silent copy would double decode memory and pay
+    a full-cache copy per token), and prog-transpose-churn stays quiet
+    on the token-row layout."""
     from deeplearning4j_tpu.analysis import program_lint
     from deeplearning4j_tpu.analysis.programs import _decode_records
 
@@ -541,7 +544,7 @@ def test_program_lint_decode_records_clean():
 def test_pool_is_gathered_by_whole_pages():
     """The structural pin on the lowered decode and chunk programs:
     every gather from the page pool takes slices of one whole
-    [n_heads, page_size, head_dim] page of one layer's K or V plane —
+    [page_size, n_heads * head_dim] page of one layer's K or V plane —
     one address a page — and none takes a single row (the per-cell
     gather was bound by its 524,288 addresses a layer, not by its
     bytes: PERF.md)."""
@@ -553,7 +556,7 @@ def test_pool_is_gathered_by_whole_pages():
                               n_layers=2, max_ctx=CTX, seed=3).init()
     prog = DecodeProgram(model, max_slots=SLOTS, page_size=PAGE)
     pool_type = "x".join(map(str, prog.kv_shape)) + "xf32"
-    page = (1, 1, 1, model.n_heads, PAGE, model.head_dim)
+    page = (1, 1, 1, PAGE, model.n_heads * model.head_dim)
     gather = re.compile(
         r'"stablehlo\.gather".*slice_sizes = array<i64: ([0-9, ]+)>'
         r".* : \(tensor<" + pool_type + ">")
@@ -569,6 +572,70 @@ def test_pool_is_gathered_by_whole_pages():
         # K and V of every layer, and nothing else reads the pool
         assert len(sizes) == reads, (rec.name, sizes)
         assert set(sizes) == {page}, (rec.name, sizes)
+
+
+@pytest.mark.parametrize("shape", ["decode", "chunk"])
+def test_cache_contractions_match_plain_per_head_attention(shape):
+    """Both cache contractions run over token rows with the heads
+    merged (a block-diagonal query, the block diagonal of the weighted
+    rows); held here to softmax attention a head at a time in float64
+    numpy, through the model's own scatter and gather. Every cell that
+    is not live — the newest page's tail, the scratch page the dead
+    ring entries point at — holds NaN, so a dead cell that reaches a
+    contraction, or a lane of another head's that is not multiplied
+    by an exact zero, fails it."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.attention import (
+        chunk_prefill_attention,
+        paged_decode_attention,
+    )
+
+    h, d, ps, p = 4, 8, PAGE, 4
+    model = CausalTransformer(vocab_size=VOCAB, d_model=h * d, n_heads=h,
+                              n_layers=1, max_ctx=p * ps)
+    rng = np.random.default_rng(7)
+    lives = [1, 13, p * ps] if shape == "decode" else [13]
+    pool = jnp.full(model.kv_shape(1 + len(lives) * p, ps), np.nan,
+                    np.float32)
+    page_ids = np.zeros((len(lives), p), np.int32)     # 0 is scratch
+    cached = []
+    for s, live in enumerate(lives):
+        n = -(-live // ps)
+        # the slot's pages in an order that is not the pool's
+        page_ids[s, :n] = 1 + s * p + rng.permutation(p)[:n]
+        k, v = rng.standard_normal((2, live, h, d)).astype(np.float32)
+        cells = np.arange(live)
+        pool = model.write_cells(pool, 0, (k, v),
+                                 page_ids[s, cells // ps], cells % ps)
+        cached.append((k, v))
+
+    def plain(q, k, v):
+        """q [H, D] over keys and values [N, H, D], a head at a time."""
+        q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+        out = np.empty((h, d))
+        for i in range(h):
+            sc = k[:, i] @ q[i] / np.sqrt(d)
+            w = np.exp(sc - sc.max())
+            out[i] = (w / w.sum()) @ v[:, i]
+        return out.reshape(h * d)
+
+    if shape == "decode":
+        q = rng.standard_normal((len(lives), h, d)).astype(np.float32)
+        got = paged_decode_attention(
+            q, *model.read_window(pool, 0, page_ids), np.asarray(lives))
+        want = [plain(q[s], *cached[s]) for s in range(len(lives))]
+    else:
+        q, k, v = rng.standard_normal((3, ps, h, d)).astype(np.float32)
+        got = chunk_prefill_attention(
+            q, k, v, *model.read_window(pool, 0, page_ids[0]), lives[0])
+        pk, pv = cached[0]
+        want = [plain(q[t], np.concatenate([pk, k[:t + 1]]),
+                      np.concatenate([pv, v[:t + 1]])) for t in range(ps)]
+    got = np.asarray(got)
+    assert got.shape == np.shape(want)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() < 1e-5
 
 
 def test_decode_records_in_default_program_set():

@@ -361,4 +361,4 @@ def test_gpt2_counts_nothing_and_keeps_three_outputs():
     prog = DecodeProgram(gpt, max_slots=2, page_size=8)
     assert prog.counters() == {}
     assert "moe_assignments" not in DecodeEngine(program=prog).stats()
-    assert prog.kv_shape == (1, 2, 9, 4, 8, 8)
+    assert prog.kv_shape == (1, 2, 9, 8, 32)
