@@ -25,7 +25,9 @@ IDLE_S = 0.002
 DRAIN_S = 60.0       # how long a first token is waited for past the close
 BLOCK = 8            # rows of the sample the reference takes at a time
 COUNTERS = ("steps", "tokens_total", "prefill_chunks", "prefix_hits",
-            "cow_copies", "completed", "evictions", "quarantines")
+            "cow_copies", "completed", "evictions", "quarantines",
+            "kv_pages_gathered", "kv_pages_live")
+STALL = 3.0          # a step over so many times the window's median
 
 
 def build(ctx):
@@ -172,6 +174,35 @@ def served_sample(finished, seed: int, width: int, rows: int):
     return tokens, served
 
 
+def stalls(laps):
+    """Of a window's turns `(engine step, s in `step_once`, s around
+    it)`: those that took over `STALL` times the window's median, as
+    (engine step, s); that median; the longest turn under the mark. The
+    whole turn counts: the machine stalls the process, not a line of
+    it."""
+    took = [(n, inside + around) for n, inside, around in laps]
+    median = float(np.median([s for _, s in took]))
+    mark = STALL * median
+    return ([(n, s) for n, s in took if s > mark], median,
+            max((s for _, s in took if s <= mark), default=0.0))
+
+
+def log_window_phases(ctx, first: int, last: int) -> None:
+    """The host's phases over the engine steps first..last, from the
+    program's step records: the table a traced run's readers log
+    (`timeline.serve_numbers`), for a run that has no readers."""
+    from benchmark import timeline
+
+    steps, _ = timeline.split_records("serve")
+    secs = [timeline.phase_seconds(r)
+            for r in timeline.pick_steps(steps or [], first, last)]
+    if not secs:
+        return
+    # the turn before the window's first step began before it opened
+    secs[0].pop("between_steps", None)
+    timeline.log_phases(secs, ctx.log)
+
+
 def mix_width(mix: dict) -> int:
     return (int(mix.get("shared_prefix", 0)) + mix["prompt_tokens"][1]
             + mix["output_tokens"][1])
@@ -235,14 +266,21 @@ def drive(ctx, eng, seed: int, seconds: float, trace: bool):
                 traced = 2
         if writer is not None:
             writer.join()
+        # a request that finishes in the window carries a stall of the
+        # warm-up in its time per token
+        before = stalls(laps)[0]
+        del pages[:], laps[:]
         gc0 = [g["collections"] for g in gc.get_stats()]
         s0 = eng.stats()
+        cpu0 = time.process_time()
         t0 = time.perf_counter()
-        del pages[:], laps[:]
         while time.perf_counter() - t0 < seconds:
             step()
         s1 = eng.stats()
         t1 = time.perf_counter()
+        cpu_s = time.process_time() - cpu0
+        in_window = len(laps)
+        left = [len(reqs) - sent for reqs, sent in zip(lists, load.next)]
         ctx.log(f"pages in use over the window: mean {np.mean(pages):.0f}, "
                 f"most {max(pages)} of {s1['pages']['total']}")
         ctx.log("collections in the window, by generation: "
@@ -256,12 +294,27 @@ def drive(ctx, eng, seed: int, seconds: float, trace: bool):
         while waiting() and time.perf_counter() - t1 < DRAIN_S:
             step()
         t_end = time.perf_counter()
-        # where a host stall would show
-        ctx.log("longest steps, window and wait (engine step, s): "
-                + ", ".join(f"{n} {s:.3f}" for n, s, _ in
-                            sorted(laps, key=lambda lap: -lap[1])[:4])
-                + f"; median {np.median([lap[1] for lap in laps]):.3f}; "
-                f"most between two steps {max(lap[2] for lap in laps):.4f}")
+        # which of its modes the run is in: the machine's stalls land on
+        # every request in flight (PERF.md, section 2)
+        stalled, median, under = stalls(laps[:in_window])
+        ctx.log(f"stalls, window steps over {STALL:g} times the median of "
+                f"{median:.4f} s: {len(stalled)}, "
+                f"{sum(s for _, s in stalled):.3f} s in all (engine step, "
+                "s): " + ", ".join(f"{n} {s:.3f}" for n, s in stalled)
+                + f"; the longest step under it {under:.3f}; most "
+                "between two steps "
+                f"{max(lap[2] for lap in laps[:in_window]):.4f}; in the "
+                "warm-up, by its own median: "
+                + (", ".join(f"{n} {s:.3f}" for n, s in before) or "none"))
+        # a slow host takes more of it for the same steps, and moves every
+        # reading of a cell whose host work the device does not hide
+        ctx.log(f"this process's CPU time over the window: {cpu_s:.2f} s of "
+                f"{t1 - t0:.2f} s, {cpu_s / in_window * 1e3:.3f} ms a step")
+        ctx.log("requests left to send at the close, the client with the "
+                f"fewest: {min(left)} of {len(lists[0])}; clients with "
+                f"none: {sum(n == 0 for n in left)}")
+        if not trace:
+            log_window_phases(ctx, s0["steps"] + 1, s1["steps"])
         rows = load.snapshot()
     finally:
         if writer is not None:

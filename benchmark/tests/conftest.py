@@ -91,14 +91,19 @@ def tiny(monkeypatch):
                         lambda chips: jax.devices()[:chips])
     monkeypatch.setattr(run, "place_cache", lambda: None)
     bench = _json("..", "BENCHMARK.json")
+    # the plane of a listed cell is its configuration's driver
+    driver_of = {w["name"]: _json("configs", f"{w['config']}.json")["driver"]
+                 for w in bench["workloads"]}
 
     def cell_metrics(cell):
-        plane = ".train" if "train" in cell else ".serve"
-        e2e = [m for m in bench["end_to_end"] if m["name"] == "setup_s"
-               or any(plane[1:] in w or ("gpt2" in w) == (plane == ".serve")
-                      for w in m.get("workloads", []))]
-        return e2e, [m for m in bench["per_layer"]
-                     if m["name"].endswith(plane)]
+        """A tiny cell reports what the first listed cell of the same
+        driver reports."""
+        config = files[f"workloads/{cell}.json"]["config"]
+        driver = files[f"configs/{config}.json"]["driver"]
+        like = next(w for w, d in driver_of.items() if d == driver)
+        pick = lambda ms: [m for m in ms  # noqa: E731
+                           if like in m.get("workloads", [like])]
+        return pick(bench["end_to_end"]), pick(bench["per_layer"])
 
     monkeypatch.setattr(run, "cell_metrics", cell_metrics)
     monkeypatch.setitem(roofline.PEAKS, jax.devices()[0].device_kind,

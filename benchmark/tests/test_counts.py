@@ -73,6 +73,7 @@ def test_same_seed_same_requests_and_every_seed_the_same_schedule():
     c = generate.requests(mix, 6, 50257)
     assert a == b and a != c
     assert len(a) == mix["clients"]
+    assert all(len(reqs) == mix["requests_per_client"] for reqs in a)
     # the seed draws the tokens; who sends which lengths when is the same
     sizes = lambda lists: [[(len(r["prompt"]), r["max_new"]) for r in reqs]  # noqa: E731
                            for reqs in lists]
@@ -84,6 +85,14 @@ def test_same_seed_same_requests_and_every_seed_the_same_schedule():
     page = 16
     assert sum(-(-reqs[0][0] // page) for reqs in sizes(a)) \
         < mix["warmup_steps"]
+    # no list runs out inside the window: a client is busy for at least
+    # its prompts' chunks and its output tokens, a step each, whatever it
+    # waits in the queue (the CPU replay has the first client silent at
+    # step 5,936, a cycle of 8.8 ms; this count alone gives 9.4)
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        seconds = json.load(f)["run_seconds"]
+    life = min(sum(-(-p // page) + o for p, o in reqs) for reqs in sizes(a))
+    assert life - mix["warmup_steps"] > seconds / 0.0095
 
 
 def test_open_loop_arrivals_are_the_mixes_too():
