@@ -378,7 +378,6 @@ def phase_serve(compute_dtype, **model_cfg) -> dict:
     import jax
 
     from deeplearning4j_tpu.engine.decode_program import DecodeProgram
-    from deeplearning4j_tpu.observability.perf import CostModel
     from deeplearning4j_tpu.parallel.serving import ModelClient, ModelServer
     from deeplearning4j_tpu.serving.continuous import (
         DecodeEngine,
@@ -420,10 +419,12 @@ def phase_serve(compute_dtype, **model_cfg) -> dict:
                                model="decoder")
         check(prog.trace_stats()["trace_counts"] == warm_counts,
               f"traffic retraced: {prog.trace_stats()['trace_counts']}")
-        # cost analysis traces and compiles on THIS thread while the
-        # engine thread steps and fetches (nxt, ok) for a live request
+        # the decode step is lowered and compiled again on THIS thread
+        # while the engine thread steps and fetches (nxt, ok) for a
+        # live request
         live = eng.submit(mixed[-1], 2 * max_new)
-        cost = prog.register_perf(CostModel())
+        step = prog.lint_records()[0]
+        cost = step.fn.lower(*step.example_args).compile().cost_analysis()
         live_out = live.result(timeout_s=300)
     finally:
         server.stop()
@@ -445,7 +446,7 @@ def phase_serve(compute_dtype, **model_cfg) -> dict:
           f"no prefix hit: {stats['prefix_requests_hit']}")
     check(audit["leaked"] == 0 and not audit["double_freed"],
           f"page audit {audit}")
-    check(cost is not None and cost["flops"] > 0,
+    check(cost.get("flops", 0) > 0,
           f"no cost analysis on this backend: {cost}")
     tokens = sum(len(o) for o in outs)
     return {"compute_dtype": compute_dtype or "float32",
@@ -624,16 +625,17 @@ def main(argv=None, sizes=FULL) -> int:
     cache_dir = place_compile_cache()
     import jax
 
+    from benchmark.roofline import device_peaks
     from deeplearning4j_tpu import native
-    from deeplearning4j_tpu.observability.perf import device_peaks
 
     t_start = time.perf_counter()
     dev = require_chip()
-    peak_flops, peak_bw, kind = device_peaks(dev)
-    report("start", platform=dev.platform, kind=kind,
+    peaks = device_peaks(str(dev.device_kind))
+    peak_flops = peaks["flops"]
+    report("start", platform=dev.platform, kind=str(dev.device_kind),
            count=len(jax.devices()), jax=jax.__version__,
            compile_cache_dir=cache_dir, native_available=native.available(),
-           peak_flops=peak_flops, peak_bytes_per_s=peak_bw)
+           peak_flops=peak_flops, peak_bytes_per_s=peaks["bytes_per_s"])
     if args.chips == 4:
         check(len(jax.devices()) == 4,
               f"--chips 4 needs four devices, jax found "

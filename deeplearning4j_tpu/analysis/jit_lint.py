@@ -141,8 +141,7 @@ def _is_jax_jit(func) -> bool:
 def _partial_jit_aliases(sf: SourceFile) -> Dict[str, ast.Call]:
     """Module-level `jit = functools.partial(jax.jit, ...)` aliases:
     name -> the partial() Call carrying the jit kwargs. Call sites of
-    the alias are jit sites with those kwargs (a previously-missed
-    form — the bench's flagship program is built this way)."""
+    the alias are jit sites with those kwargs."""
     aliases: Dict[str, ast.Call] = {}
     for node in sf.tree.body:
         if not (isinstance(node, ast.Assign)

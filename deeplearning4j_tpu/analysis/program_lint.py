@@ -6,7 +6,7 @@ between delivered and peak flops hides in dtype/layout/fusion details
 invisible at the Python level (Tensor Processing Primitives, arXiv
 2104.05755; cuDNN primitives, arXiv 1410.0759) — so every registered
 compiled program (the StepProgram single/graph/TBPTT/k-group variants,
-the serving bucket programs, the bench flagship, the clustering steps)
+the serving bucket programs, the decode programs, the clustering steps)
 is traced/lowered here and checked against its *declared* facts:
 
   prog-fp32-matmul-under-policy  dot/conv operand dtypes contradict the

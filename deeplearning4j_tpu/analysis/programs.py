@@ -9,6 +9,11 @@ registration) but at CPU-lintable dims:
   engine_graph                StepProgram on a ComputationGraph (the
                               flat-chain train program)
   engine_tbptt                the train_c program with donated carries
+  engine_resnet50 / _group_k2 StepProgram on zoo ResNet50 built as the
+                              training cell builds it (Nesterov, bf16
+                              compute, fused helper tier) at reduced
+                              dims: the single step and the k-step
+                              scan group
   engine_zero1                the ZeRO-1 mesh-sharded step over the
                               CPU device mesh, example args staged
                               sharded — the prog-unsharded-optimizer-
@@ -27,12 +32,7 @@ registration) but at CPU-lintable dims:
   clustering_kmeans_lloyd     the donated Lloyd iteration
   clustering_tsne_step        the donated embedding step (the program
                               whose dropped donation the first audit
-                              run caught — PERF.md)
-  bench_flagship_k_steps      the bench's ResNet50 k-step program at
-                              reduced dims
-  graft_entry_forward         the published __graft_entry__ forward,
-                              pinned to the flagship bf16 policy (the
-                              fp32-default the first audit run caught)
+                              run caught)
 
 Everything here imports jax — it is loaded lazily by the runner ONLY
 in `--programs` mode, so the default AST-only CLI keeps its zero-
@@ -42,15 +42,10 @@ imports jax; the whole set builds + lints in well under 60s on CPU.
 
 from __future__ import annotations
 
-import importlib.util
 import os
-import sys
-from pathlib import Path
 from typing import List
 
 from deeplearning4j_tpu.analysis.program_lint import ProgramRecord
-
-_ROOT = Path(__file__).resolve().parents[2]
 
 
 def _engine_records() -> List[ProgramRecord]:
@@ -177,28 +172,22 @@ def _clustering_records() -> List[ProgramRecord]:
     return records
 
 
-def _flagship_records() -> List[ProgramRecord]:
-    if str(_ROOT) not in sys.path:
-        sys.path.insert(0, str(_ROOT))
-    spec = importlib.util.spec_from_file_location(
-        "dl4j_bench", _ROOT / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    jit_k, args, _, _ = bench.make_flagship_program(
-        batch=2, hw=32, n_classes=8, unroll=2)
-    records = [ProgramRecord(
-        name="bench_flagship_k_steps", fn=jit_k, example_args=args,
-        precision_policy="bf16", source="bench.py",
-        consumed_outputs=(0, 1, 2, 3))]
+def _resnet50_records() -> List[ProgramRecord]:
+    """The program the training cell times: zoo ResNet50 with the
+    constructor of the benchmark's `resnet50-imagenet` configuration,
+    through StepProgram, at a size the CPU lowers in seconds."""
+    import jax.numpy as jnp
 
-    from __graft_entry__ import entry
+    from deeplearning4j_tpu.engine import StepProgram
+    from deeplearning4j_tpu.zoo import ResNet50
 
-    fwd, fargs = entry(hw=32, n_classes=8)
-    records.append(ProgramRecord(
-        name="graft_entry_forward", fn=fwd, example_args=fargs,
-        precision_policy="bf16",
-        source="__graft_entry__.py"))
-    return records
+    net = ResNet50(num_classes=8, input_shape=(32, 32, 3),
+                   updater="nesterovs", learning_rate=0.01,
+                   compute_dtype="bfloat16",
+                   helpers="fused").init_model()
+    return StepProgram(net).lint_records(
+        jnp.zeros((2, 32, 32, 3), jnp.float32),
+        jnp.zeros((2, 8), jnp.float32), k=2, name="engine_resnet50")
 
 
 def build_default_records() -> List[ProgramRecord]:
@@ -212,7 +201,7 @@ def build_default_records() -> List[ProgramRecord]:
     records += _serving_records()
     records += _decode_records()
     records += _clustering_records()
-    records += _flagship_records()
+    records += _resnet50_records()
     return records
 
 

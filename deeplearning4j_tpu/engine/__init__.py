@@ -16,11 +16,10 @@ single step program to shard. Two halves:
                 end-to-end), owner of the shared loss/update closures
                 the local-SGD and stale-gradient trainers also compile
                 from, registered with the net's JitCache (recompile
-                forensics) and a CostModel (MFU gauges) on demand.
+                forensics).
                 Optional `lax.scan` k-step grouping: one dispatch
-                advances k steps — the dispatch-amortization role of
-                the bench's hand-unrolled k_steps_fn, generalized —
-                while per-inner-step dp-visible losses are preserved
+                advances k steps while per-inner-step dp-visible
+                losses are preserved
                 so a NonFiniteGuard can condemn ONE poisoned inner
                 step instead of the whole window.
   StepHarness   the host half — one supervisor owning the

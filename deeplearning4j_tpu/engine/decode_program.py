@@ -85,10 +85,9 @@ operand values in identical order to the sequential oracle's — the
 FP-associativity discipline that makes "bitwise equal to the oracle"
 achievable at all.
 
-Forensics / policy / MFU ride the exact StepProgram rails: programs
-live in the model's JitCache (record_trace inside traced bodies,
-register_policy per key) and `register_perf` attaches XLA cost-model
-entries so MFU gauges and compile-event cost digests follow.
+Forensics and policy ride the exact StepProgram rails: programs live
+in the model's JitCache (record_trace inside traced bodies,
+register_policy per key).
 """
 
 from __future__ import annotations
@@ -517,28 +516,3 @@ class DecodeProgram:
                 precision_policy=self.precision_policy, source=source,
                 consumed_outputs=(0,)),
         ]
-
-    # ------------------------------------------------------------ perf
-    def register_perf(self, cost_model, bucket_len: Optional[int] = None):
-        """Attach XLA cost-model entries for the decode step (and the
-        chunk-prefill program when `bucket_len` is given) to
-        `cost_model` — MFU gauges + forensics cost digests, the
-        StepProgram.register_perf discipline. Best-effort: returns the
-        decode entry or None."""
-        import jax.numpy as jnp
-
-        cache = self.model._jit_cache
-        kv = self.init_kv()
-        s, p = self.max_slots, self.pages_per_slot
-        zs = jnp.zeros(s, jnp.int32)
-        entry = cost_model.register_jit_entry(
-            cache, self.decode_key(), self.model.params, kv, zs, zs,
-            jnp.zeros((s, p), jnp.int32), zs, zs)
-        if bucket_len:
-            self._chunk_program()
-            cost_model.register_jit_entry(
-                cache, self.chunk_key(), self.model.params,
-                self.init_kv(),
-                jnp.zeros(self.page_size, jnp.int32), jnp.int32(0),
-                jnp.zeros(p, jnp.int32), jnp.int32(1))
-        return entry

@@ -230,7 +230,7 @@ class MeshManager:
         """Per-replica optimizer-state memory under the current
         placement: full bytes, this-replica bytes (shard-aware), and
         the ratio — the measurable 1/n claim (asserted from array
-        shard shapes in tests and reported by `bench.py mesh`)."""
+        shard shapes in tests)."""
         import jax
 
         full = 0

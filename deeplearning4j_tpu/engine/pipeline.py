@@ -1,9 +1,7 @@
 """Harness-owned input pipeline: overlap data_wait + h2d with compute.
 
-ROADMAP item 1's prefetch clause. `DevicePrefetchIterator` existed in
-datasets/iterators.py since PR 3 but only bench.py used it — every real
-fit loop still pulled host batches synchronously, so ETL (`data_wait`)
-and the host→device copy (`h2d`) serialized with `device_compute`.
+A fit loop that pulls host batches synchronously serializes ETL
+(`data_wait`) and the host→device copy (`h2d`) with `device_compute`.
 This module gives the engine's StepHarness ownership of the staging so
 the accelerator never blocks on the host for the next batch (the
 keep-the-MXU-fed premise of Tensor Processing Primitives, arXiv

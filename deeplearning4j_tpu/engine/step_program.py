@@ -19,13 +19,10 @@ wraps a container net (MultiLayerNetwork or ComputationGraph) and owns:
     losses (`last_step_losses`) so a NonFiniteGuard can condemn a
     single poisoned inner step instead of the whole window. This
     generalizes the local-SGD grouping (which adds a dp rendezvous on
-    top) and the bench's hand-unrolled k_steps_fn (dispatch
-    amortization, PERF.md);
-  - perf registration: the group program lands in the net's JitCache
-    (key `("engine_group", ...)`, `record_trace` inside the traced
-    body) so recompile forensics cover it, and `register_perf`
-    attaches an XLA cost-analysis entry to a CostModel so MFU gauges
-    and the forensics cost digest follow automatically.
+    top);
+  - forensics: the group program lands in the net's JitCache (key
+    `("engine_group", ...)`, `record_trace` inside the traced body) so
+    recompile forensics cover it.
 """
 
 from __future__ import annotations
@@ -524,18 +521,3 @@ class StepProgram:
             source="deeplearning4j_tpu/engine/sharding.py",
             consumed_outputs=tuple(range(4)),
             sharded_argnums=(1,))
-
-    # ------------------------------------------------------------- perf
-    def register_perf(self, cost_model, key=None, *example_args,
-                      analytic_flops=None, analytic_bytes=None):
-        """Attach an XLA cost-analysis entry for a compiled engine
-        program to `cost_model` (and, through it, the JitCache
-        forensics ring). `key` defaults to the net's k=1 train entry;
-        pass a `group_key(...)` to register a k-step group. Best-effort
-        like serving warmup: returns the entry dict or None."""
-        cache = self.net._jit_cache
-        if key is None:
-            key = ("train", self._frozen_sig())
-        return cost_model.register_jit_entry(
-            cache, key, *example_args, analytic_flops=analytic_flops,
-            analytic_bytes=analytic_bytes)

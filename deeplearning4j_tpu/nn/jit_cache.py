@@ -14,12 +14,9 @@ Recompile FORENSICS (the "why did step 1042 take 8s" instrument):
 whose trace counter advanced included a trace+compile; the shim records
 a compile event — the concrete shape/dtype signature of the args that
 caused it, the call's wall duration (dominated by trace+compile on a
-compile call), a wall-clock timestamp, and the program's cost-model
-digest when one was registered (`register_cost`, fed by
-`observability.perf.CostModel.register_jit_entry`) — into a bounded
-ring surfaced on /status, and bumps `dl4j_jit_compiles_total`. Calls
-that hit the compiled cache pay two perf_counter reads and one int
-compare.
+compile call) and a wall-clock timestamp — into a bounded ring surfaced
+on /status, and bumps `dl4j_jit_compiles_total`. Calls that hit the
+compiled cache pay two perf_counter reads and one int compare.
 
     cache = JitCache()
     def f(x):
@@ -48,9 +45,9 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def place_compile_cache() -> str:
     """Where XLA's persistent compilation cache lives — the ONE place
-    this repository decides it; every entry point (chip_smoke.py, the
-    bench scripts, __graft_entry__, tests/conftest.py) calls this and
-    sets no directory itself. `JAX_COMPILATION_CACHE_DIR` wins: jax
+    this repository decides it; every entry point (chip_smoke.py,
+    benchmark/run.py, __graft_entry__, tests/conftest.py) calls this
+    and sets no directory itself. `JAX_COMPILATION_CACHE_DIR` wins: jax
     reads it on its own, so nothing is set here and whoever runs the
     program places the cache. Otherwise `<checkout>/.jax_cache`
     (git-ignored). The path is part of every entry's key, so it is
@@ -147,7 +144,6 @@ class JitCache(dict):
         self._compiles = 0
         self._compile_events: deque = deque(
             maxlen=max(1, int(compile_ring)))
-        self._costs: Dict[str, dict] = {}
         self._policies: Dict[str, str] = {}
         for k, v in dict(*args, **kwargs).items():
             self[k] = v
@@ -203,35 +199,11 @@ class JitCache(dict):
             "duration_s": round(duration_s, 6),
             "traces": int(traces),
             "wall_time": time.time(),
-            "cost_digest": self._cost_digest(key),
         }
         with self._trace_lock:
             self._compiles += int(traces)
             self._compile_events.append(event)
         _obs.count("dl4j_jit_compiles_total", n=int(traces))
-
-    def _cost_digest(self, key) -> Optional[dict]:
-        cost = self._costs.get(str(key))
-        if cost is None:
-            return None
-        return {"flops": cost.get("flops"),
-                "bytes_accessed": cost.get("bytes_accessed")}
-
-    def register_cost(self, key, cost: dict) -> None:
-        """Attach a cost-model entry ({flops, bytes_accessed, ...}) to
-        `key`: future compile events for the key carry the digest, and
-        ring events already recorded without one are backfilled."""
-        with self._trace_lock:
-            self._costs[str(key)] = dict(cost)
-            digest = {"flops": cost.get("flops"),
-                      "bytes_accessed": cost.get("bytes_accessed")}
-            for ev in self._compile_events:
-                if ev["key"] == str(key) and ev["cost_digest"] is None:
-                    ev["cost_digest"] = dict(digest)
-
-    def costs(self) -> Dict[str, dict]:
-        with self._trace_lock:
-            return {k: dict(v) for k, v in self._costs.items()}
 
     def register_policy(self, key, policy: str) -> None:
         """Declare the compute-precision policy of the program stored

@@ -27,12 +27,10 @@ from deeplearning4j_tpu.observability.metrics import (  # noqa: F401
     render_prometheus,
 )
 from deeplearning4j_tpu.observability.perf import (  # noqa: F401
-    CostModel,
     StepPhaseProfiler,
     aggregate_prometheus_text,
     aggregate_snapshots,
     dump_snapshot,
-    extract_cost,
 )
 from deeplearning4j_tpu.observability.tracing import (  # noqa: F401
     Span,

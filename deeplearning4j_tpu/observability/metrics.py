@@ -21,7 +21,7 @@ each of which passes through the `obs.emit` fault point and swallows
 ANY exception (counted in `dl4j_obs_dropped_emissions_total`) — an
 injected or real telemetry failure must never break a training step or
 drop a request. `enable(False)` turns every helper into a constant-time
-no-op (the bench_obs.py baseline).
+no-op.
 
 `REGISTERED_METRICS` is the canonical name registry, pinned by a test
 exactly like `faults.REGISTERED_POINTS`: every emission site in the
@@ -125,10 +125,6 @@ REGISTERED_METRICS = frozenset({
     "dl4j_jit_traces_total",
     "dl4j_jit_compiles_total",
     # performance introspection (observability/perf.py)
-    "dl4j_perf_mfu",
-    "dl4j_perf_program_flops",
-    "dl4j_perf_program_bytes",
-    "dl4j_perf_arithmetic_intensity",
     "dl4j_train_phase_seconds",
     # harness-owned input pipeline (engine/pipeline.py)
     "dl4j_pipeline_batches_total",
@@ -536,10 +532,10 @@ def get_registry() -> MetricsRegistry:
 
 def enable(on: bool = True) -> None:
     """Global kill switch: enable(False) turns every emission helper
-    into a constant-time no-op (the bench_obs.py off-baseline). Hot single-threaded loops (the
-per-step training sites) batch through a `StepAccumulator` instead:
-container appends per step, one guarded registry write per 32 steps —
-same totals, ~10x less in-situ cost (PERF.md "Telemetry overhead")."""
+    into a constant-time no-op. Hot single-threaded loops (the
+    per-step training sites) batch through a `StepAccumulator` instead:
+    container appends per step, one guarded registry write per 32
+    steps, same totals."""
     global _ENABLED
     _ENABLED = bool(on)
 

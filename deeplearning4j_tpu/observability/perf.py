@@ -1,22 +1,8 @@
-"""Performance introspection: cost-model MFU accounting, step phase
-attribution, cross-rank metric aggregation.
+"""Performance introspection: step phase attribution, the step
+timeline, cross-rank metric aggregation.
 
-ROADMAP item 2 ("profile the step, then attack") needs the repo to
-explain its own step time before anything cuts it. Four instruments,
-all riding the PR 5 telemetry substrate:
+Three instruments, all host-side bookkeeping:
 
-  CostModel            per-compiled-program flops / bytes-accessed /
-                       peak-memory from XLA cost analysis
-                       (`lowered.compile().cost_analysis()`), with an
-                       analytic fallback for backends that return
-                       nothing. Yields exact MFU (measured step time x
-                       program flops / device peak), arithmetic
-                       intensity, and a roofline classification — the
-                       flops/bytes accounting the TPP (arXiv
-                       2104.05755) and weight-update-sharding (arXiv
-                       2004.13336) work both lean on to decide WHERE
-                       to optimize. `perf_report()` lands the numbers
-                       as registry gauges and a dict.
   StepPhaseProfiler    the one step-phase recorder of both planes:
                        decomposes every step into named phases from
                        perf_counter marks. A fit loop's phases
@@ -31,9 +17,7 @@ all riding the PR 5 telemetry substrate:
                        `observability.tracing.clock_offset` lays over
                        a device trace.
   recompile forensics  lives in nn/jit_cache.py (signature + duration
-                       ring per new trace, `dl4j_jit_compiles_total`);
-                       `CostModel.register_jit_entry` attaches cost
-                       digests to the ring.
+                       ring per new trace, `dl4j_jit_compiles_total`).
   aggregate_snapshots  rank-0 pull path: merge per-rank
                        MetricsRegistry snapshot dumps (written by
                        `dump_snapshot`, e.g. from distributed_worker
@@ -43,9 +27,12 @@ all riding the PR 5 telemetry substrate:
                        same `render_prometheus` as a single /metrics
                        body.
 
-Everything here is host-side bookkeeping: no jax import at module
-scope, so the aggregation path stays usable in no-jax drills
-(cluster supervisor, tier-1 tests).
+Operation counts, device peaks and utilization are the benchmark's
+(`benchmark/roofline.py`, `benchmark/layer_metrics/`), not the
+package's.
+
+No jax import at module scope, so the aggregation path stays usable in
+no-jax drills (cluster supervisor, tier-1 tests).
 """
 
 from __future__ import annotations
@@ -58,263 +45,6 @@ from typing import Dict, List, Optional, Tuple
 
 from deeplearning4j_tpu.observability import metrics as _obs
 from deeplearning4j_tpu.observability.metrics import render_prometheus
-
-# Per-chip peaks, keyed by `device_kind`: (bf16 FLOP/s, HBM bytes/s) —
-# the two roofline axes. Sources: Google Cloud TPU documentation,
-# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s), "TPU v4" (275 TFLOP/s,
-# 1228 GB/s), "TPU v3" (123 TFLOP/s, 900 GB/s). A device that is not
-# in the table is an error, not a default: a utilization against a
-# guessed peak is a made-up number.
-PEAKS = {
-    "TPU v5 lite": (197e12, 819e9),
-    "TPU v4": (275e12, 1228e9),
-    "TPU v3": (123e12, 900e9),
-}
-
-
-def device_peaks(device=None) -> Tuple[float, float, str]:
-    """(peak_flops, peak_bytes_per_s, device_kind) for `device` (default
-    jax.devices()[0]). Raises KeyError for a kind `PEAKS` does not
-    list — the CPU among them."""
-    if device is None:
-        import jax
-
-        device = jax.devices()[0]
-    kind = str(device.device_kind)
-    if kind not in PEAKS:
-        raise KeyError(
-            f"no published peak for device kind {kind!r} (known: "
-            f"{sorted(PEAKS)}); add it to observability.perf.PEAKS "
-            "with its source")
-    flops, bw = PEAKS[kind]
-    return flops, bw, kind
-
-
-# ------------------------------------------------ analytic flop counts
-def matmul_flops(m: int, k: int, n: int) -> float:
-    """[m,k] @ [k,n]: one multiply + one add per MAC."""
-    return 2.0 * m * k * n
-
-
-def conv2d_flops(batch: int, out_h: int, out_w: int, c_out: int,
-                 kh: int, kw: int, c_in: int) -> float:
-    """Direct convolution MACs x2 (XLA's accounting for VALID padding;
-    SAME padding does fewer real MACs at the edges, which XLA also
-    counts exactly — use this only as the fallback/cross-check)."""
-    return 2.0 * batch * out_h * out_w * c_out * kh * kw * c_in
-
-
-def train_step_flops_from_params(n_params: int, rows: int) -> float:
-    """The classic 6NB estimate (2NB forward + 4NB backward) for a
-    dense model with N params on a B-row batch — the coarse analytic
-    fallback when XLA reports nothing and no exact count is known."""
-    return 6.0 * float(n_params) * float(rows)
-
-
-# ------------------------------------------------- XLA cost extraction
-def extract_cost(target, *args, **kwargs) -> Optional[dict]:
-    """Pull {flops, bytes_accessed, peak_bytes} from XLA cost analysis.
-
-    `target` is either a `jax.jit`-wrapped callable — lowered and
-    compiled here with the given example (or ShapeDtypeStruct) args —
-    or an already-compiled jax.stages object (the AOT path benches use
-    to avoid a duplicate compile). Returns None when `target` is
-    neither, or when the compiler counted no flops (the
-    analytic-fallback trigger)."""
-    compiled = target
-    if not hasattr(compiled, "cost_analysis"):
-        if not hasattr(target, "lower"):
-            return None
-        compiled = target.lower(*args, **kwargs).compile()
-    ca = compiled.cost_analysis()    # one dict per executable (jax 0.9)
-    flops = float(ca.get("flops", 0.0))
-    if flops <= 0.0:
-        return None
-    mem = compiled.memory_analysis()
-    return {"flops": flops,
-            "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
-            "peak_bytes": int(mem.temp_size_in_bytes
-                              + mem.argument_size_in_bytes
-                              + mem.output_size_in_bytes)}
-
-
-class CostModel:
-    """Per-program flops/bytes registry + MFU / roofline arithmetic.
-
-    Register each compiled program once (outside the timed region),
-    then `perf_report(key, seconds_per_call=...)` turns a measured
-    step time into MFU, arithmetic intensity, and a roofline verdict —
-    and lands them as `dl4j_perf_*` registry gauges so the dashboard
-    and /metrics see the same numbers the bench JSON records."""
-
-    def __init__(self, peak_flops: Optional[float] = None,
-                 peak_bytes_per_s: Optional[float] = None,
-                 device=None):
-        """Peaks come from `PEAKS` by the device's kind unless both are
-        given. On the CPU platform there is no peak: flops and bytes
-        are still counted, and `mfu`/`roofline` answer None — a CPU run
-        reports no utilization rather than one against an invented
-        peak. Any other device missing from the table raises."""
-        if device is None:
-            import jax
-
-            device = jax.devices()[0]
-        self.device_kind = str(device.device_kind)
-        if peak_flops and peak_bytes_per_s:
-            self.peak_flops = float(peak_flops)
-            self.peak_bytes_per_s = float(peak_bytes_per_s)
-        elif device.platform == "cpu":
-            self.peak_flops = self.peak_bytes_per_s = None
-        else:
-            self.peak_flops, self.peak_bytes_per_s, _ = \
-                device_peaks(device)
-        self._entries: Dict[str, dict] = {}
-
-    # ------------------------------------------------------- register
-    def register_compiled(self, key, target, *args,
-                          analytic_flops: Optional[float] = None,
-                          analytic_bytes: Optional[float] = None,
-                          **kwargs) -> dict:
-        """XLA cost analysis first; `analytic_*` are the fallback for
-        backends whose cost analysis returns nothing. Raises ValueError
-        only when BOTH sources are empty."""
-        entry = extract_cost(target, *args, **kwargs)
-        if entry is not None:
-            entry["source"] = "xla_cost_analysis"
-        elif analytic_flops:
-            entry = {"flops": float(analytic_flops),
-                     "bytes_accessed": float(analytic_bytes or 0.0),
-                     "peak_bytes": None, "source": "analytic"}
-        else:
-            raise ValueError(
-                f"no cost available for {key!r}: XLA cost analysis "
-                "returned nothing and no analytic fallback was given")
-        self._entries[str(key)] = entry
-        return dict(entry)
-
-    def register_analytic(self, key, flops: float,
-                          bytes_accessed: float = 0.0) -> dict:
-        entry = {"flops": float(flops),
-                 "bytes_accessed": float(bytes_accessed),
-                 "peak_bytes": None, "source": "analytic"}
-        self._entries[str(key)] = entry
-        return dict(entry)
-
-    def register_jit_entry(self, cache, key, *args,
-                           analytic_flops: Optional[float] = None,
-                           analytic_bytes: Optional[float] = None,
-                           **kwargs) -> Optional[dict]:
-        """Cost for a JitCache entry: unwraps the cache's forensics
-        wrapper, extracts/falls back, and hands the digest back to the
-        cache so its recompile ring carries it. Returns None (instead
-        of raising) when no cost is available — serving warmup calls
-        this opportunistically."""
-        fn = cache.get(key)
-        if fn is None:
-            return None
-        fn = getattr(fn, "__wrapped__", fn)
-        try:
-            entry = self.register_compiled(
-                key, fn, *args, analytic_flops=analytic_flops,
-                analytic_bytes=analytic_bytes, **kwargs)
-        except ValueError:
-            return None
-        if hasattr(cache, "register_cost"):
-            cache.register_cost(key, entry)
-        return entry
-
-    # ----------------------------------------------------------- reads
-    def entry(self, key) -> Optional[dict]:
-        e = self._entries.get(str(key))
-        return dict(e) if e is not None else None
-
-    def keys(self) -> List[str]:
-        return list(self._entries)
-
-    def arithmetic_intensity(self, key) -> Optional[float]:
-        e = self._entries.get(str(key))
-        if e is None or not e.get("bytes_accessed"):
-            return None
-        return e["flops"] / e["bytes_accessed"]
-
-    def mfu(self, key, seconds_per_call: float) -> Optional[float]:
-        """Model flops utilization: program flops / wall seconds /
-        device peak. The honest headline — counts the flops the model
-        NEEDS (as compiled), not the flops the kernel burned."""
-        e = self._entries.get(str(key))
-        if e is None or seconds_per_call <= 0.0 or not self.peak_flops:
-            return None
-        return e["flops"] / seconds_per_call / self.peak_flops
-
-    def roofline(self, key) -> Optional[dict]:
-        """Where this program sits on the roofline: arithmetic
-        intensity vs the ridge point (peak_flops / peak_bw), plus the
-        bandwidth-bound attainable flops ceiling."""
-        ai = self.arithmetic_intensity(key)
-        if ai is None or not self.peak_flops:
-            return None
-        ridge = self.peak_flops / self.peak_bytes_per_s
-        return {
-            "arithmetic_intensity": ai,
-            "ridge_point": ridge,
-            "bound": "compute" if ai >= ridge else "memory",
-            "attainable_flops_per_s": min(
-                self.peak_flops, ai * self.peak_bytes_per_s),
-        }
-
-    def perf_report(self, key, seconds_per_call: Optional[float] = None,
-                    items_per_call: Optional[float] = None) -> dict:
-        """One dict with everything ROADMAP item 2 needs to cite:
-        flops, bytes, arithmetic intensity, roofline verdict, and (when
-        a measured `seconds_per_call` is given) MFU + achieved
-        flops/s. Also lands the numbers as `dl4j_perf_*` gauges."""
-        e = self._entries.get(str(key))
-        if e is None:
-            raise KeyError(f"no cost registered for {key!r}")
-        report = {
-            "program": str(key),
-            "source": e["source"],
-            "flops": e["flops"],
-            "bytes_accessed": e["bytes_accessed"],
-            "peak_bytes": e.get("peak_bytes"),
-            "device_kind": self.device_kind,
-            "peak_flops": self.peak_flops,
-            "peak_bytes_per_s": self.peak_bytes_per_s,
-        }
-        roof = self.roofline(key)
-        if roof is not None:
-            report.update(roof)
-        if items_per_call:
-            report["flops_per_item"] = e["flops"] / items_per_call
-        if seconds_per_call:
-            report["seconds_per_call"] = seconds_per_call
-            report["achieved_flops_per_s"] = \
-                e["flops"] / seconds_per_call
-            report["mfu"] = self.mfu(key, seconds_per_call)
-        labels = {"program": str(key)}
-        _obs.set_gauge("dl4j_perf_program_flops", e["flops"],
-                       labels=labels)
-        _obs.set_gauge("dl4j_perf_program_bytes", e["bytes_accessed"],
-                       labels=labels)
-        if roof is not None:
-            _obs.set_gauge("dl4j_perf_arithmetic_intensity",
-                           roof["arithmetic_intensity"], labels=labels)
-        if report.get("mfu") is not None:
-            _obs.set_gauge("dl4j_perf_mfu", report["mfu"],
-                           labels=labels)
-        return report
-
-    def digest(self, key) -> Optional[dict]:
-        """Compact {flops, bytes, ai} for the JitCache forensics ring."""
-        e = self._entries.get(str(key))
-        if e is None:
-            return None
-        ai = self.arithmetic_intensity(key)
-        return {"flops": e["flops"],
-                "bytes_accessed": e["bytes_accessed"],
-                "arithmetic_intensity":
-                    round(ai, 3) if ai is not None else None}
-
 
 # ------------------------------------------------ step phase profiler
 PHASES = ("data_wait", "h2d", "dispatch", "device_compute",
@@ -566,8 +296,8 @@ def aggregate_snapshots(sources) -> dict:
     quantiles cannot merge exactly and are dropped), gauges re-keyed
     with a rank label so per-rank values stay distinguishable. The
     result renders through `render_prometheus` — the fleet /metrics
-    body MULTICHIP benches and the cluster supervisor report instead
-    of rank-local numbers."""
+    body the cluster supervisor reports instead of rank-local
+    numbers."""
     merged: dict = {"counters": {}, "gauges": {}, "histograms": {},
                     "ranks": 0, "uptime_s": 0.0}
     for i, source in enumerate(sources):
@@ -601,11 +331,8 @@ def aggregate_prometheus_text(sources) -> str:
 
 
 __all__ = [
-    "PEAKS", "PHASES",
-    "CostModel", "StepPhaseProfiler", "TIMELINE_CAPACITY",
+    "PHASES", "StepPhaseProfiler", "TIMELINE_CAPACITY",
     "get_timeline", "perf_to_unix_ns", "phase_spans", "record_request",
-    "device_peaks", "extract_cost",
-    "matmul_flops", "conv2d_flops", "train_step_flops_from_params",
     "dump_snapshot", "aggregate_snapshots", "aggregate_prometheus_text",
     "render_prometheus",
 ]

@@ -26,8 +26,7 @@ Completions may land out of dispatch order; per-row-range delivery
 makes that harmless. The in-flight window is bounded
 (`pipeline_depth`), so backpressure still cascades: window full ->
 assembler stalls -> request queue fills -> `output()` sheds load.
-`pipeline_depth=0` degrades to the serialized dispatch-then-fetch loop
-(the bench_serving.py comparison baseline).
+`pipeline_depth=0` degrades to the serialized dispatch-then-fetch loop.
 
 Multi-input coalescing: a request may carry one array per network
 input (`output(x_a, x_b)` — ComputationGraph-style named inputs), all

@@ -241,8 +241,7 @@ class TrainingMaster:
             self._harness.program.attach_mesh(self._mesh_mgr)
 
     # tracer / phase_profiler delegate to the harness so post-
-    # construction assignment (bench_obs.py's config sweep) reaches
-    # the loop that actually reads them
+    # construction assignment reaches the loop that actually reads them
     @property
     def tracer(self):
         return self._harness.tracer

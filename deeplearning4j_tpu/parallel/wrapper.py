@@ -786,8 +786,8 @@ class LocalStepTrainer:
     def run_arrays(self, xs_in, ys_in, fms_in=None, lms_in=None, k=None):
         """Run one k-step local-SGD group on pre-staged arrays with a
         leading [k, ...] step dim. Device-resident arrays can be passed
-        repeatedly without re-staging — this is how the bench amortizes
-        host->device transfer and per-dispatch latency over k steps."""
+        repeatedly without re-staging, which amortizes host->device
+        transfer and per-dispatch latency over k steps."""
         net = self.net
         is_graph = hasattr(net.conf, "network_inputs")
         if k is None:

@@ -13,8 +13,8 @@ Three responsibilities:
               warm-before-flip through the registry hot-swap the
               replica already implements (PUT with activate=False,
               then swap), then WATCHES the canary's error-rate / p99 /
-              `dl4j_perf_*` telemetry — scraped per replica and merged
-              through the PR 7 cross-rank snapshot aggregation — in
+              TTFT p99 — scraped per replica and merged through the
+              cross-rank snapshot aggregation — in
               consecutive windows against a declared `SLOPolicy`.
               Healthy windows ramp the remaining replicas one by one;
               a breach auto-rolls the canary (and any already-flipped
@@ -283,11 +283,9 @@ def slo_sample(prev: dict, cur: dict,
     err = _error_total(cur) - _error_total(prev)
     p99 = _hist_p99_delta(prev, cur, hist)
     ttft_p99 = _hist_p99_delta(prev, cur, "dl4j_decode_ttft_seconds")
-    mfu_series = cur.get("gauges", {}).get("dl4j_perf_mfu") or {}
-    mfu = list(mfu_series.values())[-1] if mfu_series else None
     return {"requests": req, "errors": err,
             "error_rate": (err / req) if req > 0 else 0.0,
-            "p99_s": p99, "ttft_p99_s": ttft_p99, "mfu": mfu}
+            "p99_s": p99, "ttft_p99_s": ttft_p99}
 
 
 # ------------------------------------------------------ replica handles

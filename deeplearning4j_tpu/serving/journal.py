@@ -62,7 +62,6 @@ from __future__ import annotations
 
 import json
 import os
-import shutil
 import struct
 import threading
 import time
@@ -86,29 +85,14 @@ SEGMENT_SUFFIX = ".wal"
 # out); tests/conftest.py closes whatever a failed durability test left
 # open so no WAL file handle leaks into later tier-1 tests
 _LIVE_JOURNALS: "weakref.WeakSet[GenerationJournal]" = weakref.WeakSet()
-# mkdtemp dirs handed out by `ephemeral_journal_dir` — reaped with the
-# journals so an interrupted bench/test run leaks no /tmp litter
-_EPHEMERAL_DIRS: List[str] = []
 
 
 def reap_stray_journals() -> None:
-    """Close every journal still open and remove tracked ephemeral
-    dirs. Teardown backstop for chaos tests — idempotent, touches
-    nothing if every journal was closed properly."""
+    """Close every journal still open. Teardown backstop for chaos
+    tests — idempotent, touches nothing if every journal was closed
+    properly."""
     for j in list(_LIVE_JOURNALS):
         j.close()
-    while _EPHEMERAL_DIRS:
-        shutil.rmtree(_EPHEMERAL_DIRS.pop(), ignore_errors=True)
-
-
-def ephemeral_journal_dir(prefix: str = "dl4j-journal-") -> str:
-    """A mkdtemp journal dir tracked for teardown (bench/drill use —
-    tests prefer tmp_path): `reap_stray_journals` removes it."""
-    import tempfile
-
-    d = tempfile.mkdtemp(prefix=prefix)
-    _EPHEMERAL_DIRS.append(d)
-    return d
 
 
 # ------------------------------------------------------- record framing

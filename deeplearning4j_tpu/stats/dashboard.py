@@ -498,12 +498,9 @@ def telemetry_lines(snapshot) -> list:
             f"{c.get('dl4j_journal_recovered_requests_total', 0)} "
             "recovered · "
             f"{c.get('dl4j_journal_torn_tails_total', 0)} torn tails")
-    # performance introspection (observability/perf.py): cost-model
-    # MFU gauge, top phases by attributed share, recompile count
+    # performance introspection (observability/perf.py): top phases
+    # by attributed share, recompile count
     perf = []
-    mfu = gauge("dl4j_perf_mfu")
-    if mfu is not None:
-        perf.append(f"MFU {mfu:.3f}")
     phase_prefix = "dl4j_train_phase_seconds{phase="
     shares = {}
     for key, h in hists.items():

@@ -53,10 +53,13 @@ def steered(smoke, monkeypatch):
     whole."""
     import jax
 
-    monkeypatch.setattr(smoke, "require_chip", lambda: jax.devices()[0])
-    monkeypatch.setattr(
-        "deeplearning4j_tpu.observability.perf.device_peaks",
-        lambda dev=None: (1e30, 1e30, "steered"))
+    from benchmark import roofline
+
+    dev = jax.devices()[0]
+    monkeypatch.setattr(smoke, "require_chip", lambda: dev)
+    monkeypatch.setitem(roofline.PEAKS, str(dev.device_kind),
+                        {"flops": 1e30, "bytes_per_s": 1e30,
+                         "source": "steered"})
     return smoke
 
 
@@ -161,29 +164,24 @@ def test_compile_cache_is_placed_once(env_dir, monkeypatch):
 @pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
                                         ("TPU v9 imaginary", False),
                                         ("cpu", False)])
-def test_device_peaks_raises_on_unknown_kind(kind, known):
-    """A device without a published peak is an error, not a default —
-    and the CPU has none: CostModel there counts flops and bytes and
-    reports no MFU."""
+def test_device_peaks_raises_on_unknown_kind(smoke, monkeypatch, kind,
+                                             known):
+    """A device without a published peak is an error, not a default,
+    and the CPU has none: `main` asks the benchmark's table (the only
+    one) right after the platform check, before any phase."""
     import types
 
-    from deeplearning4j_tpu.observability.perf import (
-        CostModel,
-        device_peaks,
-    )
-
-    dev = types.SimpleNamespace(device_kind=kind, platform=(
-        "cpu" if kind == "cpu" else "tpu"))
+    dev = types.SimpleNamespace(device_kind=kind, platform="tpu")
+    monkeypatch.setattr(smoke, "require_chip", lambda: dev)
+    started = []
+    monkeypatch.setattr(smoke, "report",
+                        lambda phase, **facts: started.append(facts))
+    monkeypatch.setattr(smoke, "run_phase", lambda *a, **k: None)
     if known:
-        assert device_peaks(dev) == (197e12, 819e9, kind)
+        assert smoke.main([]) == 0
+        assert (started[0]["peak_flops"],
+                started[0]["peak_bytes_per_s"]) == (197e12, 819e9)
         return
     with pytest.raises(KeyError, match="no published peak"):
-        device_peaks(dev)
-    if kind == "cpu":
-        cm = CostModel(device=dev)
-        cm.register_analytic("k", flops=1e9, bytes_accessed=1e6)
-        assert cm.mfu("k", 1.0) is None and cm.roofline("k") is None
-        assert cm.perf_report("k", seconds_per_call=1.0)["flops"] == 1e9
-    else:
-        with pytest.raises(KeyError):
-            CostModel(device=dev)
+        smoke.main([])
+    assert not started

@@ -201,8 +201,7 @@ def test_k_group_matches_sequential_steps():
 
 def test_k_group_amortizes_dispatches():
     """One compiled-program call per k steps: the trace counter proves
-    the group compiles ONCE and the per-call shim sees iters/k calls
-    (the dispatch amortization BENCH_engine_k*.json measures)."""
+    the group compiles ONCE and the per-call shim sees iters/k calls."""
     import jax.numpy as jnp
 
     net = _net()
@@ -396,31 +395,6 @@ def test_parallel_wrapper_session_closes_iterator():
     it = AsyncDataSetIterator([_batch(s) for s in range(4)])
     ParallelWrapper(net, mesh=make_mesh(dp=1)).fit(it)
     assert it._thread is None     # joined by the harness teardown
-
-
-# ================================================== perf registration
-def test_step_program_registers_cost_model():
-    """The compiled step registers with CostModel + the JitCache
-    forensics ring (recompile events carry the cost digest)."""
-    import jax.numpy as jnp
-
-    from deeplearning4j_tpu.observability.perf import CostModel
-
-    net = _net()
-    prog = StepProgram(net)
-    x, y = _batch(0)
-    prog.run(jnp.asarray(x), jnp.asarray(y))   # compile the k=1 step
-    cm = CostModel(peak_flops=1e12, peak_bytes_per_s=1e11)
-    entry = prog.register_perf(
-        cm, None,
-        net.params, net.updater_states, net.states,
-        jnp.asarray(0, jnp.int32), jnp.asarray(x), jnp.asarray(y),
-        None, None, net._rng, None, jnp.asarray(1.0, jnp.float32),
-        analytic_flops=1e6)
-    assert entry is not None
-    assert entry["flops"] > 0
-    key = str(("train", ()))
-    assert net._jit_cache.costs().get(key) is not None
 
 
 def test_require_sgd_rejects_solvers():

@@ -262,10 +262,6 @@ def test_registered_metrics_cover_required_names():
         "dl4j_cluster_quarantined_workers_total",
         # performance introspection (observability/perf.py)
         "dl4j_jit_compiles_total",
-        "dl4j_perf_mfu",
-        "dl4j_perf_program_flops",
-        "dl4j_perf_program_bytes",
-        "dl4j_perf_arithmetic_intensity",
         "dl4j_train_phase_seconds",
     } <= set(REGISTERED_METRICS)
 

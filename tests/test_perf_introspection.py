@@ -1,15 +1,11 @@
-"""Performance introspection tests (PR 7 tentpole): CostModel flops
-within 1% of the analytic count (matmul + conv), perf_report fields +
-registry gauges, analytic fallback, StepPhaseProfiler ≥95% wall-time
+"""Performance introspection tests: StepPhaseProfiler ≥95% wall-time
 attribution on the CPU smoke config, labeled phase histograms through
 the StepAccumulator, JitCache recompile forensics (shape-shifted trace
-ring, cost digests, /status surface), cross-rank `aggregate_snapshots`
-exactness (no-jax drill: summed counters, merged histogram buckets,
-one fleet Prometheus exposition), the cluster supervisor's
-fleet_metrics pull path, the dashboard perf line, and the perf_gate
-tool's verdict/exit-code contract."""
+ring, /status surface), cross-rank `aggregate_snapshots` exactness
+(no-jax drill: summed counters, merged histogram buckets, one fleet
+Prometheus exposition), the cluster supervisor's fleet_metrics pull
+path, and the dashboard perf line."""
 
-import importlib.util
 import json
 import os
 import threading
@@ -24,14 +20,10 @@ from deeplearning4j_tpu.observability import (
 )
 from deeplearning4j_tpu.observability import perf as perf_mod
 from deeplearning4j_tpu.observability.perf import (
-    CostModel,
     StepPhaseProfiler,
     aggregate_prometheus_text,
     aggregate_snapshots,
-    conv2d_flops,
     dump_snapshot,
-    extract_cost,
-    matmul_flops,
 )
 
 pytestmark = pytest.mark.obs
@@ -66,96 +58,6 @@ def _batch(step):
     x = rng.normal(size=(ROWS, N_IN)).astype(np.float32)
     y = np.eye(N_OUT, dtype=np.float32)[rng.integers(0, N_OUT, ROWS)]
     return x, y
-
-
-# ==================================================== cost model: XLA
-def test_cost_model_matmul_flops_within_1pct():
-    """Acceptance: XLA-counted flops of a known matmul within 1% of
-    the analytic 2*m*k*n."""
-    import jax
-    import jax.numpy as jnp
-
-    m, k, n = 32, 64, 16
-    f = jax.jit(lambda a, b: jnp.dot(a, b))
-    cm = CostModel()
-    entry = cm.register_compiled(
-        "mm", f, jnp.ones((m, k), jnp.float32),
-        jnp.ones((k, n), jnp.float32))
-    analytic = matmul_flops(m, k, n)
-    assert entry["source"] == "xla_cost_analysis"
-    assert abs(entry["flops"] - analytic) / analytic < 0.01
-    assert entry["bytes_accessed"] > 0
-
-
-def test_cost_model_conv_flops_within_1pct():
-    """Acceptance: XLA-counted flops of a known VALID conv within 1%
-    of the analytic direct-convolution count."""
-    import jax
-    import jax.numpy as jnp
-
-    batch, hw, c_in, c_out, kk = 2, 16, 8, 32, 3
-
-    def conv(x, w):
-        return jax.lax.conv_general_dilated(
-            x, w, (1, 1), "VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-    cm = CostModel()
-    entry = cm.register_compiled(
-        "conv", jax.jit(conv),
-        jnp.ones((batch, hw, hw, c_in), jnp.float32),
-        jnp.ones((kk, kk, c_in, c_out), jnp.float32))
-    out_hw = hw - kk + 1
-    analytic = conv2d_flops(batch, out_hw, out_hw, c_out, kk, kk, c_in)
-    assert entry["source"] == "xla_cost_analysis"
-    assert abs(entry["flops"] - analytic) / analytic < 0.01
-
-
-def test_cost_model_analytic_fallback_and_missing_cost():
-    """A backend returning no cost analysis falls back to the supplied
-    analytic count; with neither, registration refuses loudly."""
-    cm = CostModel(peak_flops=1e12, peak_bytes_per_s=1e11)
-    assert extract_cost(object()) is None
-    entry = cm.register_compiled("blind", object(),
-                                 analytic_flops=6e9, analytic_bytes=1e8)
-    assert entry["source"] == "analytic"
-    assert entry["flops"] == 6e9
-    assert cm.mfu("blind", seconds_per_call=0.01) \
-        == pytest.approx(6e9 / 0.01 / 1e12)
-    with pytest.raises(ValueError):
-        cm.register_compiled("nothing", object())
-
-
-def test_perf_report_fields_and_registry_gauges():
-    """perf_report carries flops/bytes/AI/roofline/MFU and lands the
-    dl4j_perf_* gauges in the global registry."""
-    import jax
-    import jax.numpy as jnp
-
-    cm = CostModel(peak_flops=1e12, peak_bytes_per_s=1e11)
-    cm.register_compiled("mm", jax.jit(lambda a, b: jnp.dot(a, b)),
-                         jnp.ones((64, 64)), jnp.ones((64, 64)))
-    report = cm.perf_report("mm", seconds_per_call=1e-3,
-                            items_per_call=64)
-    for field in ("flops", "bytes_accessed", "arithmetic_intensity",
-                  "ridge_point", "bound", "mfu",
-                  "achieved_flops_per_s", "flops_per_item"):
-        assert field in report, field
-    assert 0.0 < report["mfu"] <= 1.0
-    assert report["bound"] in ("compute", "memory")
-    r = get_registry()
-    labels = {"program": "mm"}
-    assert r.gauge_value("dl4j_perf_mfu", labels=labels) \
-        == pytest.approx(report["mfu"])
-    assert r.gauge_value("dl4j_perf_program_flops", labels=labels) \
-        == report["flops"]
-    assert r.gauge_value("dl4j_perf_program_bytes", labels=labels) \
-        == report["bytes_accessed"]
-    assert r.gauge_value("dl4j_perf_arithmetic_intensity",
-                         labels=labels) \
-        == pytest.approx(report["arithmetic_intensity"])
-    # roofline arithmetic: ridge = peak_flops / peak_bw
-    assert report["ridge_point"] == pytest.approx(10.0)
 
 
 # ============================================= labeled histograms
@@ -292,35 +194,6 @@ def test_jit_cache_recompile_ring_captures_shape_shift():
         "dl4j_jit_compiles_total") == 2
 
 
-def test_jit_cache_cost_digest_backfill_and_register_jit_entry():
-    import jax
-    import jax.numpy as jnp
-
-    from deeplearning4j_tpu.nn.jit_cache import JitCache
-
-    cache = JitCache()
-
-    def f(x):
-        cache.record_trace("predict")
-        return jnp.dot(x, jnp.ones((3, 3), jnp.float32))
-
-    cache["predict"] = jax.jit(f)
-    x = jnp.ones((4, 3), jnp.float32)
-    cache["predict"](x)
-    assert cache.compile_events()[0]["cost_digest"] is None
-    cm = CostModel()
-    entry = cm.register_jit_entry(cache, "predict", x)
-    assert entry is not None and entry["flops"] > 0
-    # the already-recorded ring event was backfilled...
-    ev = cache.compile_events()[0]
-    assert ev["cost_digest"]["flops"] == entry["flops"]
-    # ...and a NEW shape-shifted trace carries the digest directly
-    cache["predict"](jnp.ones((16, 3), jnp.float32))
-    assert cache.compile_events()[-1]["cost_digest"]["flops"] \
-        == entry["flops"]
-    assert cache.costs()["predict"]["flops"] == entry["flops"]
-
-
 def test_net_predict_recompile_forensics_via_trace_stats():
     """A real net's predict path records forensics; ParallelInference
     trace_stats surfaces them (the /status source)."""
@@ -380,7 +253,7 @@ def _rank_registry(steps, step_s, errors):
     if errors:
         r.inc("dl4j_serving_errors_total", errors,
               labels={"code": "503"})
-    r.set_gauge("dl4j_perf_mfu", 0.1 * (1 + errors),
+    r.set_gauge("dl4j_train_loss", 0.1 * (1 + errors),
                 labels={"program": "train"})
     return r
 
@@ -407,7 +280,7 @@ def test_aggregate_snapshots_exactness():
     assert h["buckets"]["0.005"] == 5
     assert h["buckets"]["0.05"] == 7
     # per-rank gauges stay distinguishable
-    g = merged["gauges"]["dl4j_perf_mfu"]
+    g = merged["gauges"]["dl4j_train_loss"]
     assert g['{program="train",rank="0"}'] == pytest.approx(0.1)
     assert g['{program="train",rank="1"}'] == pytest.approx(0.3)
 
@@ -427,7 +300,7 @@ def test_aggregate_snapshot_files_to_fleet_exposition(tmp_path):
     assert "dl4j_train_steps_total 9" in text
     assert 'dl4j_serving_errors_total{code="503"} 3' in text
     assert "dl4j_train_step_seconds_count 9" in text
-    assert 'dl4j_perf_mfu{program="train",rank="2"}' in text
+    assert 'dl4j_train_loss{program="train",rank="2"}' in text
     # cumulative bucket counts stay monotonic in the merged exposition
     cums = [int(line.rsplit(" ", 1)[1])
             for line in text.splitlines()
@@ -477,7 +350,6 @@ def test_dashboard_perf_line_pinned():
         f.render() for f in dash)
 
     r = get_registry()
-    r.set_gauge("dl4j_perf_mfu", 0.42, labels={"program": "train"})
     for _ in range(3):
         r.observe("dl4j_train_phase_seconds", 0.030,
                   labels={"phase": "dispatch"})
@@ -487,88 +359,11 @@ def test_dashboard_perf_line_pinned():
               labels={"phase": "h2d"})
     r.inc("dl4j_jit_compiles_total", 3)
     joined = "\n".join(telemetry_lines(r))
-    assert ("perf — MFU 0.420 · phases dispatch 90%, data_wait 8% · "
+    assert ("perf — phases dispatch 90%, data_wait 8% · "
             "3 recompiles") in joined
     # empty registry → no perf line
     assert all("perf —" not in line
                for line in telemetry_lines(MetricsRegistry()))
-
-
-# ========================================================= perf gate
-def _load_perf_gate():
-    path = os.path.join(os.path.dirname(__file__), "..", "tools",
-                        "perf_gate.py")
-    spec = importlib.util.spec_from_file_location("perf_gate", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_perf_gate_verdicts(tmp_path, capsys):
-    gate = _load_perf_gate()
-
-    def write(round_n, value, metric="resnet50_train"):
-        p = tmp_path / f"BENCH_r{round_n:02d}.json"
-        p.write_text(json.dumps({"metric": metric, "value": value}))
-        return str(p)
-
-    # r05 in the driver's wrapped shape ({rc, tail, parsed}) — the
-    # real BENCH_r*.json artifacts nest the bench line under "parsed"
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps({
-        "rc": 0, "tail": "...",
-        "parsed": {"metric": "resnet50_train", "value": 1000.0}}))
-    write(6, 980.0)    # -2% within default 5%
-    assert gate.main(["--dir", str(tmp_path)]) == 0
-    assert "PERF GATE PASS" in capsys.readouterr().out
-    write(7, 900.0)    # -8.2% vs r06 → fail
-    assert gate.main(["--dir", str(tmp_path)]) == 1
-    out = capsys.readouterr().out
-    assert "PERF GATE FAIL" in out and "r06" in out and "r07" in out
-    # widened tolerance passes the same pair
-    assert gate.main(["--dir", str(tmp_path),
-                      "--tolerance", "0.10"]) == 0
-    capsys.readouterr()
-    # explicit pair + metric mismatch = not comparable
-    other = tmp_path / "other.json"
-    other.write_text(json.dumps({"metric": "lenet", "value": 5.0}))
-    assert gate.main([str(tmp_path / "BENCH_r06.json"),
-                      str(other)]) == 2
-    assert "PERF GATE ERROR" in capsys.readouterr().out
-    # fewer than two rounds = skip
-    solo = tmp_path / "solo"
-    solo.mkdir()
-    write_path = solo / "BENCH_r01.json"
-    write_path.write_text(json.dumps({"metric": "m", "value": 1.0}))
-    assert gate.main(["--dir", str(solo)]) == 2
-
-
-def test_perf_gate_skips_when_newer_record_lacks_keys(tmp_path,
-                                                      capsys):
-    """A newer BENCH record missing a metric key the older one has is
-    a comparability gap (the bench grew/renamed a field), not a
-    regression: SKIP (exit 2), never FAIL (exit 1)."""
-    gate = _load_perf_gate()
-    old = tmp_path / "BENCH_r01.json"
-    old.write_text(json.dumps({"metric": "m", "value": 100.0}))
-    # newer record emits a renamed field set: no "value" yet
-    new = tmp_path / "BENCH_r02.json"
-    new.write_text(json.dumps({"metric": "m",
-                               "examples_per_sec": 97.0}))
-    assert gate.main(["--dir", str(tmp_path)]) == 2
-    out = capsys.readouterr().out
-    assert "PERF GATE SKIP" in out and "value" in out
-    # missing "metric" in the newer record skips the same way
-    new.write_text(json.dumps({"value": 97.0}))
-    assert gate.main([str(old), str(new)]) == 2
-    assert "PERF GATE SKIP" in capsys.readouterr().out
-    # and an OLDER record that is short a key still ERRORs (the gap is
-    # only forgiven in the newer direction)
-    old2 = tmp_path / "old2.json"
-    old2.write_text(json.dumps({"metric": "m"}))
-    new2 = tmp_path / "new2.json"
-    new2.write_text(json.dumps({"metric": "m", "value": 5.0}))
-    assert gate.main([str(old2), str(new2)]) == 2
-    assert "PERF GATE ERROR" in capsys.readouterr().out
 
 
 # ============================================== concurrency sanity

@@ -204,11 +204,14 @@ def test_hot_swap_mid_soak_zero_failed_zero_mixed(tmp_path):
         client = _no_retry_client(server.port)
         while not stop.is_set():
             try:
+                t_sent = time.perf_counter()
                 r = client.predict(x, model="m")
+                t_back = time.perf_counter()
                 with lock:
                     responses.append(
                         (r["version"],
-                         np.asarray(r["outputs"], np.float32)))
+                         np.asarray(r["outputs"], np.float32),
+                         t_sent, t_back))
             except Exception as e:   # noqa: BLE001 - recorded, asserted 0
                 with lock:
                     failures.append(repr(e))
@@ -235,16 +238,20 @@ def test_hot_swap_mid_soak_zero_failed_zero_mixed(tmp_path):
 
     assert failures == [], f"requests failed during swap: {failures[:5]}"
     assert len(responses) > 50
-    seen = {v for v, _ in responses}
+    seen = {r[0] for r in responses}
     assert seen == {"v1", "v2"}, f"swap never took traffic: {seen}"
-    for version, out in responses:
+    for version, out, _, _ in responses:
         # a mixed-version response would match NEITHER reference
         np.testing.assert_allclose(out, refs[version],
                                    rtol=1e-4, atol=1e-5)
-    # order sanity: once v2 appears, v1 never comes back (no flapping)
-    versions = [v for v, _ in responses]
-    first_v2 = versions.index("v2")
-    assert all(v == "v2" for v in versions[first_v2 + 1:])
+    # order sanity: once v2 has answered, v1 never comes back (no
+    # flapping). By each request's own clock, not by the order the
+    # four threads reached the list: a thread that holds a v1 answer
+    # can lose the CPU before it appends it.
+    first_v2_back = min(t_back for v, _, _, t_back in responses
+                        if v == "v2")
+    assert not [t_sent - first_v2_back for v, _, t_sent, _ in responses
+                if v == "v1" and t_sent > first_v2_back]
 
 
 # ==================================================== tenant admission

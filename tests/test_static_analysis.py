@@ -546,14 +546,18 @@ EXPECTED_BAD_PROGRAMS = {
 }
 
 
-def _program_fixture_records(name):
+def _load_by_path(name, path):
     import importlib.util
 
-    spec = importlib.util.spec_from_file_location(
-        f"analysis_programs_{name}", PROGRAMS_FIX / f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.build_records()
+    return mod
+
+
+def _program_fixture_records(name):
+    return _load_by_path(f"analysis_programs_{name}",
+                         PROGRAMS_FIX / f"{name}.py").build_records()
 
 
 def _program_findings(name):
@@ -599,21 +603,47 @@ def test_program_findings_ride_the_baseline_machinery():
     assert not new2 and len(stale2) == 1
 
 
-def test_flagship_program_clean_pin():
-    """THE acceptance pin: the flagship bench program (and the
-    published graft entry) carry no prog-unhonored-donation and no
-    prog-fp32-matmul-under-policy finding under the declared bf16
-    policy."""
-    from deeplearning4j_tpu.analysis import program_lint, programs
+PIN_RULES = ("prog-unhonored-donation", "prog-fp32-matmul-under-policy")
 
-    records = programs._flagship_records()
-    names = {r.name for r in records}
-    assert {"bench_flagship_k_steps", "graft_entry_forward"} <= names
-    assert all(r.precision_policy == "bf16" for r in records)
-    finds = program_lint.run(records)
-    bad = [f for f in finds
-           if f.rule in ("prog-unhonored-donation",
-                         "prog-fp32-matmul-under-policy")]
+
+@pytest.fixture(scope="module")
+def resnet50_records():
+    from deeplearning4j_tpu.analysis import programs
+
+    return {r.name: r for r in programs._resnet50_records()}
+
+
+@pytest.mark.parametrize("name", ["engine_resnet50",
+                                  "engine_resnet50_group_k2"])
+def test_flagship_program_clean_pin(resnet50_records, name):
+    """THE acceptance pin: the program the training cell times (zoo
+    ResNet50 as the benchmark's configuration builds it, through
+    StepProgram: the single step and the k-step group) carries no
+    prog-unhonored-donation and no prog-fp32-matmul-under-policy
+    finding under the declared bf16 policy."""
+    from deeplearning4j_tpu.analysis import program_lint
+
+    record = resnet50_records[name]
+    assert record.precision_policy == "bf16"
+    assert record.source.startswith("deeplearning4j_tpu/")
+    bad = [f for f in program_lint.run([record]) if f.rule in PIN_RULES]
+    assert bad == [], [f.render() for f in bad]
+
+
+def test_graft_entry_forward_clean_pin():
+    """The published `__graft_entry__` forward, pinned to the bf16
+    policy it declares. The record is built here: a test may load a
+    script from the root, the package may not."""
+    from deeplearning4j_tpu.analysis import program_lint
+    from deeplearning4j_tpu.analysis.program_lint import ProgramRecord
+
+    fwd, args = _load_by_path(
+        "graft_entry", ROOT / "__graft_entry__.py").entry(
+            hw=32, n_classes=8)
+    record = ProgramRecord(
+        name="graft_entry_forward", fn=fwd, example_args=args,
+        precision_policy="bf16", source="__graft_entry__.py")
+    bad = [f for f in program_lint.run([record]) if f.rule in PIN_RULES]
     assert bad == [], [f.render() for f in bad]
 
 

@@ -145,18 +145,21 @@ def test_the_ring_stays_bounded():
 
 def test_a_record_costs_microseconds():
     """10,000 stub steps with the engine's ten phases: under 5 us a
-    record (the best of five rounds: the machine is shared)."""
+    record, the best of five rounds. Read on this thread's CPU clock:
+    a thread another test left running, or five other workers, make
+    this loop wait (3.3 us became 9.6 beside two spinning threads) and
+    cannot make a record cost more."""
     names = sorted(ENGINE_PHASES - {"between_steps"})
     pp = StepPhaseProfiler(owner="decode/stub", emit_metrics=False)
     best = float("inf")
     for _ in range(5):
-        t0 = time.perf_counter()
+        t0 = time.thread_time()
         for i in range(10_000):
             pp.begin_step(since_last="between_steps")
             for n in names:
                 pp.mark(n)
             pp.end_step(step=i)
-        best = min(best, (time.perf_counter() - t0) / 10_000)
+        best = min(best, (time.thread_time() - t0) / 10_000)
     assert best < 5e-6, f"{best * 1e6:.2f} us a record"
 
 
