@@ -6,35 +6,50 @@ programs, compiled ONCE per shape and never again (the static-shape
 constraint that makes one-program XLA serving work at all, per
 "Automatic Full Compilation ... to Cloud TPUs", arXiv 1810.09868):
 
-  decode step   ONE program over the engine's fixed [max_slots] batch:
-                consume each slot's current token at its current
-                LOGICAL position, scatter that position's K/V into a
-                host-chosen (page, offset) write cell, gather each
-                slot's attention window a whole page at a time
-                through its [pages_per_slot] page ids in ring order,
+  decode step   ONE program per window width over the engine's fixed
+                [max_slots] batch: consume each slot's current token
+                at its current LOGICAL position, scatter that
+                position's K/V into a host-chosen (page, offset) write
+                cell, gather each slot's attention window a whole page
+                at a time through its [width] page ids in ring order,
                 attend under per-slot live masks, emit each slot's
                 greedy next token. Requests joining/leaving, prefix
                 pages being shared, copy-on-write forks, and ring wrap
                 past max_ctx are all pure DATA (the host page table) —
-                the compiled shape never changes, so arbitrary traffic
-                runs on one compile (pinned by trace counters).
-  chunk prefill ONE program per page_size chunk: process one
-                page-aligned slice of a prompt in parallel — causal
-                within the chunk, attending to the prior context
-                through the same gathered-page indirection — and park
-                its K/V into one physical page. A prompt is a sequence
-                of chunk dispatches interleaved between decode steps,
-                so a long prompt never stalls resident generations,
-                and a prompt whose prefix pages already live in the
-                prefix trie skips its shared chunks entirely.
+                one compiled shape per ladder width, all compiled
+                before traffic, so arbitrary traffic runs on the
+                warm-up's compiles (pinned by trace counters).
+  chunk prefill ONE program per window width and page_size chunk:
+                process one page-aligned slice of a prompt in parallel
+                — causal within the chunk, attending to the prior
+                context through the same gathered-page indirection —
+                and park its K/V into one physical page. A prompt is a
+                sequence of chunk dispatches interleaved between
+                decode steps, so a long prompt never stalls resident
+                generations, and a prompt whose prefix pages already
+                live in the prefix trie skips its shared chunks
+                entirely.
   page copy     the copy-on-write primitive: duplicate one physical
                 page (all layers, K and V) inside the donated pool —
                 what a slot pays to diverge from a shared page.
 
+The window's WIDTH follows the live pages. A program's cost is linear
+in the page ids it is handed (the gather, the zeroing of dead cells
+and both cache contractions run over all of them), so a program
+compiled for `pages_per_slot` ids pays for the whole window whatever
+the slots hold. `widths` is a fixed ladder of widths in pages: powers
+of two from the one that holds `WINDOW_FLOOR` positions up to
+`pages_per_slot` (a model whose window is no longer than the floor has
+a ladder of one). The host hands a program the narrowest ladder width
+that holds the live pages — of the longest decoding slot for a step,
+of the prior context for a chunk — and `step` / `prefill_chunk` pick
+the compiled program by the width of the ids they are given. `warmup`
+compiles and runs every width of both.
+
 What is the same for every model lives here: the three programs and
 their compile-once keys, the page indirection (`window_pages`, scratch
-page 0), donation, trace counters, the greedy token and its finite
-verdict, and the named scopes of the work every model does (`embed`,
+page 0), the width ladder, donation, trace counters, the greedy token
+and its finite verdict, and the named scopes of the work every model does (`embed`,
 `kv_write`, `kv_read`, `head`, `kv_copy`). What a model is comes from
 the model, and nothing here asks which one it is:
 
@@ -83,7 +98,13 @@ under any page-table history (shared prefixes, CoW forks, ring wrap,
 eviction replay) presents the attention reduction with identical
 operand values in identical order to the sequential oracle's — the
 FP-associativity discipline that makes "bitwise equal to the oracle"
-achievable at all.
+achievable at all. It holds at every width: no operation mixes
+slots, a wider window only appends dead cells (zeroed, masked), and
+engine and oracle agree bitwise when run at the same width. A chunk's
+width is a function of its start alone, so the two always agree on
+it; a step's is that of the longest slot decoding in it, so
+`sequential_decode` takes a `width` to run a request's steps at the
+one the engine ran them at.
 
 Forensics and policy ride the exact StepProgram rails: programs live
 in the model's JitCache (record_trace inside traced bodies,
@@ -104,6 +125,14 @@ def next_pow2(n: int) -> int:
         p <<= 1
     return p
 
+
+# The narrowest window a program is compiled for, in positions: the
+# floor of the width ladder. Every width costs set-up time whether
+# traffic uses it or not (a program to trace, lower, load and run
+# once per layer), and a narrower window saves per position, so below
+# some width a further program costs more set-up than its steps save.
+# PERF.md (PR 33) has the measured cost of a program in `setup_s`.
+WINDOW_FLOOR = 512
 
 # physical page 0: scratch — write sink for inactive/suppressed rows,
 # gather target for dead cells (zeroed inside the attention kernels)
@@ -132,6 +161,14 @@ class DecodeProgram:
         # max_ctx logical positions (sliding once positions wrap)
         self.window = int(model.max_ctx)
         self.pages_per_slot = self.window // self.page_size
+        # the ladder of window widths a program is compiled for, in
+        # pages, ascending; the last is the whole window
+        w = max(1, WINDOW_FLOOR // self.page_size)
+        ladder = []
+        while w < self.pages_per_slot:
+            ladder.append(w)
+            w *= 2
+        self.widths: Tuple[int, ...] = (*ladder, self.pages_per_slot)
         if n_pages is None:
             # equal HBM to a contiguous per-slot layout, + scratch
             n_pages = self.max_slots * self.pages_per_slot + 1
@@ -144,11 +181,14 @@ class DecodeProgram:
 
         self.precision_policy = policy_name(
             getattr(model, "compute_dtype", None))
-        # host-side dispatch tally per program kind — trace-counter
-        # siblings that count EXECUTIONS rather than retraces, so the
-        # engine's stats (and the tracing story) can report how many
-        # device dispatches a generation actually cost
-        self._dispatches = {"step": 0, "chunk": 0, "copy": 0}
+        # host-side dispatch tally per program kind and window width —
+        # trace-counter siblings that count EXECUTIONS rather than
+        # retraces, so the engine's stats (and the tracing story) can
+        # report how many device dispatches a generation actually
+        # cost, and how often each width was the one chosen
+        self._dispatches = {"step": dict.fromkeys(self.widths, 0),
+                            "chunk": dict.fromkeys(self.widths, 0),
+                            "copy": 0}
         # the model's per-step counts (`step_counters`): totals on the
         # host, and the device vectors of steps not yet added to them
         self._counter_lock = threading.Lock()
@@ -184,11 +224,26 @@ class DecodeProgram:
                 f"window {self.window}")
         return list(range(int(from_token), prompt_len, self.page_size))
 
-    def window_pages(self, table: Sequence[Optional[int]],
-                     pos: int) -> np.ndarray:
-        """Host-side virtual→physical translation: the
-        [pages_per_slot] page ids of one slot's attention window at
-        logical position `pos`, in RING order — cell
+    def live_pages(self, pos: int) -> int:
+        """Pages of a slot's window that hold a live cell at logical
+        position `pos` (every one after a wrap)."""
+        return -(-min(pos + 1, self.window) // self.page_size)
+
+    def width_for(self, n_pages: int) -> int:
+        """The narrowest ladder width that holds `n_pages` pages."""
+        for w in self.widths:
+            if w >= n_pages:
+                return w
+        raise ValueError(f"{n_pages} pages exceed the window's "
+                         f"{self.pages_per_slot}")
+
+    def window_pages(self, table: Sequence[Optional[int]], pos: int,
+                     width: Optional[int] = None) -> np.ndarray:
+        """Host-side virtual→physical translation: the [width] page
+        ids of one slot's attention window at logical position `pos`
+        (`width` defaults to the narrowest ladder width that holds the
+        live pages; after a wrap every page is live and that is
+        `pages_per_slot`), in RING order — cell
         c = ring * page_size + offset of the gathered window holds the
         position q with q % window == c, so the live cells are
         c < live = min(pos + 1, window): logical token order until the
@@ -198,20 +253,34 @@ class DecodeProgram:
         entries for live positions must be mapped. Shared by the
         engine and the sequential oracle — the single definition of
         reduction order the bitwise contract rests on."""
-        live = min(pos + 1, self.window)
-        n = -(-live // self.page_size)     # pages holding a live cell
-        page_ids = np.full(self.pages_per_slot, SCRATCH_PAGE, np.int32)
+        n = self.live_pages(pos)
+        if width is None:
+            width = self.width_for(n)
+        elif width < n:
+            raise ValueError(f"a window of {width} pages cannot hold "
+                             f"the {n} live at position {pos}")
+        page_ids = np.full(width, SCRATCH_PAGE, np.int32)
         page_ids[:n] = table[:n]
         return page_ids
 
     # ------------------------------------------------------- compile
-    def decode_key(self):
-        return ("decode_step", self.max_slots, self.window,
-                self.n_pages)
+    def _width(self, width: Optional[int]) -> int:
+        """`width` as a ladder width (None: the whole window). A
+        program of any other width would compile under traffic."""
+        if width is None:
+            return self.pages_per_slot
+        if width not in self.widths:
+            raise ValueError(f"window width {width} is not one of the "
+                             f"ladder's {self.widths}")
+        return int(width)
 
-    def chunk_key(self):
+    def decode_key(self, width: Optional[int] = None):
+        return ("decode_step", self.max_slots, self.window,
+                self.n_pages, self._width(width))
+
+    def chunk_key(self, width: Optional[int] = None):
         return ("decode_chunk_prefill", self.page_size, self.window,
-                self.n_pages)
+                self.n_pages, self._width(width))
 
     def copy_key(self):
         return ("decode_page_copy", self.n_pages)
@@ -223,18 +292,20 @@ class DecodeProgram:
             cache.register_policy(key, self.precision_policy)
         return cache[key]
 
-    def _decode_program(self):
-        return self._program(self.decode_key(), self._build_decode)
+    def _decode_program(self, width: Optional[int] = None):
+        return self._program(self.decode_key(width), self._build_decode)
 
-    def _chunk_program(self):
-        return self._program(self.chunk_key(), self._build_chunk)
+    def _chunk_program(self, width: Optional[int] = None):
+        return self._program(self.chunk_key(width), self._build_chunk)
 
     def _copy_program(self):
         return self._program(self.copy_key(), self._build_copy)
 
     def _build_decode(self, trace_key: str):
-        """Compile the shared decode step. Per-slot independence is
-        the load-bearing property: no op mixes slots (batched einsums,
+        """Compile the shared decode step (the window's width is the
+        page ids': one jitted function a ladder width, each traced for
+        one shape). Per-slot independence is the load-bearing
+        property, at every width: no op mixes slots (batched einsums,
         per-row norms/softmax, per-row gathers), so an active slot's
         emitted token is a function of ITS cells alone — the
         byte-identity-under-churn contract tests/test_decode.py pins
@@ -362,9 +433,10 @@ class DecodeProgram:
              write_off):
         """One decode step over all slots. `tokens`/`positions`/
         `write_page`/`write_off` are host [max_slots] int arrays and
-        `page_ids` a host [max_slots, pages_per_slot] int array (one
-        `window_pages` row per slot: the engine's translated page
-        table); returns
+        `page_ids` a host [max_slots, width] int array (one
+        `window_pages` row per slot, all of one ladder width that
+        holds the longest slot's live pages: the engine's translated
+        page table), which picks the program; returns
         (new_kv, next_tokens, finite_ok) with `kv` donated — the
         caller MUST rebind. `finite_ok` is the per-slot finite-logits
         verdict ([max_slots] bool): a False row's token is numeric
@@ -373,8 +445,9 @@ class DecodeProgram:
         outputs are real."""
         import jax.numpy as jnp
 
-        fn = self._decode_program()
-        self._dispatches["step"] += 1
+        width = np.shape(page_ids)[1]
+        fn = self._decode_program(width)
+        self._dispatches["step"][width] += 1
         out = fn(self.model.params, kv,
                  jnp.asarray(tokens, jnp.int32),
                  jnp.asarray(positions, jnp.int32),
@@ -412,16 +485,18 @@ class DecodeProgram:
         """Prefill one page-aligned prompt chunk (positions
         start..start+len(chunk)-1, padded to page_size) into physical
         page `write_page`, attending to the prior context through
-        `page_ids` (`window_pages(table, start - 1)`: the
-        [pages_per_slot] ids, cells >= start dead). `kv` is donated —
-        rebind."""
+        `page_ids` (`window_pages(table, start - 1)`: ids of a ladder
+        width that holds the `start / page_size` prior pages, cells
+        >= start dead), whose width picks the program. `kv` is
+        donated — rebind."""
         import jax.numpy as jnp
 
         chunk = np.asarray(chunk, np.int32).ravel()
         padded = np.zeros(self.page_size, np.int32)
         padded[:len(chunk)] = chunk
-        fn = self._chunk_program()
-        self._dispatches["chunk"] += 1
+        width = np.shape(page_ids)[0]
+        fn = self._chunk_program(width)
+        self._dispatches["chunk"][width] += 1
         return fn(self.model.params, kv, jnp.asarray(padded),
                   jnp.int32(start),
                   jnp.asarray(page_ids, jnp.int32),
@@ -437,38 +512,50 @@ class DecodeProgram:
         return fn(kv, jnp.int32(src), jnp.int32(dst))
 
     def warmup(self, kv, buckets: Sequence[int] = ()):
-        """Compile all three programs up front (serving warmup
-        discipline: compiles happen before traffic, the trace counters
-        pin that none happen after). `buckets` is accepted for
-        call-site compatibility and ignored — chunked prefill replaced
-        the per-bucket prefill family with ONE chunk shape. Returns
-        the (donated-through) pool buffer."""
+        """Compile every program up front, the chunk and the step at
+        every ladder width (serving warmup discipline: compiles happen
+        before traffic, the trace counters pin that none happen
+        after). `buckets` is accepted for call-site compatibility and
+        ignored — chunked prefill replaced the per-bucket prefill
+        family with ONE chunk shape a width. Returns the
+        (donated-through) pool buffer."""
         del buckets
         kv = self.copy_page(kv, SCRATCH_PAGE, SCRATCH_PAGE)
-        s, p = self.max_slots, self.pages_per_slot
+        s = self.max_slots
         zs = np.zeros(s, np.int32)
-        kv = self.prefill_chunk(kv, [0] * self.page_size, 0,
-                                np.full(p, SCRATCH_PAGE, np.int32),
-                                SCRATCH_PAGE)
-        kv, _, _ = self.step(kv, zs, zs,
-                             np.full((s, p), SCRATCH_PAGE, np.int32),
-                             zs, zs)
+        for w in self.widths:
+            kv = self.prefill_chunk(kv, [0] * self.page_size, 0,
+                                    np.full(w, SCRATCH_PAGE, np.int32),
+                                    SCRATCH_PAGE)
+            kv, _, _ = self.step(kv, zs, zs,
+                                 np.full((s, w), SCRATCH_PAGE, np.int32),
+                                 zs, zs)
         return kv
 
     def trace_stats(self) -> dict:
+        """`dispatches` counts executions by program, and the chunk's
+        and the step's by window width in pages beside their sums."""
         cache = self.model._jit_cache
+        d = self._dispatches
         return {"trace_counts": cache.trace_counts(),
                 "total_traces": cache.total_traces(),
                 "compiles_total": cache.compiles_total(),
                 "compile_events": cache.compile_events(),
-                "dispatches": dict(self._dispatches)}
+                "dispatches": {"step": sum(d["step"].values()),
+                               "chunk": sum(d["chunk"].values()),
+                               "copy": d["copy"],
+                               "step_by_width": dict(d["step"]),
+                               "chunk_by_width": dict(d["chunk"])}}
 
     # ------------------------------------------------------------ lint
     def lint_records(self, buckets: Sequence[int] = ()) -> List:
-        """ProgramRecords for the decode step, the chunk prefill, and
-        the page copy — built through the same cache paths the engine
-        uses (policy registered), traced/lowered by the lint but never
-        executed. Donation on the page pool is DECLARED on every record
+        """ProgramRecords for the decode step and the chunk prefill at
+        the narrowest and the widest ladder width (one record each
+        where the ladder is one width; the widest keeps the bare name,
+        a narrower one adds `_w<pages>`), and the page copy — built
+        through the same cache paths the engine uses (policy
+        registered), traced/lowered by the lint but never executed.
+        Donation on the page pool is DECLARED on every record
         (donate_argnums) so prog-unhonored-donation verifies the
         executable alias map genuinely aliases the pool in place — a
         silently-copied pool would double decode memory AND pay a
@@ -482,32 +569,37 @@ class DecodeProgram:
 
         model = self.model
         kv = self.init_kv()
-        s, p = self.max_slots, self.pages_per_slot
+        s = self.max_slots
         source = "deeplearning4j_tpu/engine/decode_program.py"
         zs = jnp.zeros(s, jnp.int32)
-        zp = jnp.zeros(p, jnp.int32)
-        step_fn = self._decode_program()
-        chunk_fn = self._chunk_program()
         copy_fn = self._copy_program()
-        return [
-            ProgramRecord(
-                name=f"decode_step_s{s}",
-                fn=getattr(step_fn, "__wrapped__", step_fn),
-                example_args=(model.params, kv, zs, zs,
-                              jnp.zeros((s, p), jnp.int32), zs, zs),
-                donate_argnums=(1,),
-                precision_policy=self.precision_policy, source=source,
-                consumed_outputs=tuple(range(
-                    3 + bool(model.step_counters)))),
-            ProgramRecord(
-                name=f"decode_prefill_c{self.page_size}",
-                fn=getattr(chunk_fn, "__wrapped__", chunk_fn),
-                example_args=(model.params, kv,
-                              jnp.zeros(self.page_size, jnp.int32),
-                              jnp.int32(0), zp, jnp.int32(1)),
-                donate_argnums=(1,),
-                precision_policy=self.precision_policy, source=source,
-                consumed_outputs=(0,)),
+        records = []
+        for w in sorted({self.widths[0], self.widths[-1]}):
+            tag = "" if w == self.pages_per_slot else f"_w{w}"
+            step_fn = self._decode_program(w)
+            chunk_fn = self._chunk_program(w)
+            records += [
+                ProgramRecord(
+                    name=f"decode_step_s{s}{tag}",
+                    fn=getattr(step_fn, "__wrapped__", step_fn),
+                    example_args=(model.params, kv, zs, zs,
+                                  jnp.zeros((s, w), jnp.int32), zs, zs),
+                    donate_argnums=(1,),
+                    precision_policy=self.precision_policy,
+                    source=source,
+                    consumed_outputs=tuple(range(
+                        3 + bool(model.step_counters)))),
+                ProgramRecord(
+                    name=f"decode_prefill_c{self.page_size}{tag}",
+                    fn=getattr(chunk_fn, "__wrapped__", chunk_fn),
+                    example_args=(model.params, kv,
+                                  jnp.zeros(self.page_size, jnp.int32),
+                                  jnp.int32(0), jnp.zeros(w, jnp.int32),
+                                  jnp.int32(1)),
+                    donate_argnums=(1,),
+                    precision_policy=self.precision_policy,
+                    source=source, consumed_outputs=(0,))]
+        return records + [
             ProgramRecord(
                 name="decode_page_copy",
                 fn=getattr(copy_fn, "__wrapped__", copy_fn),

@@ -18,7 +18,9 @@ treats request lifecycle as pure data:
           the shared decode loop. Nothing recompiles;
   leave   EOS or max-tokens frees the slot between two steps; the
           program never learns a request ended (per-slot active masks
-          are host state — the compiled shape is invariant);
+          are host state — the compiled shapes, one per window
+          width of the program's ladder, are all compiled before
+          traffic);
   evict   the `serving.slot_evict` fault point (chaos drills) can rip
           an active request out mid-generation: its recovery is
           re-prefill of the ORIGINAL prompt on a free slot + forced
@@ -57,7 +59,11 @@ over a shared refcounted physical pool (PagePool) —
 Byte-identity contract: greedy decoding + per-slot independence of the
 compiled step mean every emitted token is a deterministic function of
 the request's own tokens — independent of which slot it lands in, who
-its neighbors are, and when it joins. tests/test_decode.py pins
+its neighbors are, and when it joins. A step gathers a window as wide
+as its longest decoding slot needs (the program's ladder of widths):
+a wider window appends dead cells to every slot's reduction, zeroed
+and masked, and engine and oracle agree bitwise when run at the same
+width (engine/decode_program.py). tests/test_decode.py pins
 engine output == sequential per-request oracle under staggered churn
 AND mid-soak eviction chaos.
 
@@ -620,6 +626,8 @@ class DecodeEngine:
         self._cow_copies = 0
         self._kv_pages_gathered = 0    # pages the decode steps read,
         self._kv_pages_live = 0        # and those with a live cell
+        self._chunk_pages_gathered = 0  # the same of the chunks'
+        self._chunk_pages_live = 0      # prior context
         self._evictions = 0
         self._completed = 0
         self._quarantines = 0
@@ -1422,11 +1430,14 @@ class DecodeEngine:
         t0 = time.perf_counter()
         ring = (start // ps) % self.program.pages_per_slot
         self._table[slot][ring] = page
+        # as wide as the prior pages need: the narrowest ladder width
         page_ids = self.program.window_pages(self._table[slot],
                                              start - 1)
         self.kv = self.program.prefill_chunk(
             self.kv, prompt[start:start + ps], start, page_ids, page)
         self._prefill_chunks += 1
+        self._chunk_pages_gathered += page_ids.size
+        self._chunk_pages_live += start // ps
         if self.tracer is not None:
             self._lat.append(("chunk", handle, t0,
                               time.perf_counter()))
@@ -1529,12 +1540,13 @@ class DecodeEngine:
 
     def _step_tables(self, decoding: np.ndarray):
         """Translate the page table into the decode dispatch's index
-        arrays: [S, pages_per_slot] page ids in ring order per slot
-        (`window_pages`; non-decoding rows gather scratch), plus each
-        slot's write cell (first-token steps and non-decoding rows
-        write scratch). Counts what the step will read: every entry is
-        a page gathered, an entry off scratch a page with a live
-        cell."""
+        arrays: [S, width] page ids in ring order per slot
+        (`window_pages`; non-decoding rows gather scratch), `width`
+        the narrowest of the program's ladder that holds the live
+        pages of the longest decoding slot, plus each slot's write
+        cell (first-token steps and non-decoding rows write scratch).
+        Counts what the step will read: every entry is a page
+        gathered, an entry off scratch a page with a live cell."""
         from deeplearning4j_tpu.engine.decode_program import (
             SCRATCH_PAGE,
         )
@@ -1542,13 +1554,16 @@ class DecodeEngine:
         s_n = self.max_slots
         ps = self.program.page_size
         p = self.program.pages_per_slot
-        page_ids = np.full((s_n, p), SCRATCH_PAGE, np.int32)
+        rows = np.flatnonzero(decoding)
+        width = self.program.width_for(self.program.live_pages(
+            int(self._positions[rows].max())))
+        page_ids = np.full((s_n, width), SCRATCH_PAGE, np.int32)
         wp = np.full(s_n, SCRATCH_PAGE, np.int32)
         wo = np.zeros(s_n, np.int32)
-        for s in np.flatnonzero(decoding):
+        for s in rows:
             pos = int(self._positions[s])
             page_ids[s] = self.program.window_pages(self._table[s],
-                                                    pos)
+                                                    pos, width)
             if not self._first_step[s]:
                 wp[s] = self._table[s][(pos // ps) % p]
                 wo[s] = pos % ps
@@ -1769,6 +1784,9 @@ class DecodeEngine:
             # they gathered, and those that held a live cell
             "kv_pages_gathered": self._kv_pages_gathered,
             "kv_pages_live": self._kv_pages_live,
+            # and the prefill chunks of their prior context
+            "chunk_pages_gathered": self._chunk_pages_gathered,
+            "chunk_pages_live": self._chunk_pages_live,
             # what the model counts in its own decode steps (an expert
             # layer's routed pairs); no key where it counts nothing
             **self.program.counters(),
@@ -1802,7 +1820,7 @@ class DecodeEngine:
 def sequential_decode(program, prompt: Sequence[int],
                       max_new_tokens: int,
                       eos_id: Optional[int] = None, kv=None,
-                      slot: int = 0):
+                      slot: int = 0, width: Optional[int] = None):
     """The per-request ORACLE: chunked prefill + one-stream decode on
     the same compiled programs the engine runs, one request at a time,
     through a trivially deterministic page allocator (pages handed out
@@ -1810,7 +1828,13 @@ def sequential_decode(program, prompt: Sequence[int],
     sharing, no CoW). Returns (kv, tokens). Continuous-batched output
     must equal this bitwise for every request regardless of slot
     churn, prefix sharing, or context wrap — the correctness bar that
-    makes the paged virtual address space trustworthy."""
+    makes the paged virtual address space trustworthy. Each program
+    runs at the window width the engine gives one slot: a chunk's
+    holds its prior pages, a step's the live pages (`window_pages`).
+    The engine's step is as wide as its longest decoding slot needs;
+    `width` (in pages, one of `program.widths`) runs every step here
+    at that width instead, for a request that shared its steps with a
+    longer one."""
     from deeplearning4j_tpu.engine.decode_program import SCRATCH_PAGE
 
     if kv is None:
@@ -1845,7 +1869,8 @@ def sequential_decode(program, prompt: Sequence[int],
     positions = np.zeros(s_n, np.int32)
     while len(out) < max_new_tokens and (eos_id is None or not out
                                          or out[-1] != eos_id):
-        page_ids = np.full((s_n, pps), SCRATCH_PAGE, np.int32)
+        w = width or program.width_for(program.live_pages(pos))
+        page_ids = np.full((s_n, w), SCRATCH_PAGE, np.int32)
         wp = np.full(s_n, SCRATCH_PAGE, np.int32)
         wo = np.zeros(s_n, np.int32)
         ring = (pos // ps) % pps
@@ -1856,7 +1881,7 @@ def sequential_decode(program, prompt: Sequence[int],
             wo[slot] = pos % ps
         tokens[slot] = tok
         positions[slot] = pos
-        page_ids[slot] = program.window_pages(table, pos)
+        page_ids[slot] = program.window_pages(table, pos, w)
         kv, nxt, _ = program.step(kv, tokens, positions, page_ids,
                                   wp, wo)
         tok = int(np.asarray(nxt)[slot])

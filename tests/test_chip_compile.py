@@ -150,7 +150,9 @@ def test_decode_program_compiles_for_v5e(compute_dtype, program,
 def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
     """(program, {kind: (jitted function, argument shapes)}) of a
     serving cell as its driver builds it, with shapes on the described
-    chip in the place of the weights and the pool."""
+    chip in the place of the weights and the pool. The decode step and
+    the chunk at the widest window of the program's ladder (the whole
+    window) and, as `*_narrow`, at the narrowest."""
     import json
     import os
     from types import SimpleNamespace
@@ -178,15 +180,19 @@ def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
     params["layers"] = tuple({k: leaf(v) for k, v in layer.items()}
                              for layer in shapes["layers"])
     pool = sds(prog.kv_shape, prog.model.kv_dtype)
-    s, p, t = prog.max_slots, prog.pages_per_slot, prog.page_size
+    s, t = prog.max_slots, prog.page_size
     i32 = jnp.int32
     zs, one = sds((s,), i32), sds((), i32)
-    return prog, {
-        "decode": (prog._decode_program(),
-                   (params, pool, zs, zs, sds((s, p), i32), zs, zs)),
-        "chunk": (prog._chunk_program(),
-                  (params, pool, sds((t,), i32), one, sds((p,), i32), one)),
-        "copy": (prog._copy_program(), (pool, one, one))}
+    cases = {"copy": (prog._copy_program(), (pool, one, one))}
+    assert prog.widths[0] < prog.widths[-1] == prog.pages_per_slot
+    for tag, p in (("", prog.widths[-1]), ("_narrow", prog.widths[0])):
+        cases["decode" + tag] = (
+            prog._decode_program(p),
+            (params, pool, zs, zs, sds((s, p), i32), zs, zs))
+        cases["chunk" + tag] = (
+            prog._chunk_program(p),
+            (params, pool, sds((t,), i32), one, sds((p,), i32), one))
+    return prog, cases
 
 
 @pytest.fixture(scope="module")
@@ -202,11 +208,17 @@ def latent_cell(one_chip):
                           serve_latent, ref, jnp.bfloat16)
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk", "copy"])
+CELL_PROGRAMS = ["decode", "chunk", "copy", "decode_narrow",
+                 "chunk_narrow"]
+
+
+@pytest.mark.parametrize("program", CELL_PROGRAMS)
 def test_latent_cell_compiles_for_v5e_with_no_copy_of_the_pool(
         latent_cell, program):
     """The cell's three programs at the published widths (4.92B
-    parameters, 32 slots of 4,096 positions). The pool is updated in
+    parameters, 32 slots of 4,096 positions), the step and the chunk
+    at the widest and at the narrowest window of the ladder (32 pages
+    and 4). The pool is updated in
     place and in ONE layout: a row of 576 in place of 640 lanes makes
     the compiler convert the whole pool in and out of every program
     (0.76 GB of temporaries and two copies a step; PERF.md, PR 28), and
@@ -219,6 +231,7 @@ def test_latent_cell_compiles_for_v5e_with_no_copy_of_the_pool(
     mem = compiled.memory_analysis()
     pool_bytes = int(np.prod(prog.kv_shape)) * 2
     assert prog.kv_shape == (5, 1025, 128, 640)
+    assert prog.widths == (4, 8, 16, 32)
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 1.0e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
@@ -263,10 +276,12 @@ def _materialized(text):
     return out
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk", "copy"])
+@pytest.mark.parametrize("program", CELL_PROGRAMS)
 def test_gpt2_cell_compiles_for_v5e_with_the_pool_as_stored(gpt2_cell,
                                                             program):
-    """The cell's three programs at the published widths. The pool is
+    """The cell's three programs at the published widths, the step and
+    the chunk at the widest and at the narrowest window of the ladder
+    (64 pages and 32). The pool is
     token rows of 1,024 lanes, updated in place and in ONE layout, and
     no instruction of its shape is a `copy`: with head_dim 64 minor
     (the head-major page, and the same padded to 128) the compiler
@@ -279,6 +294,7 @@ def test_gpt2_cell_compiles_for_v5e_with_the_pool_as_stored(gpt2_cell,
     compiled = getattr(fn, "__wrapped__", fn).lower(*args).compile()
     mem = compiled.memory_analysis()
     assert prog.kv_shape == (24, 2, 1025, 16, 1024)
+    assert prog.widths == (32, 64)
     assert mem.alias_size_in_bytes >= int(np.prod(prog.kv_shape)) * 4
     assert mem.temp_size_in_bytes < 1.0e9
     text = compiled.as_text()

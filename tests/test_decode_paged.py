@@ -513,3 +513,251 @@ def test_paged_metrics_registered_and_emitted(program):
         assert "dl4j_decode_prefix_pages_shared" in snap["gauges"]
     finally:
         reg.reset()
+
+
+# ============================================ the ladder of window widths
+# a window past WINDOW_FLOOR positions: pages of 64, 32 a slot, so the
+# programs are compiled at 8, 16 and 32 pages (512, 1,024 and 2,048
+# positions)
+W_CTX, W_PAGE = 2048, 64
+W_WIDTHS = (8, 16, 32)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    model = CausalTransformer(vocab_size=VOCAB, d_model=32, n_heads=4,
+                              n_layers=2, max_ctx=W_CTX, seed=13).init()
+    prog = DecodeProgram(model, max_slots=SLOTS, page_size=W_PAGE)
+    prog.warmup(prog.init_kv())
+    return prog
+
+
+def _prompt(n, seed):
+    return np.random.default_rng(seed).integers(1, VOCAB, n).tolist()
+
+
+@pytest.mark.parametrize("max_ctx,page_size,widths", [
+    (64, 8, (8,)),                  # every window of 512 positions or
+    (512, 16, (32,)),               # less: one width, as before
+    (256, 256, (1,)),
+    (1024, 16, (32, 64)),           # GPT-2's
+    (4096, 128, (4, 8, 16, 32)),    # the latent cell's
+    (W_CTX, W_PAGE, W_WIDTHS),
+    (2048, 1024, (1, 2)),           # a page longer than the floor
+])
+def test_ladder_is_powers_of_two_from_the_floor_to_the_window(
+        max_ctx, page_size, widths):
+    model = CausalTransformer(vocab_size=VOCAB, d_model=8, n_heads=2,
+                              n_layers=1, max_ctx=max_ctx)
+    model.params = {}               # shapes only: nothing compiles
+    prog = DecodeProgram(model, max_slots=2, page_size=page_size)
+    assert prog.widths == widths
+    assert prog.widths[-1] == prog.pages_per_slot
+    for n in range(prog.pages_per_slot + 1):
+        w = prog.width_for(n)
+        assert w in widths and w >= n
+        assert not [v for v in widths if n <= v < w]
+    with pytest.raises(ValueError):
+        prog.width_for(prog.pages_per_slot + 1)
+    # every width has a key of its own; no width means the whole window
+    keys = {prog.decode_key(w) for w in widths}
+    assert len(keys) == len(widths)
+    assert prog.decode_key() == prog.decode_key(widths[-1])
+    assert prog.chunk_key() == prog.chunk_key(widths[-1])
+    assert {prog.chunk_key(w) for w in widths}.isdisjoint(keys)
+
+
+@pytest.mark.parametrize("pos,live,width", [
+    (-1, 0, 8),             # a chunk at start 0: no prior page
+    (0, 1, 8),
+    (510, 8, 8), (511, 8, 8),       # the last cell of 8 pages
+    (512, 9, 16),                   # the first past them
+    (1023, 16, 16), (1024, 17, 32),
+    (W_CTX - 1, 32, 32),
+    (W_CTX, 32, 32),                # wrapped: every page live
+    (W_CTX + 70, 32, 32),
+])
+def test_window_pages_width_ring_order_and_scratch_padding(wide, pos,
+                                                           live, width):
+    table = list(range(101, 101 + wide.pages_per_slot))
+    assert wide.live_pages(pos) == live
+    ids = wide.window_pages(table, pos)
+    assert ids.dtype == np.int32 and ids.shape == (width,)
+    assert ids[:live].tolist() == table[:live]       # ring order
+    assert (ids[live:] == SCRATCH_PAGE).all()
+    # a width that is asked for: the same ids, padded further
+    for w in W_WIDTHS:
+        if w < live:
+            with pytest.raises(ValueError):
+                wide.window_pages(table, pos, w)
+            continue
+        wider = wide.window_pages(table, pos, w)
+        assert wider.shape == (w,)
+        assert wider[:live].tolist() == table[:live]
+        assert (wider[live:] == SCRATCH_PAGE).all()
+
+
+@pytest.mark.parametrize("call", ["step", "chunk", "oracle", "key"])
+def test_a_width_off_the_ladder_is_refused_not_compiled(wide, call):
+    """A program of another width would compile under traffic."""
+    before = dict(wide.trace_stats()["trace_counts"])
+    zs = np.zeros(SLOTS, np.int32)
+    with pytest.raises(ValueError):
+        if call == "step":
+            wide.step(None, zs, zs, np.zeros((SLOTS, 12), np.int32),
+                      zs, zs)
+        elif call == "chunk":
+            wide.prefill_chunk(None, [1, 2], 0, np.zeros(3, np.int32), 1)
+        elif call == "oracle":
+            sequential_decode(wide, [1, 2, 3], 2, width=12)
+        else:
+            wide.decode_key(12)
+    assert wide.trace_stats()["trace_counts"] == before
+
+
+# (prompt length, new tokens) of the long request, by what it passes
+GROWTH = {
+    "within-the-floor": (40, 12),
+    "across-512": (500, 30),        # 8 pages -> 9: width 8 -> 16
+    "across-1024": (1000, 40),      # width 16 -> 32
+    "wraps-past-2048": (2030, 50),  # every page live, the ring turns
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROWTH))
+def test_growth_across_a_width_and_past_the_window_is_the_oracles(
+        wide, case):
+    """A request that grows from one ladder width into the next (and
+    one that wraps past the window) emits the oracle's tokens through
+    the engine while short requests join and leave around it, and
+    nothing is traced after `warmup`: a width is data."""
+    n_prompt, n_new = GROWTH[case]
+    rng = random.Random(n_prompt)
+    long_prompt = _prompt(n_prompt, n_prompt)
+    shorts = [(_prompt(rng.randint(3, 90), 7 + i), rng.randint(2, 9))
+              for i in range(7)]
+    want_long = sequential_decode(wide, long_prompt, n_new)[1]
+    want_short = [sequential_decode(wide, p, n)[1] for p, n in shorts]
+    traces = dict(wide.trace_stats()["trace_counts"])
+    d0 = wide.trace_stats()["dispatches"]
+
+    eng = DecodeEngine(program=wide, prefix_cache=False)
+    handles = [eng.submit(long_prompt, n_new)]
+    todo = list(shorts)
+    steps = 0
+    while todo or any(not h.done for h in handles):
+        if todo and steps % 5 == 0:         # churn: one joins every 5
+            handles.append(eng.submit(*todo.pop(0)))
+        eng.step_once()
+        steps += 1
+        assert steps < 4000
+    assert handles[0].tokens_so_far() == want_long
+    for h, want in zip(handles[1:], want_short):
+        assert h.tokens_so_far() == want
+    st = eng.stats()
+    assert st["trace_counts"] == traces
+    assert set(traces.values()) == {1}
+    assert len(traces) == 2 * len(W_WIDTHS) + 1
+    if case == "wraps-past-2048":
+        assert st["ctx_wraps"] >= 1
+    # the widths the long request's steps need were the ones run
+    d1 = st["dispatches"]
+    ran = {w for w in W_WIDTHS
+           if d1["step_by_width"][w] > d0["step_by_width"][w]}
+    first = wide.width_for(wide.live_pages(n_prompt - 1))
+    last = wide.width_for(wide.live_pages(n_prompt + n_new - 2))
+    assert {first, last} <= ran
+    assert max(ran) == last
+
+
+@pytest.mark.parametrize("width", W_WIDTHS)
+def test_oracle_at_a_pinned_width_is_the_engines_beside_a_longer_slot(
+        wide, width):
+    """The engine's step is as wide as its longest decoding slot
+    needs, so a short request decoding beside a long one runs at the
+    long one's width: the oracle pinned to that width (`width=`) emits
+    the same tokens, and so does the oracle at the short one's own."""
+    n_long = {8: 30, 16: 600, 32: 1100}[width]
+    long_prompt, short = _prompt(n_long, 40 + width), _prompt(21, width)
+    eng = DecodeEngine(program=wide, prefix_cache=False,
+                       max_prefills_per_step=4)
+    h_long = eng.submit(long_prompt, 40)
+    while h_long.t_first_token is None:
+        eng.step_once()
+    d0 = eng.stats()["dispatches"]["step_by_width"]
+    h = eng.submit(short, 12)
+    while not h.done:
+        eng.step_once()
+    d1 = eng.stats()["dispatches"]["step_by_width"]
+    assert not h_long.done
+    # every step of the short request's life ran at the long one's width
+    assert {w for w in W_WIDTHS if d1[w] > d0[w]} == {width}
+    assert h.tokens_so_far() == sequential_decode(
+        wide, short, 12, width=width)[1]
+    assert h.tokens_so_far() == sequential_decode(wide, short, 12)[1]
+    _drain(eng, [h_long])
+    assert h_long.tokens_so_far() == sequential_decode(
+        wide, long_prompt, 40)[1]
+
+
+@pytest.mark.parametrize("n_prompt,n_new,chunks,steps", [
+    # chunks: {width: dispatches}; starts 0, 64, ...: a chunk with n
+    # prior pages gathers the narrowest width >= n
+    (100, 3, {8: 2}, {8: 3}),
+    # 10 chunks with 0..9 prior pages: 9 at width 8, one at 16; then
+    # positions 599..602 hold 10 live pages
+    (600, 4, {8: 9, 16: 1}, {16: 4}),
+    # 16 chunks, 0..15 prior: 9 at 8, 7 at 16; positions 1023 (16
+    # pages) then 1024, 1025 (17)
+    (1024, 3, {8: 9, 16: 7}, {16: 1, 32: 2}),
+])
+def test_chunk_page_counters_and_dispatches_by_width_add_up(
+        wide, n_prompt, n_new, chunks, steps):
+    """`chunk_pages_gathered` / `chunk_pages_live` count the prior
+    context the prefill chunks read as `kv_pages_*` count the decode
+    steps', and `trace_stats()["dispatches"]` says how often each
+    width was the one chosen. Worked by hand for one request."""
+    d0 = wide.trace_stats()["dispatches"]
+    eng = DecodeEngine(program=wide, prefix_cache=False)
+    st = eng.stats()
+    assert st["chunk_pages_gathered"] == st["chunk_pages_live"] == 0
+    _drain(eng, [eng.submit(_prompt(n_prompt, 3), n_new)])
+    st = eng.stats()
+    d1 = st["dispatches"]
+    n_chunks = -(-n_prompt // W_PAGE)
+    assert st["prefill_chunks"] == n_chunks == sum(chunks.values())
+    assert st["steps"] == n_new == sum(steps.values())
+    for kind, want in (("chunk", chunks), ("step", steps)):
+        by = {w: d1[f"{kind}_by_width"][w] - d0[f"{kind}_by_width"][w]
+              for w in W_WIDTHS}
+        assert by == {w: want.get(w, 0) for w in W_WIDTHS}
+        assert d1[kind] - d0[kind] == sum(want.values())
+        assert d1[kind] == sum(d1[f"{kind}_by_width"].values())
+    assert st["chunk_pages_gathered"] == sum(
+        w * n for w, n in chunks.items())
+    assert st["chunk_pages_live"] == sum(range(n_chunks))
+    assert st["kv_pages_gathered"] == SLOTS * sum(
+        w * n for w, n in steps.items())
+    assert st["kv_pages_live"] == sum(
+        wide.live_pages(p) for p in range(n_prompt - 1,
+                                          n_prompt - 1 + n_new))
+
+
+def test_lint_records_declare_the_narrowest_and_the_widest_width(wide,
+                                                                 program):
+    """One record a program where the ladder is one width, under the
+    name it always had; a ladder declares both its ends."""
+    assert [r.name for r in program.lint_records()] == [
+        f"decode_step_s{SLOTS}", f"decode_prefill_c{PAGE}",
+        "decode_page_copy"]
+    recs = {r.name: r for r in wide.lint_records()}
+    assert sorted(recs) == sorted([
+        f"decode_step_s{SLOTS}_w8", f"decode_prefill_c{W_PAGE}_w8",
+        f"decode_step_s{SLOTS}", f"decode_prefill_c{W_PAGE}",
+        "decode_page_copy"])
+    assert recs[f"decode_step_s{SLOTS}_w8"].example_args[4].shape == (
+        SLOTS, 8)
+    assert recs[f"decode_step_s{SLOTS}"].example_args[4].shape == (
+        SLOTS, 32)
+    assert recs[f"decode_prefill_c{W_PAGE}_w8"].example_args[4].shape \
+        == (8,)

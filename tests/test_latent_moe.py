@@ -75,13 +75,15 @@ def program(model):
     return prog
 
 
-def paged_logits(prog, tokens, n_prompt):
+def paged_logits(prog, tokens, n_prompt, width=None):
     """Logits of positions n_prompt-1 .. len(tokens)-2 of one sequence
     through the paged pool: the prompt by the compiled chunk program,
     then one position at a time by the model's own layer functions in
     the decode step's order (project, write the cell, gather the
     window, finish), teacher-forced, with the logits kept where the
-    compiled step keeps their argmax."""
+    compiled step keeps their argmax. Every window is `width` pages
+    wide (None: the narrowest of the program's ladder that holds the
+    live pages, as the engine takes it)."""
     import jax
     import jax.numpy as jnp
 
@@ -90,7 +92,7 @@ def paged_logits(prog, tokens, n_prompt):
     kv = prog.init_kv()
     for start in prog.chunk_starts(n_prompt):
         kv = prog.prefill_chunk(kv, tokens[start:start + ps], start,
-                                prog.window_pages(table, start - 1),
+                                prog.window_pages(table, start - 1, width),
                                 table[start // ps])
 
     @jax.jit
@@ -110,7 +112,7 @@ def paged_logits(prog, tokens, n_prompt):
         kv, logits = step(
             m.params, kv, jnp.asarray([tokens[pos]], jnp.int32),
             jnp.asarray([pos], jnp.int32),
-            jnp.asarray(prog.window_pages(table, pos))[None],
+            jnp.asarray(prog.window_pages(table, pos, width))[None],
             jnp.asarray([SCRATCH_PAGE if first else table[pos // ps]],
                         jnp.int32),
             jnp.asarray([0 if first else pos % ps], jnp.int32))
@@ -148,6 +150,51 @@ def test_prefill_then_decode_through_the_pool_match_the_reference_logits(
     want = want[n_prompt - 1:len(tokens) - 1]
     assert np.std(want) > 0.5
     np.testing.assert_allclose(got, want, atol=1e-3, rtol=0)
+
+
+@pytest.fixture(scope="module")
+def wide_program():
+    """A window past the floor of the width ladder: 1,024 positions in
+    pages of 128, so the programs are compiled at 4 and at 8 pages."""
+    m = LatentMoETransformer(experts_held=(0, 1, 2, 5),
+                             **dict(TINY, max_ctx=1024)).init()
+    prog = DecodeProgram(m, max_slots=2, page_size=128)
+    assert prog.widths == (4, 8)
+    prog.warmup(prog.init_kv())
+    return prog
+
+
+@pytest.mark.parametrize("seed,n_prompt", [(0, 300), (1, 700)])
+def test_a_chunk_at_a_narrow_width_gives_the_full_widths_logits(
+        wide_program, seed, n_prompt):
+    """The chunk program expands as many pages of the window as it is
+    handed: a prompt prefilled through the narrowest width that holds
+    its prior pages (4 pages while they fit, then 8) and one through
+    the whole window leave the same rows in the pool, so the logits
+    decoded from them agree, with each other and with the reference,
+    inside the tolerance of the test above. Both widths were
+    dispatched, and neither traced twice."""
+    import jax.numpy as jnp
+
+    prog, m = wide_program, wide_program.model
+    tokens = np.random.default_rng(seed).integers(
+        0, VOCAB, n_prompt + 6).tolist()
+    d0 = prog.trace_stats()["dispatches"]["chunk_by_width"]
+    narrow = paged_logits(prog, tokens, n_prompt)
+    d1 = prog.trace_stats()["dispatches"]["chunk_by_width"]
+    full = paged_logits(prog, tokens, n_prompt, width=8)
+    d2 = prog.trace_stats()["dispatches"]["chunk_by_width"]
+    n_chunks = -(-n_prompt // 128)
+    assert d1[4] - d0[4] == min(n_chunks, 5)     # 0..4 prior pages
+    assert d1[8] - d0[8] == n_chunks - min(n_chunks, 5)
+    assert (d2[4] - d1[4], d2[8] - d1[8]) == (0, n_chunks)
+    want = np.asarray(ref.logits_fn(
+        m.params, jnp.asarray([tokens]), config_of(m)))[0]
+    want = want[n_prompt - 1:len(tokens) - 1]
+    assert np.std(want) > 0.5
+    np.testing.assert_allclose(narrow, full, atol=1e-3, rtol=0)
+    np.testing.assert_allclose(narrow, want, atol=1e-3, rtol=0)
+    assert set(prog.trace_stats()["trace_counts"].values()) == {1}
 
 
 def test_served_tokens_are_the_references_best(program, model):
