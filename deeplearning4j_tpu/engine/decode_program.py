@@ -78,12 +78,52 @@ the model, and nothing here asks which one it is:
         layers and steps and read through `counters()`
   head(params, x) -> logits
 
+A SECOND KIND OF STATE, for layers that keep no rows: a model may also
+describe a state a slot, whatever the context (a linear-attention
+layer's matrix a head and its short convolution's last inputs:
+nn/delta_attention.py, zoo/hybrid_delta.py). It then gives
+
+  mix_kind
+        a name a layer, "pages" or "state". The pool holds the
+        "pages" layers only: `kv_shape`'s layer count and the index
+        `write_cells` / `read_window` take follow the model's own
+        count of them, and `project` / `decode_finish` /
+        `chunk_finish` are called for those layers alone.
+  state_shape(max_slots), state_dtype
+        the state: one buffer, or a dict of them, whose axes lead
+        [state layer, slot, ...] (`init_state`; None for a model that
+        describes none).
+  state_step(lp, x, state, i, active) -> (x, state, counts | None)
+        the i-th state layer of the decode step, its feed-forward half
+        included: each `active` row's own entry advanced by the row's
+        token, every other entry left as it was. No operation mixes
+        slots, so the bitwise contract below holds for a state as it
+        does for pages; the oracle carries a state of its own.
+  state_chunk(lp, x, entry, n_state) -> (x, entry)
+        the same layer over a chunk of ONE slot: `entry` is that
+        slot's entry of the layer as the chunk found it, and comes
+        back as it stands after the chunk's first `n_state` rows. A
+        recurrence has no mask that hides a pad row, so the host says
+        how many rows are absorbed (`state_rows`).
+
+Both programs then take the state after the pool and DONATE it like
+the pool, and return it after it. The chunk program takes two more
+scalars after `write_page`: the `slot` whose entry it advances and
+`n_state`; it reads the entry as `where(start == 0, 0, state[slot])`
+— a chunk at position 0 starts its slot from zero, which is the only
+reset there is (a select, so a poisoned slot's NaN does not survive
+it) — and writes that slot's entry back. A model that describes no
+state gets the programs it always had, argument for argument: no empty
+buffer is threaded through them. What a state cannot do, the engine
+turns off: serving/continuous.py says what (the prefix trie) and why.
+
 Page 0 is SCRATCH: the write target for inactive/suppressed rows and
 the gather target for pages with no live cell — never mapped live, and
 its (possibly garbage) bytes are zeroed out inside the attention
 primitives before any contraction.
 
-All three programs DONATE the pool: updates are in-place, the caller
+All three programs DONATE the pool (and the step and the chunk the
+state, where there is one): updates are in-place, the caller
 rebinds — program-lint's prog-unhonored-donation rule verifies the
 executable alias map actually honors it (a silent copy of this buffer
 per token is the regression the rule exists to catch; all three join
@@ -181,6 +221,22 @@ class DecodeProgram:
 
         self.precision_policy = policy_name(
             getattr(model, "compute_dtype", None))
+        # what each layer keeps (the model's `mix_kind`, a name a
+        # layer; none: pages everywhere): a page layer's index into
+        # the pool, counted over the page layers alone, or -1 - i for
+        # the i-th state layer
+        kinds = getattr(model, "mix_kind", None)
+        self.has_state = kinds is not None and "state" in kinds
+        self._layer_index: Tuple[int, ...] = ()
+        if kinds is not None:
+            n_page = n_state = 0
+            for kind in kinds:
+                if kind == "state":
+                    n_state += 1
+                    self._layer_index += (-n_state,)
+                else:
+                    self._layer_index += (n_page,)
+                    n_page += 1
         # host-side dispatch tally per program kind and window width —
         # trace-counter siblings that count EXECUTIONS rather than
         # retraces, so the engine's stats (and the tracing story) can
@@ -208,6 +264,37 @@ class DecodeProgram:
         import jax.numpy as jnp
 
         return jnp.zeros(self.kv_shape, self.model.kv_dtype)
+
+    def _layers(self, params):
+        """(layer parameters, index) in order: a page layer's index
+        into the pool, -1 - i for the i-th state layer."""
+        layers = params["layers"]
+        return zip(layers, self._layer_index or range(len(layers)))
+
+    def init_state(self):
+        """The per-slot state of a model that has one (zeros, in the
+        model's own nesting; `state_dtype` is one dtype or a dtype a
+        leaf), or None. A chunk at position 0 starts its slot from
+        zero, so this is the only place the state is ever zeroed
+        whole."""
+        import jax.numpy as jnp
+
+        if not self.has_state:
+            return None
+        shapes = self.model.state_shape(self.max_slots)
+        dtypes = self.model.state_dtype
+        if not isinstance(shapes, dict):
+            return jnp.zeros(shapes, dtypes)
+        return {k: jnp.zeros(shape, dtypes[k] if isinstance(dtypes, dict)
+                             else dtypes) for k, shape in shapes.items()}
+
+    def state_rows(self, prompt_len: int, start: int) -> int:
+        """Rows of the chunk at `start` that a state absorbs: the
+        prompt's tokens but the last, which the first-token step
+        consumes (it runs at position prompt_len - 1; a state has no
+        mask, so a token absorbed twice or a pad row absorbed once is
+        a different state)."""
+        return max(0, min(self.page_size, prompt_len - 1 - start))
 
     def chunk_starts(self, prompt_len: int,
                      from_token: int = 0) -> List[int]:
@@ -316,8 +403,8 @@ class DecodeProgram:
         model = self.model
         cache = model._jit_cache
 
-        def decode_fn(params, pool, tokens, positions, page_ids,
-                      write_page, write_off):
+        def body(params, pool, state, tokens, positions, page_ids,
+                 write_page, write_off):
             cache.record_trace(trace_key)
             # The named scopes say what the work is, with no layer
             # index (a reader sums over layers): they are what the
@@ -329,10 +416,18 @@ class DecodeProgram:
                 x = model.embed(params, tokens, positions)
             live = jnp.minimum(positions + 1, self.window)
             # a row whose window maps no page is an empty slot: its
-            # garbage is not counted
+            # garbage is not counted, and its state is not advanced
             active = page_ids[:, 0] != SCRATCH_PAGE
             counts = []
-            for li, lp in enumerate(params["layers"]):
+            for lp, li in self._layers(params):
+                if li < 0:
+                    # a state layer: each active row's own entry
+                    # advanced, the others left as they were
+                    x, state, c = model.state_step(lp, x, state, -1 - li,
+                                                   active)
+                    if c is not None:
+                        counts.append(c)
+                    continue
                 q, cell = model.project(lp, x, positions)
                 # scatter: the write cell is host-chosen (suppressed
                 # rows target scratch), advanced indices broadcast per
@@ -360,9 +455,23 @@ class DecodeProgram:
                 # the slot AND its private pages, purges its trie
                 # entries, and replays the request on a healthy slot)
                 ok = jnp.all(jnp.isfinite(logits), axis=-1)
+            out = (pool,) if state is None else (pool, state)
             if counts:
-                return pool, nxt, ok, sum(counts)
-            return pool, nxt, ok
+                return (*out, nxt, ok, sum(counts))
+            return (*out, nxt, ok)
+
+        if self.has_state:
+            def decode_fn(params, pool, state, tokens, positions,
+                          page_ids, write_page, write_off):
+                return body(params, pool, state, tokens, positions,
+                            page_ids, write_page, write_off)
+
+            return jax.jit(decode_fn, donate_argnums=(1, 2))
+
+        def decode_fn(params, pool, tokens, positions, page_ids,
+                      write_page, write_off):
+            return body(params, pool, None, tokens, positions, page_ids,
+                        write_page, write_off)
 
         return jax.jit(decode_fn, donate_argnums=(1,))
 
@@ -381,12 +490,28 @@ class DecodeProgram:
         cache = model._jit_cache
         offs = np.arange(t)                  # the page's cell offsets
 
-        def chunk_fn(params, pool, tokens, start, page_ids, write_page):
+        def body(params, pool, state, tokens, start, page_ids,
+                 write_page, slot, n_state):
             cache.record_trace(trace_key)
             positions = start + jnp.arange(t)
             with jax.named_scope("embed"):
                 x = model.embed(params, tokens, positions)
-            for li, lp in enumerate(params["layers"]):
+            if state is not None:
+                # the slot's entry of every state layer; a chunk at 0
+                # starts from zero whatever the slot held (a select:
+                # a poisoned slot's NaN does not survive the reset)
+                entries = jax.tree.map(
+                    lambda a: jnp.where(
+                        start == 0, 0, jax.lax.dynamic_index_in_dim(
+                            a, slot, 1, keepdims=False)), state)
+                after = []
+            for lp, li in self._layers(params):
+                if li < 0:
+                    x, entry = model.state_chunk(
+                        lp, x, jax.tree.map(lambda a: a[-1 - li], entries),
+                        n_state)
+                    after.append(entry)
+                    continue
                 # project + PARK the chunk's cells before gathering the
                 # prior ones — the same scatter-then-gather order as
                 # the decode step, which is what lets XLA update the
@@ -402,7 +527,25 @@ class DecodeProgram:
                 with jax.named_scope("kv_read"):
                     window = model.read_window(pool, li, page_ids)
                 x = model.chunk_finish(lp, x, q, cell, window, start)
-            return pool
+            if state is None:
+                return pool
+            state = jax.tree.map(
+                lambda a, *es: jax.lax.dynamic_update_index_in_dim(
+                    a, jnp.stack(es).astype(a.dtype), slot, 1),
+                state, *after)
+            return pool, state
+
+        if self.has_state:
+            def chunk_fn(params, pool, state, tokens, start, page_ids,
+                         write_page, slot, n_state):
+                return body(params, pool, state, tokens, start, page_ids,
+                            write_page, slot, n_state)
+
+            return jax.jit(chunk_fn, donate_argnums=(1, 2))
+
+        def chunk_fn(params, pool, tokens, start, page_ids, write_page):
+            return body(params, pool, None, tokens, start, page_ids,
+                        write_page, None, None)
 
         return jax.jit(chunk_fn, donate_argnums=(1,))
 
@@ -430,7 +573,7 @@ class DecodeProgram:
 
     # ----------------------------------------------------------- run
     def step(self, kv, tokens, positions, page_ids, write_page,
-             write_off):
+             write_off, state=None):
         """One decode step over all slots. `tokens`/`positions`/
         `write_page`/`write_off` are host [max_slots] int arrays and
         `page_ids` a host [max_slots, width] int array (one
@@ -442,21 +585,28 @@ class DecodeProgram:
         verdict ([max_slots] bool): a False row's token is numeric
         poison. Inactive/suppressed rows write scratch and gather
         scratch pages (zeroed in-kernel) — the host decides whose
-        outputs are real."""
+        outputs are real. A model with state takes `state` too,
+        donated like `kv`, and the result is (new_kv, next_tokens,
+        finite_ok, new_state): every row whose window maps a page had
+        its own entry advanced by its token, write suppressed or
+        not."""
         import jax.numpy as jnp
 
         width = np.shape(page_ids)[1]
         fn = self._decode_program(width)
         self._dispatches["step"][width] += 1
-        out = fn(self.model.params, kv,
+        held = (kv,) if state is None else (kv, state)
+        out = fn(self.model.params, *held,
                  jnp.asarray(tokens, jnp.int32),
                  jnp.asarray(positions, jnp.int32),
                  jnp.asarray(page_ids, jnp.int32),
                  jnp.asarray(write_page, jnp.int32),
                  jnp.asarray(write_off, jnp.int32))
-        if len(out) > 3:
-            self._note_counts(out[3])
-        return out[:3]
+        kv, *state = out[:len(held)]
+        nxt, ok, *counts = out[len(held):]
+        if counts:
+            self._note_counts(counts[0])
+        return (kv, nxt, ok, *state)
 
     def _note_counts(self, counts) -> None:
         """The step's counts ride its own fetch: their copy to the host
@@ -481,14 +631,18 @@ class DecodeProgram:
                                               self._counter_totals)}
 
     def prefill_chunk(self, kv, chunk: Sequence[int], start: int,
-                      page_ids, write_page: int):
+                      page_ids, write_page: int, state=None,
+                      slot: int = 0, n_state: int = 0):
         """Prefill one page-aligned prompt chunk (positions
         start..start+len(chunk)-1, padded to page_size) into physical
         page `write_page`, attending to the prior context through
         `page_ids` (`window_pages(table, start - 1)`: ids of a ladder
         width that holds the `start / page_size` prior pages, cells
         >= start dead), whose width picks the program. `kv` is
-        donated — rebind."""
+        donated — rebind. A model with state takes `state` (donated;
+        the result is then (new_kv, new_state)), the `slot` whose
+        entry the chunk advances — read as zero where `start` is 0 —
+        and `n_state`, the rows that entry absorbs (`state_rows`)."""
         import jax.numpy as jnp
 
         chunk = np.asarray(chunk, np.int32).ravel()
@@ -497,10 +651,12 @@ class DecodeProgram:
         width = np.shape(page_ids)[0]
         fn = self._chunk_program(width)
         self._dispatches["chunk"][width] += 1
-        return fn(self.model.params, kv, jnp.asarray(padded),
-                  jnp.int32(start),
-                  jnp.asarray(page_ids, jnp.int32),
-                  jnp.int32(write_page))
+        args = (jnp.asarray(padded), jnp.int32(start),
+                jnp.asarray(page_ids, jnp.int32), jnp.int32(write_page))
+        if state is None:
+            return fn(self.model.params, kv, *args)
+        return fn(self.model.params, kv, state, *args, jnp.int32(slot),
+                  jnp.int32(n_state))
 
     def copy_page(self, kv, src: int, dst: int):
         """Copy-on-write: duplicate physical page `src` into `dst`
@@ -511,26 +667,33 @@ class DecodeProgram:
         self._dispatches["copy"] += 1
         return fn(kv, jnp.int32(src), jnp.int32(dst))
 
-    def warmup(self, kv, buckets: Sequence[int] = ()):
+    def warmup(self, kv, buckets: Sequence[int] = (), state=None):
         """Compile every program up front, the chunk and the step at
         every ladder width (serving warmup discipline: compiles happen
         before traffic, the trace counters pin that none happen
         after). `buckets` is accepted for call-site compatibility and
         ignored — chunked prefill replaced the per-bucket prefill
         family with ONE chunk shape a width. Returns the
-        (donated-through) pool buffer."""
+        (donated-through) pool buffer, and the state's after it
+        where the model has one (made here if none is given; its
+        programs run with it: no row is active and the chunk absorbs
+        none, so a fresh state comes back as it went in)."""
         del buckets
+        if self.has_state and state is None:
+            state = self.init_state()
         kv = self.copy_page(kv, SCRATCH_PAGE, SCRATCH_PAGE)
         s = self.max_slots
         zs = np.zeros(s, np.int32)
         for w in self.widths:
-            kv = self.prefill_chunk(kv, [0] * self.page_size, 0,
-                                    np.full(w, SCRATCH_PAGE, np.int32),
-                                    SCRATCH_PAGE)
-            kv, _, _ = self.step(kv, zs, zs,
-                                 np.full((s, w), SCRATCH_PAGE, np.int32),
-                                 zs, zs)
-        return kv
+            out = self.prefill_chunk(kv, [0] * self.page_size, 0,
+                                     np.full(w, SCRATCH_PAGE, np.int32),
+                                     SCRATCH_PAGE, state=state)
+            kv, state = out if self.has_state else (out, None)
+            kv, _, _, *rest = self.step(
+                kv, zs, zs, np.full((s, w), SCRATCH_PAGE, np.int32),
+                zs, zs, *(() if state is None else (state,)))
+            state = rest[0] if rest else None
+        return (kv, state) if self.has_state else kv
 
     def trace_stats(self) -> dict:
         """`dispatches` counts executions by program, and the chunk's
@@ -569,6 +732,11 @@ class DecodeProgram:
 
         model = self.model
         kv = self.init_kv()
+        # a model with state: the state rides beside the pool in both
+        # programs, donated and checked like it
+        held = (kv, self.init_state()) if self.has_state else (kv,)
+        donated = tuple(range(1, 1 + len(held)))
+        tail = (jnp.int32(0), jnp.int32(0)) if self.has_state else ()
         s = self.max_slots
         source = "deeplearning4j_tpu/engine/decode_program.py"
         zs = jnp.zeros(s, jnp.int32)
@@ -582,23 +750,24 @@ class DecodeProgram:
                 ProgramRecord(
                     name=f"decode_step_s{s}{tag}",
                     fn=getattr(step_fn, "__wrapped__", step_fn),
-                    example_args=(model.params, kv, zs, zs,
+                    example_args=(model.params, *held, zs, zs,
                                   jnp.zeros((s, w), jnp.int32), zs, zs),
-                    donate_argnums=(1,),
+                    donate_argnums=donated,
                     precision_policy=self.precision_policy,
                     source=source,
                     consumed_outputs=tuple(range(
-                        3 + bool(model.step_counters)))),
+                        2 + len(held) + bool(model.step_counters)))),
                 ProgramRecord(
                     name=f"decode_prefill_c{self.page_size}{tag}",
                     fn=getattr(chunk_fn, "__wrapped__", chunk_fn),
-                    example_args=(model.params, kv,
+                    example_args=(model.params, *held,
                                   jnp.zeros(self.page_size, jnp.int32),
                                   jnp.int32(0), jnp.zeros(w, jnp.int32),
-                                  jnp.int32(1)),
-                    donate_argnums=(1,),
+                                  jnp.int32(1), *tail),
+                    donate_argnums=donated,
                     precision_policy=self.precision_policy,
-                    source=source, consumed_outputs=(0,))]
+                    source=source,
+                    consumed_outputs=tuple(range(len(held))))]
         return records + [
             ProgramRecord(
                 name="decode_page_copy",

@@ -67,26 +67,34 @@ def _pad(x, width: int):
                    + [(0, width - x.shape[-1])])
 
 
-def latent_project(lp: dict, x, positions, dims, theta: float, eps: float):
+def latent_project(lp: dict, x, positions, dims, theta, eps: float):
     """x [N, h] -> ((q_nope [N, H, Dn], q_rope [N, H, R]), cell
-    [N, W]): the queries through their low-rank bottleneck, and the
-    row this layer caches of each position, [c_kv | k_rope | 0]."""
+    [N, W]): the queries through their low-rank bottleneck (or
+    straight through `wq` where the layer has none), and the row this
+    layer caches of each position, [c_kv | k_rope | 0]. `theta` None:
+    no rotation on either side (the R numbers are then one more
+    position-free key shared by the heads)."""
     import jax
     import jax.numpy as jnp
 
     n_heads, d_nope, d_rope, rank = dims
+    turn = (lambda a: a) if theta is None \
+        else (lambda a: rotary(a, positions, theta))
     with jax.named_scope("q_proj"):
         xn = rms_norm(x, lp["norm_in"], eps)
-        cq = rms_norm(mm(xn, lp["wq_a"]), lp["q_norm"], eps)
-        q = jnp.reshape(mm(cq, lp["wq_b"]),
-                        (x.shape[0], n_heads, d_nope + d_rope))
+        if "wq_a" in lp:
+            q = mm(rms_norm(mm(xn, lp["wq_a"]), lp["q_norm"], eps),
+                   lp["wq_b"])
+        else:
+            q = mm(xn, lp["wq"])
+        q = jnp.reshape(q, (x.shape[0], n_heads, d_nope + d_rope))
         q_nope = q[..., :d_nope]
-        q_rope = rotary(q[..., d_nope:], positions, theta)
+        q_rope = turn(q[..., d_nope:])
     with jax.named_scope("kv_proj"):
         kv = mm(xn, lp["wkv_a"])
         cell = _pad(jnp.concatenate([
             rms_norm(kv[..., :rank], lp["kv_norm"], eps),
-            rotary(kv[..., rank:], positions, theta)], axis=-1),
+            turn(kv[..., rank:])], axis=-1),
             row_width(rank, d_rope))
     return (q_nope, q_rope), cell
 

@@ -9,7 +9,8 @@ nothing that stands in for the absent chips — what their experts would
 have added is simply not in the result.
 
     s = sigmoid(x W_g)                     all experts, float32
-    top-k of s;  w = scale * s_top / sum(s_top)
+    top-k of s (of s + b where the router has a selection bias);
+    w = scale * s_top / sum(s_top)
     y = sum_{i in top-k, held} w_i E_i(x)  +  E_shared(x)
     E(x) = W_down(silu(W_gate x) * W_up x)
 
@@ -33,19 +34,25 @@ COUNTERS = ("moe_assignments", "moe_assignments_held",
             "moe_max_held_load", "moe_experts_hit")
 
 
-def route(x, router_w, top_k: int, scale: float):
+def route(x, router_w, top_k: int, scale: float, bias=None):
     """(expert ids [.., k], weights [.., k]) of each row: sigmoid
     scores over all experts in float32 at the highest matmul precision
     (2M parameters: nothing beside the experts, and a near-tie between
     the k-th and the next expert should turn on the stream's rounding,
-    not on the router's own), the k largest, renormalised and scaled."""
+    not on the router's own), the k largest, renormalised and scaled.
+    A `bias` [n_experts] moves the choice only: the k largest of
+    `scores + bias` are kept, weighted by their own scores."""
     import jax
     import jax.numpy as jnp
 
     scores = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    top_s, top_i = jax.lax.top_k(scores, top_k)
+    if bias is None:
+        top_s, top_i = jax.lax.top_k(scores, top_k)
+    else:
+        _, top_i = jax.lax.top_k(scores + bias, top_k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
     return top_i, scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
 
 
@@ -62,7 +69,8 @@ def held_weights(top_i, top_w, held):
 def expert_layer(lp: dict, x, held, top_k: int, scale: float,
                  active=None):
     """x [N, h] (normed, float32) -> (y [N, h], counts). `lp` has the
-    router `router` [h, n_experts], the held experts stacked in the
+    router `router` [h, n_experts] (and, where it has one, its
+    selection bias `router_bias`), the held experts stacked in the
     order of `held` (`eg`, `eu` [E, h, f]; `ed` [E, f, h]) and the
     shared expert (`sg`, `su`, `sd`). `counts` is the int32 vector of
     COUNTERS over the rows `active` marks (None: no counts)."""
@@ -72,7 +80,8 @@ def expert_layer(lp: dict, x, held, top_k: int, scale: float,
     from deeplearning4j_tpu.nn.attention import gated_mlp
 
     with jax.named_scope("moe/router"):
-        top_i, top_w = route(x, lp["router"], top_k, scale)
+        top_i, top_w = route(x, lp["router"], top_k, scale,
+                             lp.get("router_bias"))
         w = held_weights(top_i, top_w, held)            # [N, E]
     with jax.named_scope("moe/experts"):
         xe = x.astype(lp["eg"].dtype)

@@ -56,6 +56,36 @@ over a shared refcounted physical pool (PagePool) —
           lose a reference (the poison only ever wrote private
           cells).
 
+Per-slot state (a model whose layers keep a fixed-size state a slot
+instead of rows: engine/decode_program.py, "a second kind of state").
+The engine owns that buffer beside `kv` (`self.state`) and hands it to
+the step and the chunk. Three things it does for pages are wrong for
+such a state, and it does them differently:
+
+  trie    no PrefixTrie is built whatever `prefix_cache` says
+          (`stats()["prefix_cache"]` is False): a cached page brings a
+          prefix's rows back, not the state at its end. Snapshots of
+          the state at page boundaries are what would turn it on again
+          (ROADMAP R2).
+  pad     a chunk is padded to page_size and its pad rows write cells
+          no mask exposes; a recurrence has no mask, so the chunk is
+          told how many rows the state absorbs
+          (`DecodeProgram.state_rows`): the prompt's tokens but the
+          last.
+  first   the uniform first-token step runs at position len(prompt)-1
+          with its cell write suppressed; it DOES advance the state,
+          over that last prompt token, which is why the chunks leave
+          it out (a prompt of one token runs no state rows in its
+          chunk).
+
+Free, evict, quarantine, restart and journal replay need no reset of
+their own: each re-prefills from token 0, and the chunk at 0 starts its
+slot's state from zero. Ring wrap past max_ctx slides the paged
+layers' window and leaves the state whole (it has no window).
+Counters: `state_resets` (chunks dispatched at position 0),
+`state_rows` (slot-steps that advanced a state: decode rows + chunk
+rows absorbed), gauge `state_bytes`.
+
 Byte-identity contract: greedy decoding + per-slot independence of the
 compiled step mean every emitted token is a deterministic function of
 the request's own tokens — independent of which slot it lands in, who
@@ -143,6 +173,12 @@ from deeplearning4j_tpu.serving.flight import FlightRecorder
 # out); tests/conftest.py reaps whatever a failed chaos test left
 # running so no loop/watchdog thread leaks into later tier-1 tests
 _LIVE_ENGINES: "weakref.WeakSet[DecodeEngine]" = weakref.WeakSet()
+
+
+def _tree_bytes(tree) -> int:
+    import jax
+
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
 
 
 def _ring_quantile(ring, q: float) -> Optional[float]:
@@ -571,6 +607,10 @@ class DecodeEngine:
         self.max_engine_restarts = int(max_engine_restarts)
         self.poison_strike_limit = int(poison_strike_limit)
         self.kv = program.init_kv()
+        # a model with per-slot state (engine/decode_program.py): one
+        # more donated buffer, indexed by slot; None for every other
+        self.state = program.init_state()
+        self._state_bytes = _tree_bytes(self.state)
         s = self.max_slots
         self._tokens = np.zeros(s, np.int32)
         self._positions = np.zeros(s, np.int32)
@@ -584,9 +624,7 @@ class DecodeEngine:
         # positions wrap through the table past max_ctx
         p = program.pages_per_slot
         self._pool = PagePool(program.n_pages)
-        self._trie: Optional[PrefixTrie] = (
-            PrefixTrie(program.page_size) if self.prefix_cache
-            else None)
+        self._trie = self._new_trie()
         self._table: List[List[Optional[int]]] = [[None] * p
                                                   for _ in range(s)]
         # -1 = not filling; else the next prompt position to chunk
@@ -623,6 +661,8 @@ class DecodeEngine:
         self._prefix_hits = 0          # joins that mapped >=1 page
         self._prefix_page_hits = 0     # pages mapped from the trie
         self._ctx_wraps = 0            # page recycles past the window
+        self._state_resets = 0         # chunks that began a state anew
+        self._state_rows = 0           # slot-steps that advanced one
         self._cow_copies = 0
         self._kv_pages_gathered = 0    # pages the decode steps read,
         self._kv_pages_live = 0        # and those with a live cell
@@ -677,6 +717,16 @@ class DecodeEngine:
         _LIVE_ENGINES.add(self)
         if journal is not None:
             self.attach_journal(journal)
+
+    def _new_trie(self) -> Optional[PrefixTrie]:
+        """The prefix trie, or None: off by `prefix_cache=False`, and
+        off whatever it says for a program with per-slot state — a
+        cached page brings back a prefix's rows, not the state at its
+        end, so a prompt that skipped its shared chunks would start
+        from a state that never saw them."""
+        if self.prefix_cache and not self.program.has_state:
+            return PrefixTrie(self.program.page_size)
+        return None
 
     @property
     def tracer(self):
@@ -817,6 +867,7 @@ class DecodeEngine:
                     h = self._slot_req[s]
                     live.append((h, h.tokens_so_far()))
             self.kv = self.program.init_kv()
+            self.state = self.program.init_state()
             self._tokens[:] = 0
             self._positions[:] = 0
             self._active[:] = False
@@ -828,8 +879,7 @@ class DecodeEngine:
             # refcounts, and page quarantine all restart from zero
             p = self.program.pages_per_slot
             self._pool = PagePool(self.program.n_pages)
-            self._trie = (PrefixTrie(self.program.page_size)
-                          if self.prefix_cache else None)
+            self._trie = self._new_trie()
             self._table = [[None] * p for _ in range(self.max_slots)]
             self._fill_next[:] = -1
             self._first_step[:] = False
@@ -1223,9 +1273,15 @@ class DecodeEngine:
                 pp.mark("tables")
                 page_ids, wp, wo = self._step_tables(decoding)
                 pp.mark("dispatch")
-                self.kv, nxt, ok = self.program.step(
+                self.kv, nxt, ok, *state = self.program.step(
                     self.kv, self._tokens, self._positions, page_ids,
-                    wp, wo)
+                    wp, wo, *(() if self.state is None else (self.state,)))
+                if state:
+                    # every decoding row advanced its own entry, the
+                    # first-token rows too (their cell write alone is
+                    # suppressed)
+                    self.state = state[0]
+                    self._state_rows += int(decoding.sum())
                 pp.mark("fetch")    # the host blocked on the device
                 nxt_host = np.asarray(nxt)
                 ok_host = np.asarray(ok)
@@ -1433,8 +1489,20 @@ class DecodeEngine:
         # as wide as the prior pages need: the narrowest ladder width
         page_ids = self.program.window_pages(self._table[slot],
                                              start - 1)
-        self.kv = self.program.prefill_chunk(
-            self.kv, prompt[start:start + ps], start, page_ids, page)
+        if self.state is None:
+            self.kv = self.program.prefill_chunk(
+                self.kv, prompt[start:start + ps], start, page_ids, page)
+        else:
+            # the state absorbs the chunk's tokens but pad rows and the
+            # prompt's last token, which the first-token step consumes;
+            # a chunk at 0 starts the slot's state from zero, which is
+            # all the reset a freed, evicted or quarantined slot needs
+            rows = self.program.state_rows(len(prompt), start)
+            self.kv, self.state = self.program.prefill_chunk(
+                self.kv, prompt[start:start + ps], start, page_ids, page,
+                state=self.state, slot=slot, n_state=rows)
+            self._state_resets += start == 0
+            self._state_rows += rows
         self._prefill_chunks += 1
         self._chunk_pages_gathered += page_ids.size
         self._chunk_pages_live += start // ps
@@ -1775,6 +1843,7 @@ class DecodeEngine:
                 "shared": self._pool.shared_count(),
                 "quarantined": len(self._pool.quarantined),
             },
+            "prefix_cache": self._trie is not None,
             "prefix_hits": self._prefix_page_hits,
             "prefix_requests_hit": self._prefix_hits,
             "prefill_chunks": self._prefill_chunks,
@@ -1787,6 +1856,13 @@ class DecodeEngine:
             # and the prefill chunks of their prior context
             "chunk_pages_gathered": self._chunk_pages_gathered,
             "chunk_pages_live": self._chunk_pages_live,
+            # a program with per-slot state: chunks dispatched at
+            # position 0 (each starts its slot's state from zero),
+            # slot-steps that advanced a state (decode rows + chunk
+            # rows absorbed), and the buffer's size
+            "state_resets": self._state_resets,
+            "state_rows": self._state_rows,
+            "state_bytes": self._state_bytes,
             # what the model counts in its own decode steps (an expert
             # layer's routed pairs); no key where it counts nothing
             **self.program.counters(),
@@ -1839,6 +1915,9 @@ def sequential_decode(program, prompt: Sequence[int],
 
     if kv is None:
         kv = program.init_kv()
+    # a model with per-slot state: the oracle carries one of its own,
+    # begun anew by the chunk at 0 as the engine's slot is
+    state = program.init_state()
     prompt = list(prompt)
     ps = program.page_size
     pps = program.pages_per_slot
@@ -1856,10 +1935,12 @@ def sequential_decode(program, prompt: Sequence[int],
         ring = (start // ps) % pps
         if table[ring] is None:
             table[ring] = alloc()
-        kv = program.prefill_chunk(kv, prompt[start:start + ps],
-                                   start,
-                                   program.window_pages(table, start - 1),
-                                   table[ring])
+        out = program.prefill_chunk(
+            kv, prompt[start:start + ps], start,
+            program.window_pages(table, start - 1), table[ring],
+            state=state, slot=slot,
+            n_state=program.state_rows(len(prompt), start))
+        kv, state = out if program.has_state else (out, None)
     out: List[int] = []
     pos = len(prompt) - 1
     tok = prompt[-1]
@@ -1882,8 +1963,10 @@ def sequential_decode(program, prompt: Sequence[int],
         tokens[slot] = tok
         positions[slot] = pos
         page_ids[slot] = program.window_pages(table, pos, w)
-        kv, nxt, _ = program.step(kv, tokens, positions, page_ids,
-                                  wp, wo)
+        kv, nxt, _, *rest = program.step(
+            kv, tokens, positions, page_ids, wp, wo,
+            *(() if state is None else (state,)))
+        state = rest[0] if rest else None
         tok = int(np.asarray(nxt)[slot])
         out.append(tok)
         pos += 1

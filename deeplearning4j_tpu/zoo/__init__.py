@@ -8,6 +8,9 @@ where the original architecture allows)."""
 
 from deeplearning4j_tpu.zoo.base import ZooModel, ModelSelector, ZooType  # noqa: F401
 from deeplearning4j_tpu.zoo.decoder import CausalTransformer  # noqa: F401
+from deeplearning4j_tpu.zoo.hybrid_delta import (  # noqa: F401
+    HybridDeltaTransformer,
+)
 from deeplearning4j_tpu.zoo.latent_moe import (  # noqa: F401
     LatentMoETransformer,
 )

@@ -9,6 +9,13 @@ the benchmark serves (benchmark/configs/pangu-ultra-moe-718b.json).
     a = x + norm_post_attn(MLA(norm_in(x)))
     y = a + norm_post_mlp(FFN(norm_pre_mlp(a)))
 
+What varies between published blocks of this family is data here: a
+query with no bottleneck (`q_lora_rank=None`: one matrix `wq`), keys
+with no rotation (`rope_theta=None`), norms before each sublayer only
+(`sandwich_norm=False`), a router with a selection bias
+(`router_bias=True`). zoo/hybrid_delta.py puts linear-attention layers
+with a per-slot state between such layers.
+
 Like zoo/decoder.CausalTransformer it is served, not fit: a parameter
 pytree, a JitCache, and the description engine/decode_program.py builds
 its three programs from (the block at the end of the class). The
@@ -36,7 +43,7 @@ from deeplearning4j_tpu.nn.jit_cache import JitCache
 
 class LatentMoETransformer:
     def __init__(self, vocab_size: int = 512, hidden: int = 64,
-                 n_heads: int = 4, q_lora_rank: int = 24,
+                 n_heads: int = 4, q_lora_rank: Optional[int] = 24,
                  kv_lora_rank: int = 16, qk_nope_dim: int = 16,
                  qk_rope_dim: int = 8, v_head_dim: int = 16,
                  dense_ff: int = 128, moe_ff: int = 32,
@@ -44,9 +51,11 @@ class LatentMoETransformer:
                  experts_held: Optional[Sequence[int]] = None,
                  n_shared: int = 1, routed_scale: float = 1.0,
                  n_dense_layers: int = 1, n_moe_layers: int = 2,
-                 max_ctx: int = 128, rope_theta: float = 10000.0,
+                 max_ctx: int = 128,
+                 rope_theta: Optional[float] = 10000.0,
                  eps: float = 1e-5, seed: int = 123,
-                 param_dtype: str = "float32"):
+                 param_dtype: str = "float32",
+                 sandwich_norm: bool = True, router_bias: bool = False):
         if max_ctx & (max_ctx - 1):
             raise ValueError(f"max_ctx must be a power of two: {max_ctx}")
         if qk_rope_dim % 2:
@@ -60,7 +69,8 @@ class LatentMoETransformer:
                              f"under n_experts {n_experts}")
         self.vocab_size, self.hidden = int(vocab_size), int(hidden)
         self.n_heads = int(n_heads)
-        self.q_lora_rank = int(q_lora_rank)
+        self.q_lora_rank = None if q_lora_rank is None \
+            else int(q_lora_rank)
         self.kv_lora_rank = int(kv_lora_rank)
         self.qk_nope_dim, self.qk_rope_dim = int(qk_nope_dim), int(qk_rope_dim)
         self.v_head_dim = int(v_head_dim)
@@ -73,7 +83,10 @@ class LatentMoETransformer:
         self.n_moe_layers = int(n_moe_layers)
         self.n_layers = self.n_dense_layers + self.n_moe_layers
         self.max_ctx = int(max_ctx)
-        self.rope_theta, self.eps = float(rope_theta), float(eps)
+        self.rope_theta = None if rope_theta is None else float(rope_theta)
+        self.eps = float(eps)
+        self.sandwich_norm = bool(sandwich_norm)
+        self.router_bias = bool(router_bias)
         self.seed = int(seed)
         # matrices, embedding and page pool; "float32" or "bfloat16"
         self.param_dtype = str(param_dtype)
@@ -84,33 +97,44 @@ class LatentMoETransformer:
         self._jit_cache = JitCache()
 
     # ----------------------------------------------------------- shapes
-    def param_shapes(self) -> dict:
-        """Leaf shapes; matrices are [in, out], the held experts
-        stacked in the order of `experts_held`."""
+    def _mix_shapes(self, layer: int) -> dict:
+        """Leaf shapes of layer `layer`'s token-mixing half."""
+        del layer                   # latent attention in every layer
         h, heads = self.hidden, self.n_heads
-        attn = {
-            "norm_in": (h,), "wq_a": (h, self.q_lora_rank),
-            "q_norm": (self.q_lora_rank,),
-            "wq_b": (self.q_lora_rank,
-                     heads * (self.qk_nope_dim + self.qk_rope_dim)),
+        d_q = heads * (self.qk_nope_dim + self.qk_rope_dim)
+        query = {"wq": (h, d_q)} if self.q_lora_rank is None else {
+            "wq_a": (h, self.q_lora_rank), "q_norm": (self.q_lora_rank,),
+            "wq_b": (self.q_lora_rank, d_q)}
+        return {
+            "norm_in": (h,), **query,
             "wkv_a": (h, self.kv_lora_rank + self.qk_rope_dim),
             "kv_norm": (self.kv_lora_rank,),
             "wkv_b": (self.kv_lora_rank,
                       heads * (self.qk_nope_dim + self.v_head_dim)),
-            "wo": (heads * self.v_head_dim, h),
-            "norm_post_attn": (h,), "norm_pre_mlp": (h,),
-            "norm_post_mlp": (h,)}
+            "wo": (heads * self.v_head_dim, h)}
+
+    def param_shapes(self) -> dict:
+        """Leaf shapes; matrices are [in, out], the held experts
+        stacked in the order of `experts_held`."""
+        h = self.hidden
         f, e, fs = self.moe_ff, len(self.experts_held), \
             self.n_shared * self.moe_ff
-        dense = dict(attn, w_gate=(h, self.dense_ff), w_up=(h, self.dense_ff),
-                     w_down=(self.dense_ff, h))
-        moe = dict(attn, router=(h, self.n_experts), eg=(e, h, f),
+        norms = {"norm_pre_mlp": (h,)}
+        if self.sandwich_norm:
+            norms.update(norm_post_attn=(h,), norm_post_mlp=(h,))
+        dense = dict(norms, w_gate=(h, self.dense_ff),
+                     w_up=(h, self.dense_ff), w_down=(self.dense_ff, h))
+        moe = dict(norms, router=(h, self.n_experts), eg=(e, h, f),
                    eu=(e, h, f), ed=(e, f, h), sg=(h, fs), su=(h, fs),
                    sd=(fs, h))
+        if self.router_bias:
+            moe["router_bias"] = (self.n_experts,)
         return {"tok_emb": (self.vocab_size, h), "final_norm": (h,),
                 "head": (h, self.vocab_size),
-                "layers": [dict(dense)] * self.n_dense_layers
-                + [dict(moe)] * self.n_moe_layers}
+                "layers": [dict(self._mix_shapes(i),
+                                **(dense if i < self.n_dense_layers
+                                   else moe))
+                           for i in range(self.n_layers)]}
 
     def init(self) -> "LatentMoETransformer":
         """Seeded weights: matrices normal / sqrt(fan_in) (every
@@ -163,6 +187,11 @@ class LatentMoETransformer:
         return COUNTERS if self.n_moe_layers else ()
 
     @property
+    def n_page_layers(self) -> int:
+        """Layers that cache a row a token in the page pool."""
+        return self.n_layers
+
+    @property
     def _dims(self):
         return (self.n_heads, self.qk_nope_dim, self.qk_rope_dim,
                 self.kv_lora_rank)
@@ -176,7 +205,7 @@ class LatentMoETransformer:
         whole lane tiles (nn/latent_attention.py says why)."""
         from deeplearning4j_tpu.nn.latent_attention import row_width
 
-        return (self.n_layers, n_pages, page_size,
+        return (self.n_page_layers, n_pages, page_size,
                 row_width(self.kv_lora_rank, self.qk_rope_dim))
 
     def embed(self, params, tokens, positions):
@@ -225,15 +254,30 @@ class LatentMoETransformer:
 
     def _finish(self, lp, x, att, active):
         """Merged heads -> the block's output: the output projection,
-        then the feed-forward half, each normed on both sides."""
+        then the feed-forward half."""
         import jax
 
-        from deeplearning4j_tpu.nn.attention import gated_mlp, mm, rms_norm
-        from deeplearning4j_tpu.nn.moe import expert_layer
+        from deeplearning4j_tpu.nn.attention import mm
 
         with jax.named_scope("attn_out"):
-            x = x + rms_norm(mm(att, lp["wo"]), lp["norm_post_attn"],
-                             self.eps)
+            x = x + self._post(lp, "norm_post_attn", mm(att, lp["wo"]))
+        return self._ffn(lp, x, active)
+
+    def _post(self, lp, gain: str, y):
+        """A sublayer's output through its own norm where the block
+        has one after the sublayer (sandwich), as it is where not."""
+        from deeplearning4j_tpu.nn.attention import rms_norm
+
+        return rms_norm(y, lp[gain], self.eps) if gain in lp else y
+
+    def _ffn(self, lp, x, active):
+        """The block's feed-forward half on the stream after token
+        mixing -> (x, counts): the gated MLP or the expert layer."""
+        import jax
+
+        from deeplearning4j_tpu.nn.attention import gated_mlp, rms_norm
+        from deeplearning4j_tpu.nn.moe import expert_layer
+
         counts = None
         if "router" in lp:
             # the layer names its own scopes; its tail is the expert
@@ -242,12 +286,12 @@ class LatentMoETransformer:
                 lp, rms_norm(x, lp["norm_pre_mlp"], self.eps),
                 self.experts_held, self.top_k, self.routed_scale, active)
             with jax.named_scope("moe/shared"):
-                x = x + rms_norm(y, lp["norm_post_mlp"], self.eps)
+                x = x + self._post(lp, "norm_post_mlp", y)
         else:
             with jax.named_scope("mlp"):
                 y = gated_mlp(rms_norm(x, lp["norm_pre_mlp"], self.eps),
                               lp["w_gate"], lp["w_up"], lp["w_down"])
-                x = x + rms_norm(y, lp["norm_post_mlp"], self.eps)
+                x = x + self._post(lp, "norm_post_mlp", y)
         return x, counts
 
     def head(self, params, x):

@@ -184,14 +184,22 @@ def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
     i32 = jnp.int32
     zs, one = sds((s,), i32), sds((), i32)
     cases = {"copy": (prog._copy_program(), (pool, one, one))}
+    # a model with per-slot state: the state beside the pool in both
+    # programs, and the chunk's slot and rows absorbed after the rest
+    held, tail = (pool,), ()
+    if prog.has_state:
+        held += ({k: sds(v, prog.model.state_dtype) for k, v in
+                  prog.model.state_shape(s).items()},)
+        tail = (one, one)
     assert prog.widths[0] < prog.widths[-1] == prog.pages_per_slot
     for tag, p in (("", prog.widths[-1]), ("_narrow", prog.widths[0])):
         cases["decode" + tag] = (
             prog._decode_program(p),
-            (params, pool, zs, zs, sds((s, p), i32), zs, zs))
+            (params, *held, zs, zs, sds((s, p), i32), zs, zs))
         cases["chunk" + tag] = (
             prog._chunk_program(p),
-            (params, pool, sds((t,), i32), one, sds((p,), i32), one))
+            (params, *held, sds((t,), i32), one, sds((p,), i32), one,
+             *tail))
     return prog, cases
 
 
@@ -305,4 +313,58 @@ def test_gpt2_cell_compiles_for_v5e_with_the_pool_as_stored(gpt2_cell,
     assert any(dims == prog.kv_shape for _, _, dims, _ in made)
     assert not [m for m in made
                 if m[0] == "copy" and m[2] == prog.kv_shape]
+    assert not [m for m in made if m[1] >= 50e6 and m[3] < 128]
+
+
+@pytest.fixture(scope="module")
+def hybrid_cell(one_chip):
+    """`kimi-linear-reason-closed64` with shapes in the place of
+    7.55 GB of bfloat16 weights, 1.34 GB of latent pool and 0.86 GB of
+    per-slot state."""
+    import jax.numpy as jnp
+
+    from benchmark.drivers import serve_hybrid
+    from benchmark.reference import kimi_linear as ref
+
+    return _cell_programs(one_chip, "kimi-linear-reason-closed64",
+                          serve_hybrid, ref, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("program", CELL_PROGRAMS)
+def test_hybrid_cell_compiles_for_v5e_with_pool_and_state_in_place(
+        hybrid_cell, program):
+    """The cell's programs at the published widths (3.77B parameters,
+    64 slots of 8,192 positions, six layers' state of 32 matrices of
+    128 x 128 a slot), the step and the chunk at the widest and the
+    narrowest window of the ladder (64 pages and 4). Pool AND state
+    are donated and updated in place, each in ONE layout (the state
+    with its 128 x 128 matrices innermost, as stored), no instruction
+    copies the state whole, no buffer of 50 MB or more is narrower
+    than a 128-lane tile, and a program needs under a gigabyte beside
+    9.75 GB of arguments."""
+    prog, cases = hybrid_cell
+    fn, args = cases[program]
+    compiled = getattr(fn, "__wrapped__", fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert prog.kv_shape == (2, 4097, 128, 640)
+    assert prog.widths == (4, 8, 16, 32, 64)
+    shapes = prog.model.state_shape(prog.max_slots)
+    assert shapes == {"s": (6, 64, 32, 128, 128),
+                      "tail": (6, 64, 3, 12288)}
+    pool_bytes = int(np.prod(prog.kv_shape)) * 2
+    state_bytes = sum(int(np.prod(v)) * 4 for v in shapes.values())
+    assert mem.temp_size_in_bytes < 1.0e9
+    text = compiled.as_text()
+    if program == "copy":
+        assert mem.alias_size_in_bytes >= pool_bytes
+        return
+    assert mem.alias_size_in_bytes >= pool_bytes + state_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
+    assert set(re.findall(r"bf16\[2,4097,128,640\]\{([0-9,]+):",
+                          text)) == {"3,2,1,0"}
+    assert set(re.findall(r"f32\[6,64,32,128,128\]\{([0-9,]+):",
+                          text)) == {"4,3,2,1,0"}
+    made = _materialized(text)
+    assert not [m for m in made
+                if m[0] == "copy" and m[2] == shapes["s"]]
     assert not [m for m in made if m[1] >= 50e6 and m[3] < 128]
