@@ -8,6 +8,9 @@ constraint that makes one-program XLA serving work at all, per
 
   decode step   ONE program per window width over the engine's fixed
                 [max_slots] batch: consume each slot's current token
+                (the host's, or where the host says so the one the
+                step before emitted, which then never leaves the
+                device before it is consumed: `step`'s `prev`/`take`)
                 at its current LOGICAL position, scatter that
                 position's K/V into a host-chosen (page, offset) write
                 cell, gather each slot's attention window a whole page
@@ -404,7 +407,7 @@ class DecodeProgram:
         cache = model._jit_cache
 
         def body(params, pool, state, tokens, positions, page_ids,
-                 write_page, write_off):
+                 write_page, write_off, prev, take):
             cache.record_trace(trace_key)
             # The named scopes say what the work is, with no layer
             # index (a reader sums over layers): they are what the
@@ -413,6 +416,9 @@ class DecodeProgram:
             # the work is done. The model's functions name their own
             # (`qkv`, `attn`, `mlp`, ...).
             with jax.named_scope("embed"):
+                # a row the host marks takes the token the step before
+                # this one emitted for it, which never left the device
+                tokens = jnp.where(take, prev, tokens)
                 x = model.embed(params, tokens, positions)
             live = jnp.minimum(positions + 1, self.window)
             # a row whose window maps no page is an empty slot: its
@@ -462,16 +468,16 @@ class DecodeProgram:
 
         if self.has_state:
             def decode_fn(params, pool, state, tokens, positions,
-                          page_ids, write_page, write_off):
+                          page_ids, write_page, write_off, prev, take):
                 return body(params, pool, state, tokens, positions,
-                            page_ids, write_page, write_off)
+                            page_ids, write_page, write_off, prev, take)
 
             return jax.jit(decode_fn, donate_argnums=(1, 2))
 
         def decode_fn(params, pool, tokens, positions, page_ids,
-                      write_page, write_off):
+                      write_page, write_off, prev, take):
             return body(params, pool, None, tokens, positions, page_ids,
-                        write_page, write_off)
+                        write_page, write_off, prev, take)
 
         return jax.jit(decode_fn, donate_argnums=(1,))
 
@@ -573,7 +579,7 @@ class DecodeProgram:
 
     # ----------------------------------------------------------- run
     def step(self, kv, tokens, positions, page_ids, write_page,
-             write_off, state=None):
+             write_off, state=None, prev=None, take=None):
         """One decode step over all slots. `tokens`/`positions`/
         `write_page`/`write_off` are host [max_slots] int arrays and
         `page_ids` a host [max_slots, width] int array (one
@@ -589,19 +595,29 @@ class DecodeProgram:
         donated like `kv`, and the result is (new_kv, next_tokens,
         finite_ok, new_state): every row whose window maps a page had
         its own entry advanced by its token, write suppressed or
-        not."""
-        import jax.numpy as jnp
+        not.
 
+        `prev` is an earlier step's `next_tokens`, still on the device
+        and not donated (its caller may not have fetched it yet), and
+        `take` a host [max_slots] bool: a row it marks consumes
+        `prev`'s token in place of `tokens`', so a caller can dispatch
+        a step before it has read the one before (DecodeEngine's
+        run-ahead). With neither every row takes the host's.
+
+        The host arrays go over as numpy copies: an argument made with
+        `jnp.asarray` is a device program of its own between two steps
+        (`jit_convert_element_type` in the trace, PERF.md PR 28), and a
+        copy is the caller's to change again at once."""
         width = np.shape(page_ids)[1]
         fn = self._decode_program(width)
         self._dispatches["step"][width] += 1
         held = (kv,) if state is None else (kv, state)
+        if prev is None:
+            prev = take = np.zeros(self.max_slots, np.int32)
         out = fn(self.model.params, *held,
-                 jnp.asarray(tokens, jnp.int32),
-                 jnp.asarray(positions, jnp.int32),
-                 jnp.asarray(page_ids, jnp.int32),
-                 jnp.asarray(write_page, jnp.int32),
-                 jnp.asarray(write_off, jnp.int32))
+                 *(np.array(a, np.int32) for a in (
+                     tokens, positions, page_ids, write_page, write_off)),
+                 prev, np.array(take, bool))
         kv, *state = out[:len(held)]
         nxt, ok, *counts = out[len(held):]
         if counts:
@@ -610,23 +626,27 @@ class DecodeProgram:
 
     def _note_counts(self, counts) -> None:
         """The step's counts ride its own fetch: their copy to the host
-        starts with the dispatch, and they are added to the totals one
-        step late, when the caller has long had that step's tokens."""
+        starts with the dispatch, and they are added to the totals two
+        steps late. A caller runs at most one step ahead of its fetch,
+        so it has had that step's tokens by then and the add waits for
+        nothing."""
         counts.copy_to_host_async()
         with self._counter_lock:
             self._counter_pending.append(counts)
-            self._add_counts(keep=1)
+            self._add_counts(keep=2)
 
     def _add_counts(self, keep: int) -> None:
         while len(self._counter_pending) > keep:
             self._counter_totals += np.asarray(
                 self._counter_pending.pop(0))
 
-    def counters(self) -> Dict[str, int]:
+    def counters(self, wait: bool = True) -> Dict[str, int]:
         """The model's `step_counters`, summed over every decode step
-        dispatched so far (waits for the newest if it still runs)."""
+        dispatched so far (waits for the newest if it still runs), or
+        with `wait` False over all but the newest, which a caller that
+        has one step in flight leaves out so as not to block on it."""
         with self._counter_lock:
-            self._add_counts(keep=0)
+            self._add_counts(keep=0 if wait else 1)
             return {k: int(v) for k, v in zip(self.model.step_counters,
                                               self._counter_totals)}
 
@@ -643,29 +663,26 @@ class DecodeProgram:
         the result is then (new_kv, new_state)), the `slot` whose
         entry the chunk advances — read as zero where `start` is 0 —
         and `n_state`, the rows that entry absorbs (`state_rows`)."""
-        import jax.numpy as jnp
-
         chunk = np.asarray(chunk, np.int32).ravel()
         padded = np.zeros(self.page_size, np.int32)
         padded[:len(chunk)] = chunk
         width = np.shape(page_ids)[0]
         fn = self._chunk_program(width)
         self._dispatches["chunk"][width] += 1
-        args = (jnp.asarray(padded), jnp.int32(start),
-                jnp.asarray(page_ids, jnp.int32), jnp.int32(write_page))
+        # numpy values, as `step`'s: no argument is a device program
+        args = (padded, np.int32(start), np.array(page_ids, np.int32),
+                np.int32(write_page))
         if state is None:
             return fn(self.model.params, kv, *args)
-        return fn(self.model.params, kv, state, *args, jnp.int32(slot),
-                  jnp.int32(n_state))
+        return fn(self.model.params, kv, state, *args, np.int32(slot),
+                  np.int32(n_state))
 
     def copy_page(self, kv, src: int, dst: int):
         """Copy-on-write: duplicate physical page `src` into `dst`
         (every layer's cells of it). `kv` is donated — rebind."""
-        import jax.numpy as jnp
-
         fn = self._copy_program()
         self._dispatches["copy"] += 1
-        return fn(kv, jnp.int32(src), jnp.int32(dst))
+        return fn(kv, np.int32(src), np.int32(dst))
 
     def warmup(self, kv, buckets: Sequence[int] = (), state=None):
         """Compile every program up front, the chunk and the step at
@@ -751,7 +768,8 @@ class DecodeProgram:
                     name=f"decode_step_s{s}{tag}",
                     fn=getattr(step_fn, "__wrapped__", step_fn),
                     example_args=(model.params, *held, zs, zs,
-                                  jnp.zeros((s, w), jnp.int32), zs, zs),
+                                  jnp.zeros((s, w), jnp.int32), zs, zs,
+                                  zs, jnp.zeros(s, bool)),
                     donate_argnums=donated,
                     precision_policy=self.precision_policy,
                     source=source,

@@ -21,6 +21,14 @@ treats request lifecycle as pure data:
           are host state — the compiled shapes, one per window
           width of the program's ladder, are all compiled before
           traffic);
+  ahead   the engine dispatches step n+1 before it fetches step n
+          (`step_once`): the step takes the tokens of the one before
+          from the device, the host's work of a cycle runs beside the
+          device's, and what only a fetch tells (an EOS, a poison
+          verdict) arrives one step late, the overrun row thrown away
+          at its harvest. No token is emitted twice or after an EOS,
+          and the streams are the oracle's byte for byte
+          (tests/test_decode_run_ahead.py);
   evict   the `serving.slot_evict` fault point (chaos drills) can rip
           an active request out mid-generation: its recovery is
           re-prefill of the ORIGINAL prompt on a free slot + forced
@@ -150,7 +158,7 @@ import time
 import uuid
 import weakref
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -555,6 +563,16 @@ class PrefixTrie:
         self._drop_subtree(self.root, pool, quarantine=False)
 
 
+class _Dispatched(NamedTuple):
+    """One decode step between its dispatch and its fetch."""
+    nxt: object             # its tokens, [max_slots] on the device
+    ok: object              # its finite verdicts, the same
+    decoding: np.ndarray    # [max_slots] bool: the rows it ran
+    emits: np.ndarray       # of them, those whose token is the
+    #                         request's next (not a replay's forced one)
+    gen: np.ndarray         # `_slot_gen` as the dispatch found it
+
+
 class DecodeEngine:
     """Slot-based continuous-batching server for one decoder model.
 
@@ -637,6 +655,22 @@ class DecodeEngine:
         self._first_step = np.zeros(s, bool)
         # pages each slot registered into the trie (poison purge set)
         self._trie_owned: List[List[int]] = [[] for _ in range(s)]
+        # ---- run-ahead of one step (see `step_once`) ----
+        # the decode step dispatched and not yet fetched, or None
+        self._inflight: Optional[_Dispatched] = None
+        # the newest dispatched step's tokens, still on the device, and
+        # the rows whose next token is among them (the others take the
+        # host's `_tokens`: a first-token step, a replay's forced token)
+        self._nxt_dev = None
+        self._take = np.zeros(s, bool)
+        # emitting steps the resident still has to be dispatched for:
+        # max_new_tokens less what it has emitted and what is in flight
+        self._budget = np.zeros(s, np.int64)
+        # bumped when a slot is freed: a dispatched row is credited to
+        # the resident of its dispatch and to nobody who came after
+        self._slot_gen = np.zeros(s, np.int64)
+        self._steps_ahead = 0          # steps dispatched over an unfetched one
+        self._rows_discarded = 0       # rows whose resident had left
         # pending entries: (handle, replay_tokens or None)
         self._pending: deque = deque()
         # requests popped from pending but not yet resident (prefill
@@ -793,12 +827,23 @@ class DecodeEngine:
         for handle, _ in pending:
             handle._finish(None, error=err)
             self._end_span(handle, "shutdown")
+        # a step in flight is dropped unfetched: its streams fail just
+        # below, and `kv` is that step's output, the caller's to use
+        self._drop_inflight()
         for s in range(self.max_slots):
             if self._active[s] and self._slot_req[s] is not None:
                 handle = self._slot_req[s]
                 handle._finish(None, error=err)
                 self._end_span(handle, "shutdown")
                 self._free_slot(s)
+
+    def _drop_inflight(self) -> None:
+        """Forget the decode step in flight, if any, unfetched (stop,
+        restart): no row of it is credited to anyone, and no slot's
+        next token is the device's."""
+        self._inflight = None
+        self._nxt_dev = None
+        self._take[:] = False
 
     def _loop(self, epoch: int) -> None:
         while True:
@@ -883,7 +928,11 @@ class DecodeEngine:
             self._table = [[None] * p for _ in range(self.max_slots)]
             self._fill_next[:] = -1
             self._first_step[:] = False
+            self._budget[:] = 0
             self._trie_owned = [[] for _ in range(self.max_slots)]
+            # the step in flight ran on the pool that was just let go:
+            # dropped unfetched, its tokens come again with the replay
+            self._drop_inflight()
         finally:
             if got:
                 self._step_lock.release()
@@ -1237,9 +1286,38 @@ class DecodeEngine:
         """One engine iteration: deadline/cancel sweep, chaos check,
         admit/advance chunked prefills to free healthy slots (bounded
         chunk dispatches), one shared decode dispatch over the
-        translated page table, per-slot finite-verdict quarantine,
-        harvest. Returns False when there was nothing to do. Public so
-        tests drive churn deterministically without the loop thread.
+        translated page table, then the fetch and harvest of the step
+        dispatched by the call BEFORE this one: per-slot finite-verdict
+        quarantine, tokens. Returns False when there was nothing to do.
+        Public so tests drive churn deterministically without the loop
+        thread.
+
+        A run-ahead of exactly one step: step n+1 is dispatched before
+        step n is fetched, so between two calls one step is in flight
+        and everything the host does, in here and in the caller's turn,
+        runs beside the device's work. Step n+1 takes the tokens of
+        step n from the device (`DecodeProgram.step`'s `prev`/`take`).
+        What the host knows without the fetch it does at dispatch: a
+        slot's position advances, a replay's forced token is popped,
+        and a resident whose last emitting step (by `max_new_tokens`)
+        is in flight sits the next step out; its slot is freed by that
+        step's harvest, one call later than the host could have known.
+        What only the fetch tells arrives one step late: an EOS, a
+        poison verdict, and likewise a cancel, a deadline or an
+        eviction that lands while a step is in flight, find the slot's
+        row already in the step after. That row is thrown away at its
+        harvest (`rows_discarded`): a row is credited to the resident
+        its dispatch found (`_slot_gen`) and to nobody placed on the
+        slot since. It wrote one cell into a page the slot then owned
+        alone, and every program is dispatched under `_step_lock`, so
+        the device runs them in dispatch order and a later owner of the
+        page writes after it; a per-slot state is started from zero by
+        the next resident's chunk at position 0. A call that finds no
+        row to dispatch fetches and harvests the step in flight (the
+        drain), and where nothing is in flight the order is the old
+        one, a call late. The step record of a call carries the number
+        of the step it harvests.
+
         Telemetry (fault points aside, counters, gauges) fires OUTSIDE
         the step lock — emission is never a blocking op under a
         lock."""
@@ -1260,32 +1338,34 @@ class DecodeEngine:
             n_deadline, n_cancel = self._sweep_deadlines()
             evicted = self._evict_lowest_active() if evict else 0
             pp.mark("admit")
-            admitted, emitted = self._admit_pending()
+            admitted = self._admit_pending()
             # slots still mid-prefill sit out the decode dispatch
             # (their rows compute scratch-backed garbage the harvest
-            # ignores); everyone else needs a writable cell for the
+            # ignores), as do those whose last emitting step is in
+            # flight; everyone else needs a writable cell for the
             # current position — alloc / ring wrap / copy-on-write
             pp.mark("prepare_cells")
             self._prepare_write_cells()
-            decoding = self._active & (self._fill_next < 0)
-            stepped = bool(decoding.any())
-            if stepped:
+            decoding = self._decoding()
+            flight, self._inflight = self._inflight, None
+            if decoding.any():
                 pp.mark("tables")
                 page_ids, wp, wo = self._step_tables(decoding)
                 pp.mark("dispatch")
-                self.kv, nxt, ok, *state = self.program.step(
-                    self.kv, self._tokens, self._positions, page_ids,
-                    wp, wo, *(() if self.state is None else (self.state,)))
-                if state:
-                    # every decoding row advanced its own entry, the
-                    # first-token rows too (their cell write alone is
-                    # suppressed)
-                    self.state = state[0]
-                    self._state_rows += int(decoding.sum())
+                self._inflight = self._dispatch(decoding, page_ids, wp,
+                                                wo)
+                self._steps_ahead += flight is not None
+            emitted = 0
+            if flight is not None:
                 pp.mark("fetch")    # the host blocked on the device
-                nxt_host = np.asarray(nxt)
-                ok_host = np.asarray(ok)
+                nxt_host = np.asarray(flight.nxt)
+                ok_host = np.asarray(flight.ok)
                 pp.mark("harvest")
+                # a row counts for the resident its dispatch found
+                credited = flight.decoding & (flight.gen
+                                              == self._slot_gen)
+                self._rows_discarded += int(
+                    np.count_nonzero(flight.decoding & ~credited))
                 try:
                     # `decode.nonfinite` chaos site: force a poison
                     # verdict on the lowest decoding slot — the NaN
@@ -1296,13 +1376,14 @@ class DecodeEngine:
                     # analyze: allow=thr-blocking-under-lock — chaos hit must align with the decode step it poisons
                     _fire("decode.nonfinite")
                 except FaultInjectedError:
-                    victims = np.flatnonzero(decoding)
+                    victims = np.flatnonzero(credited)
                     if victims.size:
                         ok_host = ok_host.copy()
                         ok_host[victims[0]] = False
                 self._steps += 1
-                self._quarantine_poisoned(ok_host, decoding)
-                emitted += self._harvest(nxt_host, decoding)
+                self._quarantine_poisoned(ok_host, credited)
+                emitted = self._harvest(nxt_host,
+                                        credited & flight.emits)
             jevents, self._jevents = self._jevents, []
             lat, self._lat = self._lat, []
             dump_reason, self._flight_dump_reason = (
@@ -1336,17 +1417,65 @@ class DecodeEngine:
         self._publish_gauges()
         pp.mark("journal")
         self._write_journal(jevents)
-        if stepped:
-            # one record an engine step; a call that ran none (idle, or
-            # chunks only) is left to the next step's `between_steps`
+        if flight is not None:
+            # one record an engine step, under the number of the step
+            # this call harvested; a call that harvested none (idle,
+            # chunks only, or the first dispatch after a drain) is left
+            # to the next record's `between_steps`
             pp.end_step(step=self._steps)
-        return bool(stepped or admitted or chunks or evicted
-                    or n_deadline or n_cancel)
+        return bool(flight is not None or self._inflight is not None
+                    or admitted or chunks or evicted or n_deadline
+                    or n_cancel)
+
+    def _decoding(self) -> np.ndarray:
+        """The rows of the next decode dispatch: resident, prompt paged
+        in, and with an emitting step still to be dispatched (a replay
+        holds that budget until its forced tokens are through)."""
+        return self._active & (self._fill_next < 0) & (self._budget > 0)
+
+    def _dispatch(self, decoding: np.ndarray, page_ids, wp,
+                  wo) -> _Dispatched:
+        """Dispatch one decode step and do at once what the host knows
+        of its outcome without its tokens: each row's position
+        advances, a replaying row's next forced token becomes the
+        host's token for the step after, and every other row is marked
+        to take this step's token from the device."""
+        self.kv, nxt, ok, *state = self.program.step(
+            self.kv, self._tokens, self._positions, page_ids, wp, wo,
+            self.state, self._nxt_dev, self._take)
+        for out in (nxt, ok):
+            out.copy_to_host_async()
+        if state:
+            # every decoding row advanced its own entry, the
+            # first-token rows too (their cell write alone is
+            # suppressed)
+            self.state = state[0]
+            self._state_rows += int(decoding.sum())
+        self._nxt_dev = nxt
+        self._positions[decoding] += 1
+        self._first_step[decoding] = False
+        emits = decoding.copy()
+        for s in map(int, np.flatnonzero(decoding)):
+            replay = self._slot_replay[s]
+            if replay is None:
+                continue
+            # forced replay: this step's token is the recorded one
+            # again and is not emitted twice
+            emits[s] = False
+            self._tokens[s] = replay.popleft()
+            if not replay:
+                self._slot_replay[s] = None
+        self._take[decoding] = emits[decoding]
+        self._budget[emits] -= 1
+        return _Dispatched(nxt, ok, decoding, emits,
+                           self._slot_gen.copy())
 
     def _sweep_deadlines(self) -> Tuple[int, int]:
         """Finish expired/cancelled streams with their PARTIAL tokens
         (explicit finish_reason) and free their slots. Runs at the top
-        of every step — a deadline costs at most one step of slack."""
+        of every step — a deadline costs at most two steps of slack:
+        the step in flight when it lands, whose row for the slot is
+        thrown away, and the step this call dispatches."""
         now = time.monotonic()
 
         def _verdict(handle: GenerationHandle) -> Optional[str]:
@@ -1392,7 +1521,7 @@ class DecodeEngine:
         self._cancelled += n_cancel
         return n_deadline, n_cancel
 
-    def _admit_pending(self):
+    def _admit_pending(self) -> bool:
         """Spend this step's chunk budget: advance in-flight chunked
         prefills first (oldest slot first — a resident prompt finishes
         before a new one starts competing), then place waiting
@@ -1401,7 +1530,6 @@ class DecodeEngine:
         the Kth same-prompt request skips prefill entirely (bounded
         only by free slots)."""
         admitted = False
-        emitted = 0
         budget = self.max_prefills_per_step
         for s in range(self.max_slots):
             if budget <= 0:
@@ -1424,7 +1552,7 @@ class DecodeEngine:
                 with self._cond:
                     self._placing -= 1
             admitted = True
-        return admitted, emitted
+        return admitted
 
     def _place(self, handle: GenerationHandle,
                replay: Optional[List[int]], slot: int) -> int:
@@ -1442,6 +1570,10 @@ class DecodeEngine:
         self._slot_req[slot] = handle
         self._active[slot] = True
         self._slot_replay[slot] = deque(replay) if replay else None
+        # what it has emitted (a replay's recorded tokens are all of
+        # it: one in flight at the eviction was thrown away) counts
+        self._budget[slot] = (handle.max_new_tokens
+                              - len(handle.tokens_so_far()))
         if handle.t_placed is None:
             # first placement only: a re-placement after eviction is
             # recovery churn, not admission wait
@@ -1572,14 +1704,17 @@ class DecodeEngine:
         window (ctx wrap), or copy-on-write a page something else
         still references (a trie registration or a prefix twin). A
         slot the pool cannot serve even after reclaim is evicted —
-        it requeues with replay, losing nothing."""
+        it requeues with replay, losing nothing. A slot's position is
+        that of its next dispatch (it advanced when the last went
+        out), so all of this is a function of positions and the page
+        table and waits for no fetch."""
         ps = self.program.page_size
         c = self.program.window
         p = self.program.pages_per_slot
-        for s in range(self.max_slots):
-            if (not self._active[s] or self._fill_next[s] >= 0
-                    or self._first_step[s]):
-                continue
+        for s in map(int, np.flatnonzero(self._decoding()
+                                         & ~self._first_step)):
+            if not self._active[s]:
+                continue        # evicted for an earlier slot's page
             pos = int(self._positions[s])
             ring = (pos // ps) % p
             page = self._table[s][ring]
@@ -1640,27 +1775,17 @@ class DecodeEngine:
             np.count_nonzero(page_ids != SCRATCH_PAGE))
         return page_ids, wp, wo
 
-    def _harvest(self, nxt_host: np.ndarray,
-                 decoding: np.ndarray) -> int:
+    def _harvest(self, nxt_host: np.ndarray, emits: np.ndarray) -> int:
+        """Hand the fetched step's tokens to the rows that emit one:
+        still held by the resident of their dispatch, and not a
+        replay's forced step. Position and replay moved at dispatch."""
         emitted = 0
         # one clock read per step: every slot's token materialized in
         # the same dispatch, so they share a timestamp (TTFT/ITL marks
         # are tuples into _lat — emission happens after the step lock)
         now = time.perf_counter()
-        for s in range(self.max_slots):
-            if not decoding[s] or not self._active[s]:
-                continue
-            self._positions[s] += 1
-            self._first_step[s] = False
-            replay = self._slot_replay[s]
-            if replay is not None:
-                forced = replay.popleft()
-                if not replay:
-                    self._slot_replay[s] = None
-                self._tokens[s] = forced
-                continue
+        for s in map(int, np.flatnonzero(emits & self._active)):
             tok = int(nxt_host[s])
-            self._tokens[s] = tok
             handle = self._slot_req[s]
             handle._append(tok)
             self._jevents.append(("progress", handle))
@@ -1709,6 +1834,10 @@ class DecodeEngine:
         self._slot_replay[slot] = None
         self._positions[slot] = 0
         self._tokens[slot] = 0
+        # whatever is in flight for the slot was the old resident's
+        self._take[slot] = False
+        self._budget[slot] = 0
+        self._slot_gen[slot] += 1
 
     # --------------------------------------------------------- eviction
     def _evict_slot(self, s: int) -> None:
@@ -1865,7 +1994,9 @@ class DecodeEngine:
             "state_bytes": self._state_bytes,
             # what the model counts in its own decode steps (an expert
             # layer's routed pairs); no key where it counts nothing
-            **self.program.counters(),
+            # a step in flight is not waited for: its counts are added
+            # once it is fetched
+            **self.program.counters(wait=self._inflight is None),
             "trie_blocks": (len(self._trie)
                             if self._trie is not None else 0),
             "steps": self._steps,
@@ -1882,6 +2013,12 @@ class DecodeEngine:
             "tokens_per_s": round(self.tokens_per_s(), 3),
             "trace_counts": self.program.trace_stats()["trace_counts"],
             "dispatches": self.program.trace_stats().get("dispatches"),
+            # the run-ahead: decode steps dispatched while the step
+            # before was unfetched (over `steps`: the share of steps
+            # that ran ahead, 1.0 less the drains), and rows thrown
+            # away because their resident had left by their harvest
+            "steps_ahead": self._steps_ahead,
+            "rows_discarded": self._rows_discarded,
             "latency": self.latency_stats(),
             "phases": self._phases.report(),
             "flight": self._flight.stats(),

@@ -193,9 +193,12 @@ def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
         tail = (one, one)
     assert prog.widths[0] < prog.widths[-1] == prog.pages_per_slot
     for tag, p in (("", prog.widths[-1]), ("_narrow", prog.widths[0])):
+        # the step's last two arguments (PR 35): the step before's
+        # tokens, on the device, and the rows that take them
         cases["decode" + tag] = (
             prog._decode_program(p),
-            (params, *held, zs, zs, sds((s, p), i32), zs, zs))
+            (params, *held, zs, zs, sds((s, p), i32), zs, zs, zs,
+             sds((s,), jnp.bool_)))
         cases["chunk" + tag] = (
             prog._chunk_program(p),
             (params, *held, sds((t,), i32), one, sds((p,), i32), one,
@@ -368,3 +371,43 @@ def test_hybrid_cell_compiles_for_v5e_with_pool_and_state_in_place(
     assert not [m for m in made
                 if m[0] == "copy" and m[2] == shapes["s"]]
     assert not [m for m in made if m[1] >= 50e6 and m[3] < 128]
+
+
+@pytest.mark.parametrize("cell,width", [("latent", 8), ("latent", 16),
+                                        ("hybrid", 8), ("hybrid", 16),
+                                        ("hybrid", 32)])
+def test_decode_step_keeps_its_pins_at_the_ladder_widths_between(
+        cell, width, request):
+    """The decode step at the ladder widths the tests above leave out
+    (they take the narrowest and the widest), with the two arguments of
+    PR 35: the step before's tokens (`s32[slots]`, the fifth such
+    parameter, not donated: its caller may not have fetched it) and the
+    rows that take them (`pred[slots]`). Pool and state are still
+    donated and updated in place, in one layout, under a gigabyte of
+    temporaries."""
+    import jax
+    import jax.numpy as jnp
+
+    prog, cases = request.getfixturevalue(f"{cell}_cell")
+    assert width in prog.widths[1:-1]
+    _, args = cases["decode"]
+    s = prog.max_slots
+    ids = jax.ShapeDtypeStruct((s, width), jnp.int32,
+                               sharding=args[-5].sharding)
+    fn = prog._decode_program(width)
+    compiled = getattr(fn, "__wrapped__", fn).lower(
+        *args[:-5], ids, *args[-4:]).compile()
+    mem = compiled.memory_analysis()
+    donated = int(np.prod(prog.kv_shape)) * 2
+    if prog.has_state:
+        donated += sum(int(np.prod(v)) * 4 for v in
+                       prog.model.state_shape(s).values())
+    assert donated <= mem.alias_size_in_bytes < donated + 4 * s
+    assert mem.temp_size_in_bytes < 1.0e9
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert len(re.findall(rf"s32\[{s}\]\S* parameter\(", entry)) == 5
+    assert len(re.findall(rf"pred\[{s}\]\S* parameter\(", entry)) == 1
+    pool = ",".join(str(d) for d in prog.kv_shape)
+    assert set(re.findall(rf"bf16\[{pool}\]\{{([0-9,]+):", text)) == {
+        ",".join(str(i) for i in reversed(range(len(prog.kv_shape))))}

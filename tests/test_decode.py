@@ -274,7 +274,10 @@ def test_streaming_accumulation_mid_generation(program):
     assert h.tokens_so_far() == []
     # one engine iteration = admit + chunk-prefill the short prompt +
     # the uniform first-token decode dispatch — a join on a one-page
-    # prompt emits its first token the same step it is admitted
+    # prompt has its first token dispatched by the call that admits
+    # it, and harvested by the next (the engine runs one step ahead)
+    eng.step_once()
+    assert h.tokens_so_far() == []
     eng.step_once()
     assert len(h.tokens_so_far()) == 1
     eng.step_once()

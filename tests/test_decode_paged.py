@@ -287,9 +287,11 @@ def test_kv_page_counters_follow_a_hand_worked_schedule(program):
                        max_prefills_per_step=1)
     assert eng.stats()["kv_pages_gathered"] == 0
     assert eng.stats()["kv_pages_live"] == 0
-    # A: 10 tokens (2 chunks), 3 new. One chunk a step, so step 1
-    # prefills chunk 0 and no slot decodes; step 2 prefills chunk 1
-    # and A decodes at position 9 (2 pages live), then 10 and 11
+    # A: 10 tokens (2 chunks), 3 new. One chunk a step, so call 1
+    # prefills chunk 0 and no slot decodes; call 2 prefills chunk 1
+    # and A decodes at position 9 (2 pages live), then 10 and 11. The
+    # pages are counted as a step is dispatched, the step as it is
+    # harvested, by the call after (the engine runs one step ahead)
     a = eng.submit(list(range(1, 11)), 3)
     assert eng.step_once()
     assert eng.stats()["steps"] == 0          # nothing decoded yet
@@ -297,9 +299,13 @@ def test_kv_page_counters_follow_a_hand_worked_schedule(program):
     for k in (1, 2, 3):
         assert eng.step_once()
         st = eng.stats()
-        assert st["steps"] == k
+        assert st["steps"] == k - 1
         assert st["kv_pages_gathered"] == k * SLOTS * pps
         assert st["kv_pages_live"] == 2 * k
+    assert not a.done
+    assert eng.step_once()                    # the drain: step 3's harvest
+    assert eng.stats()["steps"] == 3
+    assert eng.stats()["kv_pages_gathered"] == 3 * SLOTS * pps
     assert a.done
     # B: 2 * PAGE - 1 tokens, 4 new: positions 14, 15 (2 pages live),
     # then 16, 17 (a third page)
