@@ -10,9 +10,15 @@ have added is simply not in the result.
 
     s = sigmoid(x W_g)                     all experts, float32
     top-k of s (of s + b where the router has a selection bias);
-    w = scale * s_top / sum(s_top)
+    w = scale * s_top / (sum(s_top) + norm_eps)
     y = sum_{i in top-k, held} w_i E_i(x)  +  E_shared(x)
     E(x) = W_down(silu(W_gate x) * W_up x)
+
+What varies between published layers of this kind is data: the shared
+expert is computed where the layer has one (`"sg" in lp`; with none and
+every expert held the result is the model's own layer and no partial
+sum), and `norm_eps` is the epsilon some routers put under the
+renormalisation (0: none, and the program that was traced without it).
 
 Per-row independence (the property the decode oracle's byte identity
 rests on, engine/decode_program.py): no token is dropped and no
@@ -21,7 +27,11 @@ weight for an expert it did not choose is exactly 0, so a row's result
 is a function of that row alone, whatever the other rows route to.
 With 16 experts of 94 MB held and 32 rows a step, the experts' weights
 are what a step moves; the rows a skinny product wastes cost nothing
-beside them.
+beside them. With all 64 experts of 28 MB held and 128 rows a step
+(benchmark cell `lfm2-moe-chat-closed128`) the same product is 16 times
+the operations the routing asked for, 1.24 TFLOP a step where 0.077 are
+needed, beside 9.66 GB of weights: the baseline a grouped product over
+the experts hit (ROADMAP S6 / R11) will be judged on.
 """
 
 from __future__ import annotations
@@ -34,14 +44,17 @@ COUNTERS = ("moe_assignments", "moe_assignments_held",
             "moe_max_held_load", "moe_experts_hit")
 
 
-def route(x, router_w, top_k: int, scale: float, bias=None):
+def route(x, router_w, top_k: int, scale: float, bias=None,
+          norm_eps: float = 0.0):
     """(expert ids [.., k], weights [.., k]) of each row: sigmoid
     scores over all experts in float32 at the highest matmul precision
     (2M parameters: nothing beside the experts, and a near-tie between
     the k-th and the next expert should turn on the stream's rounding,
     not on the router's own), the k largest, renormalised and scaled.
     A `bias` [n_experts] moves the choice only: the k largest of
-    `scores + bias` are kept, weighted by their own scores."""
+    `scores + bias` are kept, weighted by their own scores. `norm_eps`
+    is added to the renormalisation's denominator where it is not 0
+    (a Python number: at 0 the traced program has no such add)."""
     import jax
     import jax.numpy as jnp
 
@@ -53,7 +66,11 @@ def route(x, router_w, top_k: int, scale: float, bias=None):
     else:
         _, top_i = jax.lax.top_k(scores + bias, top_k)
         top_s = jnp.take_along_axis(scores, top_i, axis=-1)
-    return top_i, scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    top_w = scale * top_s
+    total = jnp.sum(top_s, axis=-1, keepdims=True)
+    if norm_eps:
+        total = total + norm_eps
+    return top_i, top_w / total
 
 
 def held_weights(top_i, top_w, held):
@@ -67,13 +84,14 @@ def held_weights(top_i, top_w, held):
 
 
 def expert_layer(lp: dict, x, held, top_k: int, scale: float,
-                 active=None):
+                 active=None, norm_eps: float = 0.0):
     """x [N, h] (normed, float32) -> (y [N, h], counts). `lp` has the
     router `router` [h, n_experts] (and, where it has one, its
     selection bias `router_bias`), the held experts stacked in the
-    order of `held` (`eg`, `eu` [E, h, f]; `ed` [E, f, h]) and the
-    shared expert (`sg`, `su`, `sd`). `counts` is the int32 vector of
-    COUNTERS over the rows `active` marks (None: no counts)."""
+    order of `held` (`eg`, `eu` [E, h, f]; `ed` [E, f, h]) and, where
+    the layer has one, the shared expert (`sg`, `su`, `sd`). `counts`
+    is the int32 vector of COUNTERS over the rows `active` marks (None:
+    no counts); `norm_eps` is `route`'s."""
     import jax
     import jax.numpy as jnp
 
@@ -81,7 +99,7 @@ def expert_layer(lp: dict, x, held, top_k: int, scale: float,
 
     with jax.named_scope("moe/router"):
         top_i, top_w = route(x, lp["router"], top_k, scale,
-                             lp.get("router_bias"))
+                             lp.get("router_bias"), norm_eps)
         w = held_weights(top_i, top_w, held)            # [N, E]
     with jax.named_scope("moe/experts"):
         xe = x.astype(lp["eg"].dtype)
@@ -96,8 +114,9 @@ def expert_layer(lp: dict, x, held, top_k: int, scale: float,
         # the weighted sum elementwise in float32: a float32 dot
         # would round its operands to bfloat16 on the chip
         y = jnp.sum(ye * jnp.transpose(w)[:, :, None], axis=0)
-    with jax.named_scope("moe/shared"):
-        y = y + gated_mlp(x, lp["sg"], lp["su"], lp["sd"])
+    if "sg" in lp:
+        with jax.named_scope("moe/shared"):
+            y = y + gated_mlp(x, lp["sg"], lp["su"], lp["sd"])
     if active is None:
         return y, None
     with jax.named_scope("moe/router"):

@@ -13,8 +13,14 @@ What varies between published blocks of this family is data here: a
 query with no bottleneck (`q_lora_rank=None`: one matrix `wq`), keys
 with no rotation (`rope_theta=None`), norms before each sublayer only
 (`sandwich_norm=False`), a router with a selection bias
-(`router_bias=True`). zoo/hybrid_delta.py puts linear-attention layers
-with a per-slot state between such layers.
+(`router_bias=True`) or an epsilon under its renormalisation
+(`route_eps`), an expert layer with no shared expert (`n_shared=0`: the
+layer then has no `sg`/`su`/`sd` leaves and nn/moe.py adds none), a
+head tied to the embedding (`tie_embeddings=True`: no `head` leaf).
+zoo/hybrid_delta.py puts linear-attention layers with a per-slot state
+between such layers; zoo/short_conv_moe.py takes the feed-forward
+halves, the seeded weights and the counters for a decoder of gated
+short convolutions and grouped-query attention.
 
 Like zoo/decoder.CausalTransformer it is served, not fit: a parameter
 pytree, a JitCache, and the description engine/decode_program.py builds
@@ -55,7 +61,8 @@ class LatentMoETransformer:
                  rope_theta: Optional[float] = 10000.0,
                  eps: float = 1e-5, seed: int = 123,
                  param_dtype: str = "float32",
-                 sandwich_norm: bool = True, router_bias: bool = False):
+                 sandwich_norm: bool = True, router_bias: bool = False,
+                 route_eps: float = 0.0, tie_embeddings: bool = False):
         if max_ctx & (max_ctx - 1):
             raise ValueError(f"max_ctx must be a power of two: {max_ctx}")
         if qk_rope_dim % 2:
@@ -87,6 +94,8 @@ class LatentMoETransformer:
         self.eps = float(eps)
         self.sandwich_norm = bool(sandwich_norm)
         self.router_bias = bool(router_bias)
+        self.route_eps = float(route_eps)
+        self.tie_embeddings = bool(tie_embeddings)
         self.seed = int(seed)
         # matrices, embedding and page pool; "float32" or "bfloat16"
         self.param_dtype = str(param_dtype)
@@ -125,16 +134,18 @@ class LatentMoETransformer:
         dense = dict(norms, w_gate=(h, self.dense_ff),
                      w_up=(h, self.dense_ff), w_down=(self.dense_ff, h))
         moe = dict(norms, router=(h, self.n_experts), eg=(e, h, f),
-                   eu=(e, h, f), ed=(e, f, h), sg=(h, fs), su=(h, fs),
-                   sd=(fs, h))
+                   eu=(e, h, f), ed=(e, f, h))
+        if fs:
+            moe.update(sg=(h, fs), su=(h, fs), sd=(fs, h))
         if self.router_bias:
             moe["router_bias"] = (self.n_experts,)
-        return {"tok_emb": (self.vocab_size, h), "final_norm": (h,),
-                "head": (h, self.vocab_size),
-                "layers": [dict(self._mix_shapes(i),
-                                **(dense if i < self.n_dense_layers
-                                   else moe))
-                           for i in range(self.n_layers)]}
+        top = {"tok_emb": (self.vocab_size, h), "final_norm": (h,)}
+        if not self.tie_embeddings:
+            top["head"] = (h, self.vocab_size)
+        return dict(top, layers=[
+            dict(self._mix_shapes(i),
+                 **(dense if i < self.n_dense_layers else moe))
+            for i in range(self.n_layers)])
 
     def init(self) -> "LatentMoETransformer":
         """Seeded weights: matrices normal / sqrt(fan_in) (every
@@ -284,7 +295,8 @@ class LatentMoETransformer:
             # layer's time too, so it carries one of them (`moe/*`)
             y, counts = expert_layer(
                 lp, rms_norm(x, lp["norm_pre_mlp"], self.eps),
-                self.experts_held, self.top_k, self.routed_scale, active)
+                self.experts_held, self.top_k, self.routed_scale, active,
+                self.route_eps)
             with jax.named_scope("moe/shared"):
                 x = x + self._post(lp, "norm_post_mlp", y)
         else:
@@ -295,8 +307,15 @@ class LatentMoETransformer:
         return x, counts
 
     def head(self, params, x):
+        import jax.numpy as jnp
+
         from deeplearning4j_tpu.nn.attention import mm, rms_norm
 
-        return mm(rms_norm(x, params["final_norm"], self.eps),
-                  params["head"])
+        xn = rms_norm(x, params["final_norm"], self.eps)
+        if "head" in params:
+            return mm(xn, params["head"])
+        # tied: the embedding's rows as stored, no transpose made
+        emb = params["tok_emb"]
+        return jnp.einsum("...d,vd->...v", xn.astype(emb.dtype), emb,
+                          preferred_element_type=jnp.float32)
 

@@ -188,8 +188,10 @@ def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
     # programs, and the chunk's slot and rows absorbed after the rest
     held, tail = (pool,), ()
     if prog.has_state:
-        held += ({k: sds(v, prog.model.state_dtype) for k, v in
-                  prog.model.state_shape(s).items()},)
+        shape = prog.model.state_shape(s)
+        dt = prog.model.state_dtype
+        held += ({k: sds(v, dt) for k, v in shape.items()}
+                 if isinstance(shape, dict) else sds(shape, dt),)
         tail = (one, one)
     assert prog.widths[0] < prog.widths[-1] == prog.pages_per_slot
     for tag, p in (("", prog.widths[-1]), ("_narrow", prog.widths[0])):
@@ -371,6 +373,60 @@ def test_hybrid_cell_compiles_for_v5e_with_pool_and_state_in_place(
     assert not [m for m in made
                 if m[0] == "copy" and m[2] == shapes["s"]]
     assert not [m for m in made if m[1] >= 50e6 and m[3] < 128]
+
+
+@pytest.fixture(scope="module")
+def conv_cell(one_chip):
+    """`lfm2-moe-chat-closed128` with shapes in the place of 10.36 GB
+    of bfloat16 weights, 2.15 GB of K/V pool and 15 MB of tails."""
+    import jax.numpy as jnp
+
+    from benchmark.drivers import serve_conv
+    from benchmark.reference import lfm2_moe as ref
+
+    return _cell_programs(one_chip, "lfm2-moe-chat-closed128", serve_conv,
+                          ref, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("program", CELL_PROGRAMS)
+def test_conv_cell_compiles_for_v5e_with_pool_and_tails_in_place(
+        conv_cell, program):
+    """The cell's programs at the published widths (5.18B parameters,
+    every one of 64 experts of eight layers, 128 slots of 4,096
+    positions, two attention layers' K and V rows of 512 lanes in
+    bfloat16, seven layers' tails of two rows of 2,048 a slot), the step
+    and the chunk at the widest and the narrowest window of the ladder
+    (32 pages and 4). Pool AND tails are donated and updated in place,
+    the pool in ONE layout and never copied whole or raised to
+    float32, no buffer of 50 MB or more is narrower than a 128-lane
+    tile, and arguments and temporaries leave the chip's 15.75 GiB a
+    twelfth to spare (the cell's ceiling of 92%)."""
+    prog, cases = conv_cell
+    fn, args = cases[program]
+    compiled = getattr(fn, "__wrapped__", fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert prog.kv_shape == (2, 2, 4097, 128, 512)
+    assert prog.widths == (4, 8, 16, 32)
+    tails = prog.model.state_shape(prog.max_slots)
+    assert tails == (7, 128, 2, 2048)
+    pool_bytes = int(np.prod(prog.kv_shape)) * 2
+    text = compiled.as_text()
+    if program == "copy":
+        assert mem.alias_size_in_bytes >= pool_bytes
+        return
+    assert mem.alias_size_in_bytes >= pool_bytes + int(np.prod(tails)) * 4
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 0.92 * 15.75 * 2**30
+    assert set(re.findall(r"bf16\[2,2,4097,128,512\]\{([0-9,]+):",
+                          text)) == {"4,3,2,1,0"}
+    assert not re.findall(r"f32\[2,2,4097,128,512\]", text)
+    made = _materialized(text)
+    assert not [m for m in made
+                if m[0] == "copy" and m[2] == prog.kv_shape]
+    assert not [m for m in made if m[1] >= 50e6 and m[3] < 128]
+    # no float32 copy of a gathered window (it would be 1.07 GB a plane
+    # at 32 pages)
+    assert not [m for m in made if m[1] >= 1.0e9 and m[2] != prog.kv_shape]
 
 
 @pytest.mark.parametrize("cell,width", [("latent", 8), ("latent", 16),

@@ -237,38 +237,116 @@ def test_absorbed_decode_attention_matches_the_expanded(model):
                                np.asarray(expanded[-1]), atol=2e-5, rtol=0)
 
 
-def test_shares_of_the_experts_add_up_to_the_uncut_layer():
-    """8 experts as 4 shares of 2: every share routes over all 8 and
-    computes its own experts' terms and the shared expert; their sum,
-    the shared expert counted once, is the uncut reference's layer."""
+# the reference's keys for a layer with no shared expert, every expert
+# of 64 routed over (benchmark/reference/lfm2_moe.py reads these)
+NO_SHARED_CFG = dict(
+    hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+    intermediate_size=128, moe_intermediate_size=32, num_experts_per_tok=4,
+    num_hidden_layers=2, num_dense_layers=1, vocab_size=VOCAB,
+    conv_L_cache=3, router_experts=64, experts_held=list(range(64)),
+    layer_types=["conv", "conv"], rope_parameters={"rope_theta": 1e6},
+    norm_eps=1e-5, route_norm_eps=1e-6, routed_scaling_factor=1.0)
+
+
+@pytest.mark.parametrize("shared", [True, False],
+                         ids=["shared_expert", "no_shared_expert"])
+def test_shares_of_the_experts_add_up_to_the_uncut_layer(shared):
+    """The experts as 4 shares (8 as 4 of 2 beside a shared expert; 64
+    as 4 of 16 with none, a selection bias and an epsilon under the
+    renormalisation): every share routes over all and computes its own
+    experts' terms, and the shared expert where the layer has one;
+    their sum, the shared expert counted once, is the uncut
+    reference's layer."""
     import jax
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.nn.attention import gated_mlp
     from deeplearning4j_tpu.nn.moe import expert_layer
 
-    whole = LatentMoETransformer(**TINY).init()
-    lp = whole.params["layers"][2]
-    cfg = config_of(whole)
+    if shared:
+        whole = LatentMoETransformer(**TINY).init()
+        want_fn = lambda lp, xn: ref.expert_ffn(  # noqa: E731
+            lp, xn, config_of(whole))
+        shares = [(0, 1), (2, 3), (4, 5), (6, 7)]
+    else:
+        from benchmark.reference import lfm2_moe
+        from deeplearning4j_tpu.zoo.hybrid_delta import settle
+
+        whole = LatentMoETransformer(**dict(
+            TINY, n_experts=64, top_k=4, n_shared=0, routed_scale=1.0,
+            router_bias=True, route_eps=1e-6)).init()
+        want_fn = lambda lp, xn: lfm2_moe.expert_ffn(  # noqa: E731
+            lp, xn, NO_SHARED_CFG)
+        shares = [tuple(range(i, i + 16)) for i in range(0, 64, 16)]
+    lp = settle(whole.params["layers"][2]) if not shared \
+        else whole.params["layers"][2]
+    assert ("sg" in lp) == shared
     xn = jax.random.normal(jax.random.PRNGKey(4), (12, whole.hidden))
-    want = ref.expert_ffn(lp, xn, cfg)
-    shares = [(0, 1), (2, 3), (4, 5), (6, 7)]
+    want = want_fn(lp, xn)
     total = 0.0
     for held in shares:
         mine = dict(lp, **{k: lp[k][jnp.asarray(held)]
                            for k in ("eg", "eu", "ed")})
         y, counts = expert_layer(mine, xn, held, whole.top_k,
                                  whole.routed_scale,
-                                 active=jnp.ones(12, bool))
+                                 active=jnp.ones(12, bool),
+                                 norm_eps=whole.route_eps)
         total = total + y
         assert int(counts[0]) == 12 * whole.top_k
-    total = total - (len(shares) - 1) * gated_mlp(xn, lp["sg"], lp["su"],
-                                                  lp["sd"])
+    if shared:
+        total = total - (len(shares) - 1) * gated_mlp(
+            xn, lp["sg"], lp["su"], lp["sd"])
     assert float(jnp.std(want)) > 0.1
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                atol=2e-5, rtol=0)
     # and a share alone is not the layer
     assert float(jnp.max(jnp.abs(y - want))) > 0.05
+    # with every expert held the layer is the model's own: every routed
+    # pair falls on a held expert
+    every = tuple(range(whole.n_experts))
+    y, counts = expert_layer(lp, xn, every, whole.top_k, whole.routed_scale,
+                             active=jnp.ones(12, bool),
+                             norm_eps=whole.route_eps)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5,
+                               rtol=0)
+    assert int(counts[0]) == int(counts[1]) == 12 * whole.top_k
+
+
+def test_route_without_an_epsilon_is_the_program_it_was():
+    """`norm_eps` is data: at 0 (what every model before LFM2-MoE
+    passes) the weights are `scale * s / sum(s)` bit for bit and the
+    traced program has no add under the division; at 1e-6 they are
+    `scale * s / (sum(s) + 1e-6)`."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.moe import route
+
+    key = jax.random.PRNGKey(2)
+    x = jax.random.normal(key, (9, 32))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (32, 16))
+    bias = 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (16,))
+
+    def before(x, w, bias):
+        scores = jax.nn.sigmoid(jnp.matmul(
+            x, w, precision=jax.lax.Precision.HIGHEST))
+        _, top_i = jax.lax.top_k(scores + bias, 3)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+        return top_i, 2.5 * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+
+    now = jax.jit(lambda x, w, b: route(x, w, 3, 2.5, b))
+    for got, want in zip(now(x, w, bias), jax.jit(before)(x, w, bias)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    text = str(jax.make_jaxpr(lambda x, w, b: route(x, w, 3, 2.5, b))(
+        x, w, bias))
+    assert str(jax.make_jaxpr(before)(x, w, bias)) == text
+    top_i, with_eps = route(x, w, 3, 2.5, bias, norm_eps=1e-6)
+    top_s = jnp.take_along_axis(jax.nn.sigmoid(jnp.matmul(
+        x, w, precision=jax.lax.Precision.HIGHEST)), top_i, axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(with_eps), np.asarray(2.5 * top_s / (
+            jnp.sum(top_s, axis=-1, keepdims=True) + 1e-6)), rtol=1e-6)
+    assert float(jnp.max(jnp.abs(jnp.sum(with_eps, -1) - 2.5))) < 1e-4
 
 
 # ==================================================== bfloat16 storage
