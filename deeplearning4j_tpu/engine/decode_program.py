@@ -22,16 +22,17 @@ constraint that makes one-program XLA serving work at all, per
                 one compiled shape per ladder width, all compiled
                 before traffic, so arbitrary traffic runs on the
                 warm-up's compiles (pinned by trace counters).
-  chunk prefill ONE program per window width and page_size chunk:
-                process one page-aligned slice of a prompt in parallel
-                — causal within the chunk, attending to the prior
-                context through the same gathered-page indirection —
-                and park its K/V into one physical page. A prompt is a
-                sequence of chunk dispatches interleaved between
-                decode steps, so a long prompt never stalls resident
-                generations, and a prompt whose prefix pages already
-                live in the prefix trie skips its shared chunks
-                entirely.
+  chunk prefill ONE program per window width, over `chunk_tokens`
+                rows: process one aligned block of a prompt in
+                parallel — causal within the chunk, attending to the
+                prior context through the same gathered-page
+                indirection — and park its K/V into the block's
+                `chunk_pages` physical pages, or into scratch where
+                the host says so. A prompt is a sequence of chunk
+                dispatches interleaved between decode steps, so a long
+                prompt never stalls resident generations, and a prompt
+                whose prefix pages already live in the prefix trie
+                skips the blocks they cover whole.
   page copy     the copy-on-write primitive: duplicate one physical
                 page (all layers, K and V) inside the donated pool —
                 what a slot pays to diverge from a shared page.
@@ -71,8 +72,10 @@ the model, and nothing here asks which one it is:
   write_cells(pool, li, cell, page, offset) -> pool
   read_window(pool, li, page_ids) -> window
         the scatter and the gather in the pool's own layout; `page`
-        and `offset` are per slot in the decode step, one page and its
-        offsets in a chunk
+        and `offset` are per slot in the decode step; a chunk hands
+        over whole pages, `cell` as [pages, page_size, ...], `page` a
+        page id a page and `offset` all of a page's cells (a slice),
+        or, where it is one page long, that page and its offsets
   decode_finish(lp, x, q, window, live, active) -> (x, counts | None)
   chunk_finish(lp, x, q, cell, window, start) -> x
         attention over the gathered window and the layer's feed-forward
@@ -111,7 +114,7 @@ nn/delta_attention.py, zoo/hybrid_delta.py). It then gives
 
 Both programs then take the state after the pool and DONATE it like
 the pool, and return it after it. The chunk program takes two more
-scalars after `write_page`: the `slot` whose entry it advances and
+scalars after `write_pages`: the `slot` whose entry it advances and
 `n_state`; it reads the entry as `where(start == 0, 0, state[slot])`
 — a chunk at position 0 starts its slot from zero, which is the only
 reset there is (a select, so a poisoned slot's NaN does not survive
@@ -122,8 +125,9 @@ turns off: serving/continuous.py says what (the prefix trie) and why.
 
 Page 0 is SCRATCH: the write target for inactive/suppressed rows and
 the gather target for pages with no live cell — never mapped live, and
-its (possibly garbage) bytes are zeroed out inside the attention
-primitives before any contraction.
+its (possibly garbage) bytes are kept out inside the attention
+primitives: a dead cell's score is masked, and what a sum would carry
+of it is zeroed first.
 
 All three programs DONATE the pool (and the step and the chunk the
 state, where there is one): updates are in-place, the caller
@@ -142,7 +146,7 @@ eviction replay) presents the attention reduction with identical
 operand values in identical order to the sequential oracle's — the
 FP-associativity discipline that makes "bitwise equal to the oracle"
 achievable at all. It holds at every width: no operation mixes
-slots, a wider window only appends dead cells (zeroed, masked), and
+slots, a wider window only appends dead cells (masked, zeroed), and
 engine and oracle agree bitwise when run at the same width. A chunk's
 width is a function of its start alone, so the two always agree on
 it; a step's is that of the longest slot decoding in it, so
@@ -177,8 +181,18 @@ def next_pow2(n: int) -> int:
 # PERF.md (PR 33) has the measured cost of a program in `setup_s`.
 WINDOW_FLOOR = 512
 
+# The most tokens one prefill chunk holds. A chunk's LENGTH is a
+# compute choice and a page's size a sharing grain: a chunk spans as
+# many whole pages as this budget holds (at least one, so a page of
+# this many tokens or more keeps the chunk it had). A dense product
+# over this many rows is still bound by the weights it reads (GPT-2
+# medium: 64 operations a byte at 128 rows against the v5e's 240), so
+# the pages of a chunk cost about what one does. PERF.md (PR 37) has
+# both lengths that were measured.
+CHUNK_TOKENS = 128
+
 # physical page 0: scratch — write sink for inactive/suppressed rows,
-# gather target for dead cells (zeroed inside the attention kernels)
+# gather target for dead cells (masked inside the attention kernels)
 SCRATCH_PAGE = 0
 
 
@@ -204,6 +218,11 @@ class DecodeProgram:
         # max_ctx logical positions (sliding once positions wrap)
         self.window = int(model.max_ctx)
         self.pages_per_slot = self.window // self.page_size
+        # a prefill chunk: the whole pages a budget of CHUNK_TOKENS
+        # holds, never past the window
+        self.chunk_pages = max(
+            1, min(CHUNK_TOKENS, self.window) // self.page_size)
+        self.chunk_tokens = self.chunk_pages * self.page_size
         # the ladder of window widths a program is compiled for, in
         # pages, ascending; the last is the whole window
         w = max(1, WINDOW_FLOOR // self.page_size)
@@ -297,22 +316,35 @@ class DecodeProgram:
         consumes (it runs at position prompt_len - 1; a state has no
         mask, so a token absorbed twice or a pad row absorbed once is
         a different state)."""
-        return max(0, min(self.page_size, prompt_len - 1 - start))
+        return max(0, min(self.chunk_tokens, prompt_len - 1 - start))
 
     def chunk_starts(self, prompt_len: int,
                      from_token: int = 0) -> List[int]:
-        """The page-aligned chunk schedule for a prompt: one
-        `page_size` chunk dispatch per uncovered page, starting at the
-        first token the prefix trie did not cover (`from_token` is
-        always page-aligned — partial trie pages only match when they
-        cover the prompt's entire tail)."""
+        """The chunk schedule for a prompt, a function of the position
+        alone: the starts of the `chunk_tokens`-aligned blocks that
+        hold a token at or after `from_token`, the first the prefix
+        trie did not cover. A block the trie covers in part is run
+        whole from its aligned start all the same (its covered pages'
+        rows parked in scratch), so every cell of every page comes
+        from the same row of the same program over the same split of
+        prior window and own chunk, whoever fills it: the engine, a
+        prefix twin or the oracle."""
         if prompt_len < 1:
             raise ValueError("prompt must carry at least one token")
         if prompt_len > self.window:
             raise ValueError(
                 f"prompt length {prompt_len} exceeds the attention "
                 f"window {self.window}")
-        return list(range(int(from_token), prompt_len, self.page_size))
+        b = self.chunk_tokens
+        return list(range(int(from_token) // b * b, prompt_len, b))
+
+    def block_pages(self, prompt_len: int, start: int) -> range:
+        """The logical pages of the block at `start` that hold a
+        prompt token: the pages its chunk fills (a block's later pages
+        are past the prompt's end, and their rows go to scratch)."""
+        ps = self.page_size
+        end = min(start + self.chunk_tokens, prompt_len)
+        return range(start // ps, -(-end // ps))
 
     def live_pages(self, pos: int) -> int:
         """Pages of a slot's window that hold a live cell at logical
@@ -369,8 +401,9 @@ class DecodeProgram:
                 self.n_pages, self._width(width))
 
     def chunk_key(self, width: Optional[int] = None):
-        return ("decode_chunk_prefill", self.page_size, self.window,
-                self.n_pages, self._width(width))
+        return ("decode_chunk_prefill", self.chunk_tokens,
+                self.page_size, self.window, self.n_pages,
+                self._width(width))
 
     def copy_key(self):
         return ("decode_page_copy", self.n_pages)
@@ -482,22 +515,43 @@ class DecodeProgram:
         return jax.jit(decode_fn, donate_argnums=(1,))
 
     def _build_chunk(self, trace_key: str):
-        """Compile the chunk-prefill program: one page_size slice of a
-        prompt, causal within the chunk, prior context via gathered
-        pages, K/V parked into ONE physical page (`write_page` is a
-        traced scalar — no recompile per page). Pad rows beyond
-        `length` write page cells the live masks never expose; they
-        are overwritten cell-by-cell as decoding advances."""
+        """Compile the chunk-prefill program: one `chunk_tokens`
+        block of a prompt, causal within the chunk, prior context via
+        gathered pages, row r's K/V parked at
+        `(write_pages[r // page_size], r % page_size)` (`write_pages`
+        is traced — no recompile per page; an entry that is the
+        scratch page throws its rows away: a page the prefix trie
+        already holds, or one past the prompt's end). Pad rows in the
+        prompt's last page write cells the live masks never expose;
+        they are overwritten cell-by-cell as decoding advances. ONE
+        length a width: a short chunk is padded, since the weights set
+        a chunk's time and every further program costs set-up. A chunk
+        of several pages parks a page at a time: a row at a time the
+        128 rows of GPT-2's chunk were 1.07 of its 2.43 ms on the chip
+        (each row an update of its own), a page at a time 0.16
+        (PERF.md, PR 37)."""
         import jax
         import jax.numpy as jnp
 
         model = self.model
-        t = self.page_size
+        t = self.chunk_tokens
         cache = model._jit_cache
-        offs = np.arange(t)                  # the page's cell offsets
+        cp, ps = self.chunk_pages, self.page_size
+
+        def park(pool, li, cell, write_pages):
+            if cp > 1:
+                # one update a page, each a whole page's rows
+                paged = jax.tree.map(
+                    lambda a: jnp.reshape(a, (cp, ps) + a.shape[1:]), cell)
+                return model.write_cells(pool, li, paged, write_pages,
+                                         slice(None))
+            # a chunk of one page: that page and its offsets, the
+            # program the page-128 cells always had
+            return model.write_cells(pool, li, cell, write_pages[0],
+                                     np.arange(ps))
 
         def body(params, pool, state, tokens, start, page_ids,
-                 write_page, slot, n_state):
+                 write_pages, slot, n_state):
             cache.record_trace(trace_key)
             positions = start + jnp.arange(t)
             with jax.named_scope("embed"):
@@ -523,13 +577,14 @@ class DecodeProgram:
                 # the decode step, which is what lets XLA update the
                 # donated pool in place (a gather of the PRE-scatter
                 # pool forced two full-pool copies). Safe because the
-                # prior pages can never alias `write_page`: prefill
-                # never wraps (prompt <= window), so the page ids name
-                # earlier blocks' pages or scratch.
+                # prior pages can never alias a page written here:
+                # prefill never wraps (prompt <= window), so the page
+                # ids name earlier blocks' pages, or scratch, whose
+                # cells are dead: masked, and zeroed where a sum would
+                # carry them.
                 q, cell = model.project(lp, x, positions)
                 with jax.named_scope("kv_write"):
-                    pool = model.write_cells(pool, li, cell, write_page,
-                                             offs)
+                    pool = park(pool, li, cell, write_pages)
                 with jax.named_scope("kv_read"):
                     window = model.read_window(pool, li, page_ids)
                 x = model.chunk_finish(lp, x, q, cell, window, start)
@@ -543,15 +598,15 @@ class DecodeProgram:
 
         if self.has_state:
             def chunk_fn(params, pool, state, tokens, start, page_ids,
-                         write_page, slot, n_state):
+                         write_pages, slot, n_state):
                 return body(params, pool, state, tokens, start, page_ids,
-                            write_page, slot, n_state)
+                            write_pages, slot, n_state)
 
             return jax.jit(chunk_fn, donate_argnums=(1, 2))
 
-        def chunk_fn(params, pool, tokens, start, page_ids, write_page):
+        def chunk_fn(params, pool, tokens, start, page_ids, write_pages):
             return body(params, pool, None, tokens, start, page_ids,
-                        write_page, None, None)
+                        write_pages, None, None)
 
         return jax.jit(chunk_fn, donate_argnums=(1,))
 
@@ -651,27 +706,33 @@ class DecodeProgram:
                                               self._counter_totals)}
 
     def prefill_chunk(self, kv, chunk: Sequence[int], start: int,
-                      page_ids, write_page: int, state=None,
+                      page_ids, write_pages, state=None,
                       slot: int = 0, n_state: int = 0):
-        """Prefill one page-aligned prompt chunk (positions
-        start..start+len(chunk)-1, padded to page_size) into physical
-        page `write_page`, attending to the prior context through
-        `page_ids` (`window_pages(table, start - 1)`: ids of a ladder
-        width that holds the `start / page_size` prior pages, cells
-        >= start dead), whose width picks the program. `kv` is
+        """Prefill one prompt chunk (positions
+        start..start+len(chunk)-1 of a `chunk_tokens`-aligned block,
+        padded to `chunk_tokens`) into the physical pages
+        `write_pages`, one a page of the block in order (at most
+        `chunk_pages`; the scratch page throws a page's rows away, as
+        do the pages past those given), attending to the prior context
+        through `page_ids` (`window_pages(table, start - 1)`: ids of a
+        ladder width that holds the `start / page_size` prior pages,
+        cells >= start dead), whose width picks the program. `kv` is
         donated — rebind. A model with state takes `state` (donated;
         the result is then (new_kv, new_state)), the `slot` whose
         entry the chunk advances — read as zero where `start` is 0 —
         and `n_state`, the rows that entry absorbs (`state_rows`)."""
         chunk = np.asarray(chunk, np.int32).ravel()
-        padded = np.zeros(self.page_size, np.int32)
+        padded = np.zeros(self.chunk_tokens, np.int32)
         padded[:len(chunk)] = chunk
+        write_pages = np.asarray(write_pages, np.int32).ravel()
+        pages = np.full(self.chunk_pages, SCRATCH_PAGE, np.int32)
+        pages[:len(write_pages)] = write_pages
         width = np.shape(page_ids)[0]
         fn = self._chunk_program(width)
         self._dispatches["chunk"][width] += 1
         # numpy values, as `step`'s: no argument is a device program
         args = (padded, np.int32(start), np.array(page_ids, np.int32),
-                np.int32(write_page))
+                pages)
         if state is None:
             return fn(self.model.params, kv, *args)
         return fn(self.model.params, kv, state, *args, np.int32(slot),
@@ -702,9 +763,9 @@ class DecodeProgram:
         s = self.max_slots
         zs = np.zeros(s, np.int32)
         for w in self.widths:
-            out = self.prefill_chunk(kv, [0] * self.page_size, 0,
+            out = self.prefill_chunk(kv, [0] * self.chunk_tokens, 0,
                                      np.full(w, SCRATCH_PAGE, np.int32),
-                                     SCRATCH_PAGE, state=state)
+                                     (), state=state)
             kv, state = out if self.has_state else (out, None)
             kv, _, _, *rest = self.step(
                 kv, zs, zs, np.full((s, w), SCRATCH_PAGE, np.int32),
@@ -776,12 +837,13 @@ class DecodeProgram:
                     consumed_outputs=tuple(range(
                         2 + len(held) + bool(model.step_counters)))),
                 ProgramRecord(
-                    name=f"decode_prefill_c{self.page_size}{tag}",
+                    name=f"decode_prefill_c{self.chunk_tokens}{tag}",
                     fn=getattr(chunk_fn, "__wrapped__", chunk_fn),
                     example_args=(model.params, *held,
-                                  jnp.zeros(self.page_size, jnp.int32),
+                                  jnp.zeros(self.chunk_tokens, jnp.int32),
                                   jnp.int32(0), jnp.zeros(w, jnp.int32),
-                                  jnp.int32(1), *tail),
+                                  jnp.ones(self.chunk_pages, jnp.int32),
+                                  *tail),
                     donate_argnums=donated,
                     precision_policy=self.precision_policy,
                     source=source,

@@ -3,12 +3,17 @@
 The decode-serving arc (ROADMAP item 2) needs a transformer forward
 that exists in TWO compiled shapes over ONE set of weights:
 
-  chunk prefill   one page_size-aligned slice [T, d_model] of a prompt
-             processed in parallel: causal within the chunk, attending
-             to the PRIOR context through gathered pages, emitting
-             the chunk's K/V so the caller parks them in a physical
-             page. Chunks interleave with decode steps, so a long
-             prompt never stalls resident generations.
+  chunk prefill   one aligned block [T, d_model] of a prompt, T the
+             whole pages a token budget holds (`DecodeProgram.
+             chunk_tokens`), processed in parallel: causal within the
+             chunk, attending to the PRIOR context through gathered
+             pages, emitting the chunk's K/V so the caller parks them
+             in the block's physical pages. A block always starts at
+             its aligned position and is run whole, so a cell is the
+             work of the same row over the same split of prior window
+             and own chunk whoever fills it. Chunks interleave with
+             decode steps, so a long prompt never stalls resident
+             generations.
   decode     ONE new position per slot, batched over the engine's
              [max_slots] axis, attending against whole pages GATHERED
              in ring order — the page-table indirection that makes
@@ -41,12 +46,16 @@ congruent to c modulo the window — a function of the position alone,
 and logical token order until the ring wraps) is that mechanism — a
 wrapped ring, a shared prefix page, and a fresh contiguous fill all
 reduce over the same [cells] axis in the same order, whatever
-physical pages the table names. Dead cells are zeroed BEFORE the
-score contraction (not just masked after): a dead cell lies in the
-unwritten tail of a page or on the shared scratch page, whose bytes
-other slots scribble, and 0·garbage is the only value that can never
-leak — exp(MASK_VALUE - max) underflows the weight to exactly
-0.0, and the zeroed value keeps 0·NaN out of the weighted sum.
+physical pages the table names. A dead cell lies in the unwritten
+tail of a page or on the shared scratch page, whose bytes other slots
+scribble. Its KEY reaches one number alone, its own score (a row of
+the window times the query), and the mask replaces that score whatever
+it is: a select takes nothing from the operand it drops, so a dead key
+needs no zeroing, and zeroing it was a pass over the whole window
+(PERF.md, PR 37). Its VALUE is zeroed BEFORE the weighted sum (not
+just weighted by nothing): exp(MASK_VALUE - max) underflows the weight
+to exactly 0.0, and 0·garbage is safe only where the garbage is 0 —
+the zeroed value keeps 0·NaN out of the sum.
 
 Everything here is pure jax on traced values — no host syncs, no
 Python branching on data — so the functions compose into donated,
@@ -129,8 +138,9 @@ def paged_decode_attention(q, k_rows, v_rows, live):
     position congruent to c modulo the window, with the new position's
     K/V already written at its cell. `live[s]` counts the slot's
     readable cells; cells beyond it (the unwritten tail of the newest
-    page, and whole pages that point at scratch) are zeroed before the
-    score contraction (see the module docstring). Both contractions
+    page, and whole pages that point at scratch) have their scores
+    masked and their values zeroed (see the module docstring). Both
+    contractions
     run over the rows as stored (module docstring, "Layout
     discipline"). Returns [S, n_heads * head_dim], heads merged."""
     import jax.numpy as jnp
@@ -138,7 +148,6 @@ def paged_decode_attention(q, k_rows, v_rows, live):
     n = k_rows.shape[1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
     mask = jnp.arange(n)[None, :] < live[:, None]          # [S, N]
-    k_rows = jnp.where(mask[:, :, None], k_rows, 0.0)
     v_rows = jnp.where(mask[:, :, None], v_rows, 0.0)
     e = _head_blocks(q)
     qb = merge_heads(q)[:, :, None] * e                    # [S, C, H]
@@ -158,16 +167,16 @@ def chunk_prefill_attention(q, k, v, k_rows, v_rows, n_prior):
     positions 0..n_prior-1 gathered a whole page at a time in ring
     order, which before a wrap (and a prompt never wraps) is logical
     order: cell c holds position c (cells >= n_prior are scratch:
-    zeroed + masked). ONE softmax spans [prior cells ; chunk] so the
-    reduction order is fixed regardless of how the prior pages were
-    produced — computed by an earlier chunk, or mapped read-only from
-    the prefix trie. Returns [T, n_heads * head_dim], heads merged."""
+    scores masked, values zeroed). ONE softmax spans
+    [prior cells ; chunk] so the reduction order is fixed regardless
+    of how the prior pages were produced — computed by an earlier
+    chunk, or mapped read-only from the prefix trie. Returns
+    [T, n_heads * head_dim], heads merged."""
     import jax.numpy as jnp
 
     t, n = q.shape[0], k_rows.shape[0]
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
     prior = jnp.arange(n) < n_prior                        # [N]
-    k_rows = jnp.where(prior[:, None], k_rows, 0.0)
     v_rows = jnp.where(prior[:, None], v_rows, 0.0)
     e = _head_blocks(q)
     qb = merge_heads(q)[:, :, None] * e                    # [T, C, H]

@@ -16,7 +16,7 @@ over it and both are here:
 
   expanded   (chunk prefill) expand every cached row to per-head keys
              and values and attend as usual: the cost of the expansion
-             is shared by the chunk's page_size queries.
+             is shared by the chunk's queries (`chunk_tokens` of them).
   absorbed   (decode step) fold `W_kvb`'s key half into the query and
              its value half into the output, and attend in the latent
              space: `(q_nope W_K) . c_kv + q_rope . k_rope`, then

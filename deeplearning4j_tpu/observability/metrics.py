@@ -109,6 +109,7 @@ REGISTERED_METRICS = frozenset({
     "dl4j_decode_prefix_pages_shared",
     "dl4j_decode_pages_free",
     "dl4j_decode_prefill_chunks_total",
+    "dl4j_decode_prefill_pages_total",
     "dl4j_decode_ctx_wraps_total",
     # decode durability (quarantine / migration / watchdog / deadlines)
     "dl4j_decode_slot_quarantines_total",

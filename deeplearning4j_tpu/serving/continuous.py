@@ -10,9 +10,11 @@ fixed `max_slots` batch (engine/decode_program.DecodeProgram) and
 treats request lifecycle as pure data:
 
   join    an admitted request claims a free slot at ANY step: its
-          prompt prefills in page_size CHUNKS, one chunk dispatch
-          interleaved per engine step, so a long prompt never stalls
-          resident generations; once its K/V pages are in, a uniform
+          prompt prefills in CHUNKS of `chunk_tokens` (the whole pages
+          a token budget holds: a chunk's length is a compute choice,
+          a page the grain of sharing), one chunk dispatch interleaved
+          per engine step, so a long prompt never stalls resident
+          generations; once its K/V pages are in, a uniform
           first-token decode step (write suppressed — the cells are
           already written) emits its first token and the slot rides
           the shared decode loop. Nothing recompiles;
@@ -47,8 +49,11 @@ over a shared refcounted physical pool (PagePool) —
           read-only pages (one pool ref per referent), and the Kth
           identical prompt skips prefill entirely. Sharing is bitwise
           safe because a shared page holds exactly the bytes its
-          unshared twin would have computed, and the uniform
-          first-token step runs identically either way;
+          unshared twin would have computed (a chunk block the trie
+          covers in part is run whole from its aligned start, the
+          covered pages' rows parked in scratch, so a cell always
+          comes from the same row of the same program), and the
+          uniform first-token step runs identically either way;
   CoW     the first generation write into a page something else still
           references (a trie entry, a prefix twin) copies it first
           (`decode_page_copy`) — divergence costs one page copy, not
@@ -75,9 +80,9 @@ such a state, and it does them differently:
           prefix's rows back, not the state at its end. Snapshots of
           the state at page boundaries are what would turn it on again
           (ROADMAP R2).
-  pad     a chunk is padded to page_size and its pad rows write cells
-          no mask exposes; a recurrence has no mask, so the chunk is
-          told how many rows the state absorbs
+  pad     a chunk is padded to `chunk_tokens` and its pad rows write
+          cells no mask exposes or the scratch page; a recurrence has
+          no mask, so the chunk is told how many rows the state absorbs
           (`DecodeProgram.state_rows`): the prompt's tokens but the
           last.
   first   the uniform first-token step runs at position len(prompt)-1
@@ -645,7 +650,8 @@ class DecodeEngine:
         self._trie = self._new_trie()
         self._table: List[List[Optional[int]]] = [[None] * p
                                                   for _ in range(s)]
-        # -1 = not filling; else the next prompt position to chunk
+        # -1 = not filling; else the first prompt position no page of
+        # the slot holds yet (the next chunk is the block around it)
         self._fill_next = np.full(s, -1, np.int64)
         # True while the slot's NEXT decode dispatch is the uniform
         # first-token step: position len(prompt)-1, write suppressed
@@ -691,7 +697,9 @@ class DecodeEngine:
         self._tokens_emitted = 0
         self._steps = 0
         self._prefills = 0
-        self._prefill_chunks = 0
+        self._prefill_chunks = 0       # chunk DISPATCHES
+        self._prefill_pages = 0        # pages those chunks filled
+        self._prefill_rows_padded = 0  # rows they parked in scratch
         self._prefix_hits = 0          # joins that mapped >=1 page
         self._prefix_page_hits = 0     # pages mapped from the trie
         self._ctx_wraps = 0            # page recycles past the window
@@ -1332,6 +1340,7 @@ class DecodeEngine:
         quar_before = self._quarantines
         replays_before = self._replays
         chunks_before = self._prefill_chunks
+        filled_before = self._prefill_pages
         hits_before = self._prefix_page_hits
         wraps_before = self._ctx_wraps
         with self._step_lock:
@@ -1392,6 +1401,9 @@ class DecodeEngine:
         chunks = self._prefill_chunks - chunks_before
         if chunks:
             _obs.count("dl4j_decode_prefill_chunks_total", n=chunks)
+        filled = self._prefill_pages - filled_before
+        if filled:
+            _obs.count("dl4j_decode_prefill_pages_total", n=filled)
         hits = self._prefix_page_hits - hits_before
         if hits:
             _obs.count("dl4j_decode_prefix_hits_total", n=hits)
@@ -1604,45 +1616,67 @@ class DecodeEngine:
         return self._advance_fill(slot)
 
     def _advance_fill(self, slot: int) -> int:
-        """Dispatch ONE prompt chunk for a filling slot (page_size
-        tokens into one freshly allocated page). Returns the chunk
-        dispatches spent; 0 means the pool is exhausted beyond
-        recovery this step — the fill resumes next step."""
+        """Dispatch ONE prompt chunk for a filling slot: the
+        `chunk_tokens`-aligned block that holds the slot's first
+        uncovered token, into freshly allocated pages. A page of the
+        block the prefix trie already mapped is left as it is: its
+        rows are computed again (the block runs whole from its aligned
+        start: `DecodeProgram.chunk_starts`) and parked in scratch,
+        never in a page something else reads. Returns the chunk
+        dispatches spent, 1 whatever the chunk holds; 0 means the pool
+        could not give every page of the block this step — what it
+        gave stays in the slot's table and the block is tried again
+        next step."""
+        from deeplearning4j_tpu.engine.decode_program import (
+            SCRATCH_PAGE,
+        )
+
         handle = self._slot_req[slot]
         prompt = handle.prompt
-        ps = self.program.page_size
-        start = int(self._fill_next[slot])
-        page = self._alloc_page(slot)
-        if page is None:
-            return 0
+        program = self.program
+        ps = program.page_size
+        covered = int(self._fill_next[slot])
+        start = program.chunk_starts(len(prompt), covered)[0]
+        table = self._table[slot]
+        write_pages = []
+        for b in program.block_pages(len(prompt), start):
+            if b * ps < covered:
+                write_pages.append(SCRATCH_PAGE)    # the trie's page
+                continue
+            if table[b] is None:
+                table[b] = self._alloc_page(slot)
+                if table[b] is None:
+                    return 0
+            write_pages.append(table[b])
         t0 = time.perf_counter()
-        ring = (start // ps) % self.program.pages_per_slot
-        self._table[slot][ring] = page
         # as wide as the prior pages need: the narrowest ladder width
-        page_ids = self.program.window_pages(self._table[slot],
-                                             start - 1)
+        page_ids = program.window_pages(table, start - 1)
+        chunk = prompt[start:start + program.chunk_tokens]
         if self.state is None:
-            self.kv = self.program.prefill_chunk(
-                self.kv, prompt[start:start + ps], start, page_ids, page)
+            self.kv = program.prefill_chunk(
+                self.kv, chunk, start, page_ids, write_pages)
         else:
             # the state absorbs the chunk's tokens but pad rows and the
             # prompt's last token, which the first-token step consumes;
             # a chunk at 0 starts the slot's state from zero, which is
             # all the reset a freed, evicted or quarantined slot needs
-            rows = self.program.state_rows(len(prompt), start)
-            self.kv, self.state = self.program.prefill_chunk(
-                self.kv, prompt[start:start + ps], start, page_ids, page,
+            rows = program.state_rows(len(prompt), start)
+            self.kv, self.state = program.prefill_chunk(
+                self.kv, chunk, start, page_ids, write_pages,
                 state=self.state, slot=slot, n_state=rows)
             self._state_resets += start == 0
             self._state_rows += rows
+        filled = sum(p != SCRATCH_PAGE for p in write_pages)
         self._prefill_chunks += 1
+        self._prefill_pages += filled
+        self._prefill_rows_padded += (program.chunk_pages - filled) * ps
         self._chunk_pages_gathered += page_ids.size
         self._chunk_pages_live += start // ps
         if self.tracer is not None:
             self._lat.append(("chunk", handle, t0,
                               time.perf_counter()))
         self._flight.note("chunk", self._steps, slot=slot, start=start)
-        nxt = start + ps
+        nxt = start + program.chunk_tokens
         if nxt >= len(prompt):
             self._fill_next[slot] = -1
             self._fill_done(slot)
@@ -1975,7 +2009,12 @@ class DecodeEngine:
             "prefix_cache": self._trie is not None,
             "prefix_hits": self._prefix_page_hits,
             "prefix_requests_hit": self._prefix_hits,
+            # chunk dispatches, the pages they filled, and the rows
+            # they parked in scratch (pages of a block the trie held
+            # already, computed again, and pages past a prompt's end)
             "prefill_chunks": self._prefill_chunks,
+            "prefill_pages": self._prefill_pages,
+            "prefill_rows_padded": self._prefill_rows_padded,
             "ctx_wraps": self._ctx_wraps,
             "cow_copies": self._cow_copies,
             # what the decode steps read of the pool, in pages: all
@@ -2069,13 +2108,13 @@ def sequential_decode(program, prompt: Sequence[int],
         return next_free - 1
 
     for start in program.chunk_starts(len(prompt)):
-        ring = (start // ps) % pps
-        if table[ring] is None:
-            table[ring] = alloc()
+        pages = program.block_pages(len(prompt), start)
+        for b in pages:
+            table[b] = alloc()
         out = program.prefill_chunk(
-            kv, prompt[start:start + ps], start,
-            program.window_pages(table, start - 1), table[ring],
-            state=state, slot=slot,
+            kv, prompt[start:start + program.chunk_tokens], start,
+            program.window_pages(table, start - 1),
+            table[pages.start:pages.stop], state=state, slot=slot,
             n_state=program.state_rows(len(prompt), start))
         kv, state = out if program.has_state else (out, None)
     out: List[int] = []

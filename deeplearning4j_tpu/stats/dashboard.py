@@ -430,11 +430,12 @@ def telemetry_lines(snapshot) -> list:
             dec.append(f"{c['dl4j_decode_slot_evictions_total']} "
                        "evictions")
         # paged KV virtual memory: prefix-hit rate (pages served from
-        # the trie vs pages computed by chunk prefill) + pool headroom
+        # the trie vs pages filled by chunk prefill; a chunk fills
+        # several, so its dispatches are not the count) + pool headroom
         hits = c.get("dl4j_decode_prefix_hits_total", 0)
-        chunks = c.get("dl4j_decode_prefill_chunks_total", 0)
-        if hits + chunks:
-            rate = 100.0 * hits / (hits + chunks)
+        filled = c.get("dl4j_decode_prefill_pages_total", 0)
+        if hits + filled:
+            rate = 100.0 * hits / (hits + filled)
             dec.append(f"prefix hit {rate:.0f}%")
         pages_free = gauge("dl4j_decode_pages_free")
         if pages_free is not None:

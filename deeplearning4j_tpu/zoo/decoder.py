@@ -136,8 +136,9 @@ class CausalTransformer:
 
     def write_cells(self, pool, li, cell, page, offset):
         """pool[li, io, page, offset] = k or v as one row, heads merged
-        (`page` per slot or one page, `offset` per slot or the page's
-        offsets: the advanced indices broadcast). A row is written
+        (`page` and `offset` per slot in the decode step; from a chunk
+        a page id a page with `offset` the whole page, or one page and
+        its offsets: the advanced indices broadcast). A row is written
         whole, so no cell is scattered into the lanes of another's."""
         from deeplearning4j_tpu.nn.attention import merge_heads
 

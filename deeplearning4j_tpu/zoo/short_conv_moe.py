@@ -117,8 +117,9 @@ class ShortConvMoETransformer(LatentMoETransformer):
 
     def write_cells(self, pool, li, cell, page, offset):
         """pool[li, io, page, offset] = the K row or the V row, whole
-        (`page` per slot or one page, `offset` per slot or the page's
-        offsets: the advanced indices broadcast)."""
+        (`page` and `offset` per slot in the decode step; from a chunk
+        a page id a page with `offset` the whole page, or one page and
+        its offsets: the advanced indices broadcast)."""
         k, v = cell
         pool = pool.at[li, 0, page, offset].set(k.astype(pool.dtype))
         return pool.at[li, 1, page, offset].set(v.astype(pool.dtype))

@@ -117,7 +117,7 @@ def test_pallas_kernel_compiles_for_v5e(kernel, stage, one_chip,
 
 
 @pytest.mark.parametrize("program", ["decode_step_s8",
-                                     "decode_prefill_c16",
+                                     "decode_prefill_c128",
                                      "decode_page_copy"])
 @pytest.mark.parametrize("compute_dtype", [None, "bfloat16"],
                          ids=["f32", "bf16"])
@@ -126,7 +126,8 @@ def test_decode_program_compiles_for_v5e(compute_dtype, program,
     """The three programs DecodeEngine dispatches, lowered from
     `DecodeProgram.lint_records()` (the cache paths the engine itself
     uses) with the example arguments turned into shapes on the
-    described chip."""
+    described chip. The chunk is named by its length in tokens: eight
+    pages of 16, the whole window of this toy model."""
     import jax
 
     from deeplearning4j_tpu.engine.decode_program import DecodeProgram
@@ -180,7 +181,7 @@ def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
     params["layers"] = tuple({k: leaf(v) for k, v in layer.items()}
                              for layer in shapes["layers"])
     pool = sds(prog.kv_shape, prog.model.kv_dtype)
-    s, t = prog.max_slots, prog.page_size
+    s, t = prog.max_slots, prog.chunk_tokens
     i32 = jnp.int32
     zs, one = sds((s,), i32), sds((), i32)
     cases = {"copy": (prog._copy_program(), (pool, one, one))}
@@ -203,8 +204,8 @@ def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
              sds((s,), jnp.bool_)))
         cases["chunk" + tag] = (
             prog._chunk_program(p),
-            (params, *held, sds((t,), i32), one, sds((p,), i32), one,
-             *tail))
+            (params, *held, sds((t,), i32), one, sds((p,), i32),
+             sds((prog.chunk_pages,), i32), *tail))
     return prog, cases
 
 

@@ -102,19 +102,25 @@ def _drive_churn(program, reqs, stagger=2, eos=None, queue_limit=64,
 
 # ===================================================== program shapes
 def test_chunk_schedule_is_page_aligned(program):
-    """Chunked prefill replaced pow2 prefill buckets: a prompt is a
-    page-aligned chunk dispatch per uncovered page, and the prefix
-    trie's coverage (always page-aligned or total) slots in as
-    `from_token`."""
+    """Chunked prefill replaced pow2 prefill buckets, and a chunk is
+    the whole pages a token budget holds: with a window of 64 that is
+    all 8 pages, so every prompt of this fixture is ONE chunk from
+    position 0, whatever the prefix trie covered (`from_token`, always
+    page-aligned or total); `block_pages` are the pages it fills.
+    (tests/test_decode_paged.py has the schedule where a window holds
+    several chunks.)"""
+    assert (program.chunk_pages, program.chunk_tokens) == (CTX // PAGE,
+                                                           CTX)
     assert program.chunk_starts(1) == [0]
     assert program.chunk_starts(PAGE) == [0]
-    assert program.chunk_starts(PAGE + 1) == [0, PAGE]
-    assert program.chunk_starts(CTX) == list(range(0, CTX, PAGE))
-    assert program.chunk_starts(21, from_token=PAGE) == [PAGE, 2 * PAGE]
+    assert program.chunk_starts(PAGE + 1) == [0]
+    assert program.chunk_starts(CTX) == [0]
+    assert program.chunk_starts(21, from_token=PAGE) == [0]
+    assert program.block_pages(21, 0) == range(0, 3)
     for n in range(1, CTX + 1):
-        starts = program.chunk_starts(n)
-        assert all(s % PAGE == 0 for s in starts)
-        assert starts[-1] < n <= starts[-1] + PAGE
+        assert program.chunk_starts(n) == [0]
+        assert program.block_pages(n, 0) == range(0, -(-n // PAGE))
+        assert program.state_rows(n, 0) == n - 1
     with pytest.raises(ValueError):
         program.chunk_starts(CTX + 1)
     with pytest.raises(ValueError):
@@ -435,7 +441,8 @@ def test_decode_metrics_registered_and_emitted(program):
     """The decode metric domain, pinned like every other domain:
     dl4j_decode_active_slots, dl4j_decode_tokens_total,
     dl4j_decode_tokens_per_s, dl4j_decode_prefill_chunks_total,
-    dl4j_decode_slot_evictions_total registered; traffic emits them;
+    dl4j_decode_prefill_pages_total, dl4j_decode_slot_evictions_total
+    registered; traffic emits them;
     the fault point serving.slot_evict is registered. (PR 26 took
     dl4j_decode_prefill_seconds away: it timed an asynchronous
     dispatch; chunks are counted, and their device time is the device
@@ -443,6 +450,7 @@ def test_decode_metrics_registered_and_emitted(program):
     names = {"dl4j_decode_active_slots", "dl4j_decode_tokens_total",
              "dl4j_decode_tokens_per_s",
              "dl4j_decode_prefill_chunks_total",
+             "dl4j_decode_prefill_pages_total",
              "dl4j_decode_slot_evictions_total"}
     assert "dl4j_decode_prefill_seconds" not in REGISTERED_METRICS
     assert names <= set(REGISTERED_METRICS)
@@ -451,6 +459,7 @@ def test_decode_metrics_registered_and_emitted(program):
     tokens_before = reg.counter_value("dl4j_decode_tokens_total")
     chunks_before = reg.counter_value(
         "dl4j_decode_prefill_chunks_total")
+    pages_before = reg.counter_value("dl4j_decode_prefill_pages_total")
     evicts_before = reg.counter_value(
         "dl4j_decode_slot_evictions_total")
     reqs = _requests(4, seed=6)
@@ -463,6 +472,9 @@ def test_decode_metrics_registered_and_emitted(program):
         == evicts_before + 1
     assert reg.counter_value("dl4j_decode_prefill_chunks_total") \
         == chunks_before + eng.stats()["prefill_chunks"]
+    assert reg.counter_value("dl4j_decode_prefill_pages_total") \
+        == pages_before + eng.stats()["prefill_pages"]
+    assert eng.stats()["prefill_pages"] >= eng.stats()["prefill_chunks"]
     snap = reg.snapshot()
     assert "dl4j_decode_prefill_seconds" not in snap["histograms"]
     gauges = snap["gauges"]
@@ -484,10 +496,13 @@ def test_dashboard_decode_line(program):
     decode = [l for l in lines if l.startswith("decode — ")]
     assert decode == [
         "decode — 3 slots · 123.4 tok/s · 420 tokens · 2 evictions"]
-    # paged-KV extension: prefix-hit rate (trie pages vs computed
-    # chunks) and pool headroom join the line when the metrics move
+    # paged-KV extension: prefix-hit rate (trie pages vs pages the
+    # chunks filled; a chunk fills several, so its dispatches do not
+    # enter) and pool headroom join the line when the metrics move
     snapshot["counters"]["dl4j_decode_prefix_hits_total"] = {(): 30.0}
     snapshot["counters"]["dl4j_decode_prefill_chunks_total"] = {
+        (): 2.0}
+    snapshot["counters"]["dl4j_decode_prefill_pages_total"] = {
         (): 10.0}
     snapshot["gauges"]["dl4j_decode_pages_free"] = {(): 7.0}
     decode = [l for l in telemetry_lines(snapshot)

@@ -20,7 +20,14 @@ The load-bearing pins:
   * the paged metrics are registered and emitted:
     dl4j_decode_prefix_hits_total, dl4j_decode_prefix_pages_shared,
     dl4j_decode_pages_free, dl4j_decode_prefill_chunks_total,
-    dl4j_decode_ctx_wraps_total.
+    dl4j_decode_prefill_pages_total, dl4j_decode_ctx_wraps_total;
+  * a prefill chunk spans the whole pages a token budget holds
+    (`chunk_tokens`), aligned on the prompt: the schedule, the bitwise
+    contract with trie coverage that ends inside a chunk's block, a
+    pool that runs dry in the middle of one. `program`'s window (64)
+    is ONE chunk; `blocks` (512 positions, four chunks of 128) and
+    `wide` (2,048 at two pages a chunk) give a chunk a prior window
+    beside its own rows, as on the chip.
 """
 
 import random
@@ -111,10 +118,14 @@ def test_shared_prefix_divergent_tails_bitwise(program):
     assert got == oracle
     s = eng.stats()
     assert s["prefix_requests_hit"] >= 3     # every twin mapped blocks
-    # the shared blocks were computed once; only tails chunked after
-    total_chunks_unshared = sum(len(program.chunk_starts(len(p)))
-                                for p in prompts)
-    assert s["prefill_chunks"] < total_chunks_unshared
+    # the shared blocks were filled once, only tails after: a twin's
+    # chunk runs its block whole, the two shared pages' rows parked in
+    # scratch beside the pages past the prompt's end
+    assert s["prefill_chunks"] == len(prompts)
+    pages_unshared = sum(-(-len(p) // PAGE) for p in prompts)
+    assert s["prefill_pages"] == pages_unshared - s["prefix_hits"]
+    assert s["prefill_pages"] * PAGE + s["prefill_rows_padded"] \
+        == s["prefill_chunks"] * program.chunk_tokens
 
 
 def test_cow_divergence_mid_page(program):
@@ -181,10 +192,10 @@ def test_ring_wrap_vs_contiguous_window_oracle(program):
         return [logical.get(top - (top - r) % pps) for r in range(pps)]
 
     for start in big.chunk_starts(len(prompt)):
-        wp = page_for(start // ps)
-        kv = big.prefill_chunk(kv, prompt[start:start + ps], start,
-                               big.window_pages(ring_table(start),
-                                                start - 1), wp)
+        wp = [page_for(b) for b in big.block_pages(len(prompt), start)]
+        kv = big.prefill_chunk(
+            kv, prompt[start:start + big.chunk_tokens], start,
+            big.window_pages(ring_table(start), start - 1), wp)
     oracle_toks = []
     pos, tok, suppress = len(prompt) - 1, prompt[-1], True
     while len(oracle_toks) < n_new:
@@ -249,24 +260,33 @@ def _window_forward(params, tokens, n_heads, window, max_ctx):
     return norm(x, params["lnf_g"], params["lnf_b"]) @ params["tok_emb"].T
 
 
-def test_engine_matches_plain_sliding_window_forward(program):
+@pytest.mark.parametrize("fixture,n_prompt,chunks", [
+    ("program", 2 * PAGE + 5, 1),    # three pages, one chunk
+    ("blocks", 2 * 128 + 5, 3),      # 33 pages in three chunks of 16
+])
+def test_engine_matches_plain_sliding_window_forward(request, fixture,
+                                                     n_prompt, chunks):
     """Against mathematics, not against the same programs: a run that
-    prefills several chunks, decodes, and wraps the ring emits the
-    tokens a plain float32 sliding-window causal forward picks — the
-    page gather in ring order changes where a cell sits in the
-    reduction, never which cells are in it."""
+    prefills several pages (in `blocks` several chunks, each attending
+    the ones before through the gathered window), decodes, and wraps
+    the ring emits the tokens a plain float32 sliding-window causal
+    forward picks — the page gather in ring order changes where a cell
+    sits in the reduction, never which cells are in it."""
+    program = request.getfixturevalue(fixture)
+    ctx = program.window
     rng = random.Random(41)
-    prompt = [rng.randrange(VOCAB) for _ in range(2 * PAGE + 5)]
-    n_new = CTX + 2 * PAGE + 3           # wraps, and recycles 2 pages
+    prompt = [rng.randrange(VOCAB) for _ in range(n_prompt)]
+    n_new = ctx - n_prompt + 4 * PAGE + 8   # wraps, and recycles pages
     eng = DecodeEngine(program=program)
     h = eng.submit(prompt, n_new)
     toks = _drain(eng, [h])[0]
     st = eng.stats()
-    assert st["prefill_chunks"] == 3 and st["ctx_wraps"] >= 2
+    assert st["prefill_chunks"] == chunks and st["ctx_wraps"] >= 2
+    assert st["prefill_pages"] == -(-n_prompt // PAGE)
     assert len(toks) == n_new
     model = program.model
     logits = np.asarray(_window_forward(
-        model.params, prompt + toks[:-1], model.n_heads, CTX,
+        model.params, prompt + toks[:-1], model.n_heads, ctx,
         model.max_ctx))[len(prompt) - 1:]
     assert logits.shape == (n_new, VOCAB)
     # the served token's logit lies within 1e-4 of the reference's
@@ -287,15 +307,12 @@ def test_kv_page_counters_follow_a_hand_worked_schedule(program):
                        max_prefills_per_step=1)
     assert eng.stats()["kv_pages_gathered"] == 0
     assert eng.stats()["kv_pages_live"] == 0
-    # A: 10 tokens (2 chunks), 3 new. One chunk a step, so call 1
-    # prefills chunk 0 and no slot decodes; call 2 prefills chunk 1
-    # and A decodes at position 9 (2 pages live), then 10 and 11. The
-    # pages are counted as a step is dispatched, the step as it is
-    # harvested, by the call after (the engine runs one step ahead)
+    # A: 10 tokens (2 pages, one chunk), 3 new. Call 1 prefills the
+    # chunk and A decodes at position 9 (2 pages live), then 10 and
+    # 11. The pages are counted as a step is dispatched, the step as
+    # it is harvested, by the call after (the engine runs one step
+    # ahead)
     a = eng.submit(list(range(1, 11)), 3)
-    assert eng.step_once()
-    assert eng.stats()["steps"] == 0          # nothing decoded yet
-    assert eng.stats()["kv_pages_gathered"] == 0
     for k in (1, 2, 3):
         assert eng.step_once()
         st = eng.stats()
@@ -500,6 +517,7 @@ def test_paged_metrics_registered_and_emitted(program):
                  "dl4j_decode_prefix_pages_shared",
                  "dl4j_decode_pages_free",
                  "dl4j_decode_prefill_chunks_total",
+                 "dl4j_decode_prefill_pages_total",
                  "dl4j_decode_ctx_wraps_total"):
         assert name in REGISTERED_METRICS
     reg = get_registry()
@@ -511,7 +529,9 @@ def test_paged_metrics_registered_and_emitted(program):
         h2 = eng.submit(prompt, 4)          # prefix twin
         _drain(eng, [h1, h2])
         assert reg.counter_value(
-            "dl4j_decode_prefill_chunks_total") > 0
+            "dl4j_decode_prefill_chunks_total") == 1
+        assert reg.counter_value(
+            "dl4j_decode_prefill_pages_total") == 2
         assert reg.counter_value("dl4j_decode_prefix_hits_total") > 0
         assert reg.counter_value("dl4j_decode_ctx_wraps_total") > 0
         snap = reg.snapshot()
@@ -540,6 +560,31 @@ def wide():
 
 def _prompt(n, seed):
     return np.random.default_rng(seed).integers(1, VOCAB, n).tolist()
+
+
+# a window of FOUR chunks at small pages: 512 positions, a chunk of 128
+# tokens = 16 pages of 8 (`blocks`) or 32 of 4 (`blocks4`); one width
+B_CTX, B_TOKENS = 512, 128
+
+
+def _blocks(page_size):
+    model = CausalTransformer(vocab_size=VOCAB, d_model=32, n_heads=4,
+                              n_layers=2, max_ctx=B_CTX, seed=17).init()
+    prog = DecodeProgram(model, max_slots=SLOTS, page_size=page_size)
+    assert prog.chunk_tokens == B_TOKENS
+    assert prog.chunk_pages == B_TOKENS // page_size
+    prog.warmup(prog.init_kv())
+    return prog
+
+
+@pytest.fixture(scope="module")
+def blocks():
+    return _blocks(PAGE)
+
+
+@pytest.fixture(scope="module")
+def blocks4():
+    return _blocks(4)
 
 
 @pytest.mark.parametrize("max_ctx,page_size,widths", [
@@ -706,23 +751,31 @@ def test_oracle_at_a_pinned_width_is_the_engines_beside_a_longer_slot(
         wide, long_prompt, 40)[1]
 
 
-@pytest.mark.parametrize("n_prompt,n_new,chunks,steps", [
-    # chunks: {width: dispatches}; starts 0, 64, ...: a chunk with n
-    # prior pages gathers the narrowest width >= n
-    (100, 3, {8: 2}, {8: 3}),
-    # 10 chunks with 0..9 prior pages: 9 at width 8, one at 16; then
+@pytest.mark.parametrize("n_prompt,n_new,chunks,steps,pages,padded", [
+    # a chunk is two pages of 64; chunks: {width: dispatches}; starts
+    # 0, 128, ...: a chunk with n prior pages gathers the narrowest
+    # width >= n
+    # one token: a page filled, the chunk's other page to scratch
+    (1, 2, {8: 1}, {8: 2}, 1, 64),
+    (100, 3, {8: 1}, {8: 3}, 2, 0),
+    # 5 chunks with 0, 2, 4, 6, 8 prior pages, all at width 8; the
+    # last fills page 8 alone, its other page is past the prompt's end
+    (520, 2, {8: 5}, {16: 2}, 9, 64),
+    # the same five chunks, the last fills both its pages; then
     # positions 599..602 hold 10 live pages
-    (600, 4, {8: 9, 16: 1}, {16: 4}),
-    # 16 chunks, 0..15 prior: 9 at 8, 7 at 16; positions 1023 (16
+    (600, 4, {8: 5}, {16: 4}, 10, 0),
+    # 8 chunks, 0, 2, .. 14 prior: 5 at 8, 3 at 16; positions 1023 (16
     # pages) then 1024, 1025 (17)
-    (1024, 3, {8: 9, 16: 7}, {16: 1, 32: 2}),
+    (1024, 3, {8: 5, 16: 3}, {16: 1, 32: 2}, 16, 0),
 ])
 def test_chunk_page_counters_and_dispatches_by_width_add_up(
-        wide, n_prompt, n_new, chunks, steps):
+        wide, n_prompt, n_new, chunks, steps, pages, padded):
     """`chunk_pages_gathered` / `chunk_pages_live` count the prior
     context the prefill chunks read as `kv_pages_*` count the decode
-    steps', and `trace_stats()["dispatches"]` says how often each
-    width was the one chosen. Worked by hand for one request."""
+    steps', `prefill_pages` the pages the chunks filled and
+    `prefill_rows_padded` the rows they parked in scratch, and
+    `trace_stats()["dispatches"]` says how often each width was the
+    one chosen. Worked by hand for one request."""
     d0 = wide.trace_stats()["dispatches"]
     eng = DecodeEngine(program=wide, prefix_cache=False)
     st = eng.stats()
@@ -730,8 +783,12 @@ def test_chunk_page_counters_and_dispatches_by_width_add_up(
     _drain(eng, [eng.submit(_prompt(n_prompt, 3), n_new)])
     st = eng.stats()
     d1 = st["dispatches"]
-    n_chunks = -(-n_prompt // W_PAGE)
+    assert (wide.chunk_pages, wide.chunk_tokens) == (2, 2 * W_PAGE)
+    n_chunks = -(-n_prompt // wide.chunk_tokens)
     assert st["prefill_chunks"] == n_chunks == sum(chunks.values())
+    assert st["prefill_pages"] == pages == -(-n_prompt // W_PAGE)
+    assert st["prefill_rows_padded"] == padded \
+        == n_chunks * wide.chunk_tokens - pages * W_PAGE
     assert st["steps"] == n_new == sum(steps.values())
     for kind, want in (("chunk", chunks), ("step", steps)):
         by = {w: d1[f"{kind}_by_width"][w] - d0[f"{kind}_by_width"][w]
@@ -741,7 +798,8 @@ def test_chunk_page_counters_and_dispatches_by_width_add_up(
         assert d1[kind] == sum(d1[f"{kind}_by_width"].values())
     assert st["chunk_pages_gathered"] == sum(
         w * n for w, n in chunks.items())
-    assert st["chunk_pages_live"] == sum(range(n_chunks))
+    assert st["chunk_pages_live"] == sum(
+        2 * i for i in range(n_chunks))
     assert st["kv_pages_gathered"] == SLOTS * sum(
         w * n for w, n in steps.items())
     assert st["kv_pages_live"] == sum(
@@ -751,19 +809,197 @@ def test_chunk_page_counters_and_dispatches_by_width_add_up(
 
 def test_lint_records_declare_the_narrowest_and_the_widest_width(wide,
                                                                  program):
-    """One record a program where the ladder is one width, under the
-    name it always had; a ladder declares both its ends."""
+    """One record a program where the ladder is one width; a ladder
+    declares both its ends. The chunk's record is named by its length
+    in tokens (`chunk_tokens`: the window's 64 here, 128 in `wide`),
+    and takes that many tokens and a page id a page of them."""
     assert [r.name for r in program.lint_records()] == [
-        f"decode_step_s{SLOTS}", f"decode_prefill_c{PAGE}",
+        f"decode_step_s{SLOTS}", f"decode_prefill_c{CTX}",
         "decode_page_copy"]
     recs = {r.name: r for r in wide.lint_records()}
     assert sorted(recs) == sorted([
-        f"decode_step_s{SLOTS}_w8", f"decode_prefill_c{W_PAGE}_w8",
-        f"decode_step_s{SLOTS}", f"decode_prefill_c{W_PAGE}",
+        f"decode_step_s{SLOTS}_w8", "decode_prefill_c128_w8",
+        f"decode_step_s{SLOTS}", "decode_prefill_c128",
         "decode_page_copy"])
+    for name in ("decode_prefill_c128_w8", "decode_prefill_c128"):
+        assert recs[name].example_args[2].shape == (128,)
+        assert recs[name].example_args[5].shape == (2,)
     assert recs[f"decode_step_s{SLOTS}_w8"].example_args[4].shape == (
         SLOTS, 8)
     assert recs[f"decode_step_s{SLOTS}"].example_args[4].shape == (
         SLOTS, 32)
-    assert recs[f"decode_prefill_c{W_PAGE}_w8"].example_args[4].shape \
+    assert recs["decode_prefill_c128_w8"].example_args[4].shape \
         == (8,)
+
+
+# ================================== a chunk of several pages (PR 37)
+@pytest.mark.parametrize("max_ctx,page_size,chunk_pages,chunk_tokens", [
+    (64, 8, 8, 64),             # the tier-1 fixtures: the whole window
+    (B_CTX, 8, 16, 128), (B_CTX, 4, 32, 128),
+    (1024, 16, 8, 128),         # GPT-2's
+    (W_CTX, W_PAGE, 2, 128),
+    (4096, 128, 1, 128),        # the latent cells': the chunk they had
+    (256, 256, 1, 256),         # a page past the budget: one page
+    (2048, 1024, 1, 1024),
+])
+def test_chunk_starts_are_chunk_aligned_and_honour_from_token(
+        max_ctx, page_size, chunk_pages, chunk_tokens):
+    """A chunk is the whole pages `CHUNK_TOKENS` holds, at least one
+    and never past the window, and the schedule is a function of the
+    position alone: the aligned blocks that hold a token at or after
+    `from_token`, the first of them run from its aligned START even
+    where the trie's coverage ends inside it."""
+    model = CausalTransformer(vocab_size=VOCAB, d_model=8, n_heads=2,
+                              n_layers=1, max_ctx=max_ctx)
+    model.params = {}               # shapes only: nothing compiles
+    prog = DecodeProgram(model, max_slots=2, page_size=page_size)
+    assert (prog.chunk_pages, prog.chunk_tokens) == (chunk_pages,
+                                                     chunk_tokens)
+    assert chunk_tokens in prog.chunk_key()
+    b, ps = chunk_tokens, page_size
+    for n in {1, ps - 1, ps, ps + 1, b - 1, b, b + 1, 2 * b + ps + 3,
+              max_ctx - 1, max_ctx} - {0}:
+        if n > max_ctx:
+            continue
+        whole = prog.chunk_starts(n)
+        assert whole == list(range(0, n, b))
+        filled = [p for st in whole for p in prog.block_pages(n, st)]
+        assert filled == list(range(-(-n // ps)))   # each page once
+        assert sum(prog.state_rows(n, st) for st in whole) == n - 1
+        for covered in range(0, n, ps):     # the trie's: page-aligned
+            starts = prog.chunk_starts(n, from_token=covered)
+            assert starts == [st for st in whole if st + b > covered]
+            assert starts[0] <= covered < starts[0] + b
+            assert starts[0] % b == 0
+    with pytest.raises(ValueError):
+        prog.chunk_starts(max_ctx + 1)
+
+
+@pytest.mark.parametrize("n_prompt", [1, 100, B_TOKENS, B_TOKENS + 1,
+                                      300])
+@pytest.mark.parametrize("fixture", ["blocks4", "blocks"])
+def test_chunks_of_several_pages_are_the_oracles_bitwise(
+        request, fixture, n_prompt):
+    """Pages of 4 and of 8 under a chunk of 128 tokens: a prompt of one
+    token, one that ends inside a chunk's block, at its edge, a token
+    past it and in the third block, with short requests joining and
+    leaving beside it. The streams are `sequential_decode`'s, the
+    counters add up, and no chunk shape compiles after `warmup`."""
+    prog = request.getfixturevalue(fixture)
+    ps = prog.page_size
+    long_prompt = _prompt(n_prompt, n_prompt)
+    shorts = [(_prompt(5 + 9 * i, 70 + i), 3 + i) for i in range(3)]
+    reqs = [(long_prompt, 11)] + shorts
+    want = [sequential_decode(prog, p, n)[1] for p, n in reqs]
+    traces = dict(prog.trace_stats()["trace_counts"])
+    d0 = prog.trace_stats()["dispatches"]["chunk"]
+    eng = DecodeEngine(program=prog, prefix_cache=False)
+    handles, todo, steps = [], list(reqs), 0
+    while todo or any(not h.done for h in handles):
+        if todo and steps % 2 == 0:
+            handles.append(eng.submit(*todo.pop(0)))
+        eng.step_once()
+        steps += 1
+        assert steps < 500
+    assert [h.tokens_so_far() for h in handles] == want
+    st = eng.stats()
+    assert st["trace_counts"] == traces
+    assert set(traces.values()) == {1} and len(traces) == 3
+    assert st["prefill_chunks"] == sum(
+        -(-len(p) // B_TOKENS) for p, _ in reqs)
+    assert st["prefill_pages"] == sum(-(-len(p) // ps) for p, _ in reqs)
+    assert st["prefill_pages"] * ps + st["prefill_rows_padded"] \
+        == st["prefill_chunks"] * B_TOKENS
+    # a chunk is one dispatch whatever it holds
+    assert st["dispatches"]["chunk"] - d0 == st["prefill_chunks"]
+    audit = eng._pool.audit()
+    assert audit["leaked"] == 0 and not audit["double_freed"]
+
+
+@pytest.mark.parametrize("fixture", ["blocks4", "blocks"])
+def test_trie_coverage_that_ends_inside_a_block_is_bitwise(request,
+                                                           fixture):
+    """A tenant's prefix of one whole chunk and 5 pages of the next:
+    the twin maps all of them, skips the first block and runs the
+    second WHOLE from its aligned start, the 5 shared pages' rows
+    parked in scratch. Its stream is its unshared twin's bit for bit,
+    and the shared pages keep their bytes and their references."""
+    prog = request.getfixturevalue(fixture)
+    ps, cp = prog.page_size, prog.chunk_pages
+    n_shared = cp + 5
+    system = _prompt(n_shared * ps, 5)
+    first = system + _prompt(30, 6)
+    twin = system + _prompt(50, 7)
+    want = sequential_decode(prog, twin, 40)[1]
+
+    eng = DecodeEngine(program=prog)
+    _drain(eng, [eng.submit(first, 4)])
+    st0 = eng.stats()
+    assert st0["prefill_chunks"] == 2
+    shared, covered = eng._trie.match(twin)
+    assert len(shared) == n_shared and covered == n_shared * ps
+    assert covered % B_TOKENS != 0          # inside the second block
+    bytes_before = np.asarray(eng.kv[:, :, np.asarray(shared)])
+    assert [int(eng._pool.ref[p]) for p in shared] == [1] * n_shared
+
+    h = eng.submit(twin, 40)
+    eng.step_once()
+    # placed: one reference more a shared page, and ONE chunk filled
+    # the pages past them (the block's later pages lie past the
+    # prompt's end)
+    assert [int(eng._pool.ref[p]) for p in shared] == [2] * n_shared
+    st = eng.stats()
+    n_pages = -(-len(twin) // ps)
+    assert st["prefix_hits"] - st0["prefix_hits"] == n_shared
+    assert st["prefill_chunks"] - st0["prefill_chunks"] == 1
+    assert st["prefill_pages"] - st0["prefill_pages"] \
+        == n_pages - n_shared
+    assert st["prefill_rows_padded"] - st0["prefill_rows_padded"] \
+        == (cp - (n_pages - n_shared)) * ps
+    assert st["chunk_pages_live"] - st0["chunk_pages_live"] == cp
+    assert _drain(eng, [h]) == [want]
+    assert np.array_equal(
+        bytes_before, np.asarray(eng.kv[:, :, np.asarray(shared)]))
+    assert [int(eng._pool.ref[p]) for p in shared] == [1] * n_shared
+    # and with no trie at all: the same stream
+    off = DecodeEngine(program=prog, prefix_cache=False)
+    assert _drain(off, [off.submit(twin, 40)]) == [want]
+    audit = eng._pool.audit()
+    assert audit["leaked"] == 0 and not audit["double_freed"]
+
+
+def test_a_pool_dry_in_the_middle_of_a_block_resumes(blocks):
+    """The pool gives 3 of a block's 13 pages and then nothing more
+    this step: no chunk is dispatched, the 3 stay in the slot's table,
+    and the next step takes the other 10 and fills the block. No page
+    is lost or handed out twice, and the stream is the oracle's."""
+    prompt = _prompt(100, 9)                # 13 pages of the first block
+    want = sequential_decode(blocks, prompt, 9)[1]
+    eng = DecodeEngine(program=blocks, prefix_cache=False)
+    free0 = eng._pool.free_count
+    real, asked = eng._alloc_page, []
+
+    def dry_at_the_fourth(for_slot):
+        asked.append(for_slot)
+        return None if len(asked) == 4 else real(for_slot)
+
+    eng._alloc_page = dry_at_the_fourth
+    h = eng.submit(prompt, 9)
+    assert eng.step_once()                  # placed, nothing dispatched
+    st = eng.stats()
+    assert st["prefill_chunks"] == 0 and st["prefill_pages"] == 0
+    assert st["active_slots"] == 1 and eng._inflight is None
+    held = [p for p in eng._table[0] if p is not None]
+    assert len(held) == 3 and eng._pool.free_count == free0 - 3
+    assert int(eng._fill_next[0]) == 0
+    assert eng.step_once()                  # the block, tried again
+    st = eng.stats()
+    assert st["prefill_chunks"] == 1 and st["prefill_pages"] == 13
+    assert len(asked) == 4 + 10             # the 3 were not asked again
+    filled = [p for p in eng._table[0] if p is not None]
+    assert filled[:3] == held and len(set(filled)) == 13
+    assert eng._pool.free_count == free0 - 13
+    assert _drain(eng, [h]) == [want]
+    audit = eng._pool.audit()
+    assert audit["leaked"] == 0 and not audit["double_freed"]
+    assert eng._pool.free_count == free0

@@ -40,12 +40,12 @@ CFG = dict(
 GAP_TOL = 1e-4
 
 
-def _model(**kw):
+def _model(max_ctx=CTX, **kw):
     return HybridDeltaTransformer(
         layer_kinds=KINDS, vocab_size=VOCAB, hidden=32, n_heads=2,
         kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8,
         dense_ff=64, moe_ff=16, n_experts=8, top_k=2,
-        experts_held=(0, 1, 2, 5), max_ctx=CTX, kda_heads=2,
+        experts_held=(0, 1, 2, 5), max_ctx=max_ctx, kda_heads=2,
         kda_head_dim=8, gate_rank=8, routed_scale=2.446, seed=5,
         **kw).init()
 
@@ -53,6 +53,19 @@ def _model(**kw):
 @pytest.fixture(scope="module")
 def program():
     prog = DecodeProgram(_model(), max_slots=SLOTS, page_size=PAGE)
+    prog.warmup(prog.init_kv())
+    return prog
+
+
+@pytest.fixture(scope="module")
+def blocks():
+    """A window of two chunks: 256 positions in pages of 8, so a chunk
+    is 16 pages (128 tokens) and a prompt past 128 has a second chunk
+    with the first one's pages as its prior window, as on the chip.
+    `program`'s window is one chunk."""
+    prog = DecodeProgram(_model(max_ctx=256), max_slots=SLOTS,
+                         page_size=PAGE)
+    assert (prog.chunk_pages, prog.chunk_tokens) == (16, 128)
     prog.warmup(prog.init_kv())
     return prog
 
@@ -138,6 +151,32 @@ def test_prefill_then_decode_is_the_reference_forward_pass(program,
     assert eng.stats()["state_rows"] == n_prompt - 1 + 12
 
 
+@pytest.mark.parametrize("n_prompt", [127, 128, 129, 200])
+def test_chunks_of_several_pages_absorb_each_prompt_row_once(blocks,
+                                                             n_prompt):
+    """A prompt that ends inside its first chunk of 16 pages, at its
+    edge, one token past it and well into the second: the state
+    absorbs every prompt token but the last exactly once (every served
+    token is the reference's first choice, which a row absorbed twice
+    or a pad row absorbed once would leave), the second chunk starts
+    from the state the first left and attends its pages, and the
+    engine is the oracle's bitwise."""
+    prompt = np.random.default_rng(n_prompt).integers(
+        0, VOCAB, n_prompt).tolist()
+    traces = dict(blocks.trace_stats()["trace_counts"])
+    eng, (out,) = _drive(blocks, [(prompt, 6)])
+    assert out == sequential_decode(blocks, prompt, 6)[1]
+    assert _gaps(blocks, prompt, out).max() <= GAP_TOL
+    st = eng.stats()
+    assert st["trace_counts"] == traces and set(traces.values()) == {1}
+    assert st["prefill_chunks"] == -(-n_prompt // 128)
+    assert st["prefill_pages"] == -(-n_prompt // PAGE)
+    assert st["prefill_pages"] * PAGE + st["prefill_rows_padded"] \
+        == st["prefill_chunks"] * 128
+    assert st["state_resets"] == 1
+    assert st["state_rows"] == n_prompt - 1 + 6
+
+
 def test_a_token_absorbed_twice_would_show(program):
     """The witness for the test above: feed the oracle's first token
     step the prompt's last token with the chunks having absorbed it
@@ -198,8 +237,8 @@ def test_a_chunk_at_zero_resets_a_poisoned_state(program):
 
 def test_the_trie_is_off_whatever_prefix_cache_says(program):
     """A cached page would bring a prefix's rows back without the
-    state at its end: no trie is built, shared prefixes fill chunk by
-    chunk, and the streams are the oracle's."""
+    state at its end: no trie is built, shared prefixes are filled
+    page for page, and the streams are the oracle's."""
     shared = list(range(3, 3 + 2 * PAGE))
     reqs = [(shared + [7, 8, i], 5) for i in range(4)]
     eng, got = _drive(program, reqs, prefix_cache=True)
@@ -208,7 +247,7 @@ def test_the_trie_is_off_whatever_prefix_cache_says(program):
     assert st["prefix_cache"] is False
     assert st["prefix_hits"] == 0 and st["trie_blocks"] == 0
     assert st["cow_copies"] == 0
-    assert st["prefill_chunks"] == 4 * 3
+    assert st["prefill_chunks"] == 4 and st["prefill_pages"] == 4 * 3
     # a model without state keeps its trie
     from deeplearning4j_tpu.zoo.decoder import CausalTransformer
 

@@ -91,9 +91,11 @@ def paged_logits(prog, tokens, n_prompt, width=None):
     table = list(range(1, pps + 1))
     kv = prog.init_kv()
     for start in prog.chunk_starts(n_prompt):
-        kv = prog.prefill_chunk(kv, tokens[start:start + ps], start,
-                                prog.window_pages(table, start - 1, width),
-                                table[start // ps])
+        pages = prog.block_pages(n_prompt, start)
+        kv = prog.prefill_chunk(
+            kv, tokens[start:min(n_prompt, start + prog.chunk_tokens)],
+            start, prog.window_pages(table, start - 1, width),
+            table[pages.start:pages.stop])
 
     @jax.jit
     def step(params, pool, tok, pos, page_ids, wp, wo):

@@ -43,16 +43,29 @@ CFG = dict(
 LOGIT_TOL = 1e-4
 
 
-def _model(**kw):
+def _model(max_ctx=CTX, **kw):
     return ShortConvMoETransformer(
         layer_kinds=KINDS, n_kv_heads=2, head_dim=8, vocab_size=VOCAB,
         hidden=32, n_heads=4, dense_ff=64, moe_ff=16, n_experts=8, top_k=2,
-        max_ctx=CTX, seed=5, **kw).init()
+        max_ctx=max_ctx, seed=5, **kw).init()
 
 
 @pytest.fixture(scope="module")
 def program():
     prog = DecodeProgram(_model(), max_slots=SLOTS, page_size=PAGE)
+    prog.warmup(prog.init_kv())
+    return prog
+
+
+@pytest.fixture(scope="module")
+def blocks():
+    """A window of two chunks: 256 positions in pages of 8, so a chunk
+    is 16 pages (128 tokens) and a prompt past 128 has a second chunk
+    with the first one's pages as its prior window, as on the chip.
+    `program`'s window is one chunk."""
+    prog = DecodeProgram(_model(max_ctx=256), max_slots=SLOTS,
+                         page_size=PAGE)
+    assert (prog.chunk_pages, prog.chunk_tokens) == (16, 128)
     prog.warmup(prog.init_kv())
     return prog
 
@@ -94,9 +107,11 @@ def paged_logits(prog, tokens, n_prompt):
     table = list(range(1, pps + 1))
     kv, state = prog.init_kv(), prog.init_state()
     for start in prog.chunk_starts(n_prompt):
+        pages = prog.block_pages(n_prompt, start)
         kv, state = prog.prefill_chunk(
-            kv, tokens[start:start + ps], start,
-            prog.window_pages(table, start - 1), table[start // ps],
+            kv, tokens[start:start + prog.chunk_tokens], start,
+            prog.window_pages(table, start - 1),
+            table[pages.start:pages.stop],
             state=state, slot=0, n_state=prog.state_rows(n_prompt, start))
 
     @jax.jit
@@ -173,6 +188,38 @@ def test_prefill_then_decode_match_the_reference_in_logits(program,
     want = want[n_prompt - 1:len(tokens) - 1]
     assert float(np.std(want)) > 0.1
     np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+
+
+@pytest.mark.parametrize("n_prompt", [127, 128, 129, 200])
+def test_chunks_of_several_pages_absorb_each_prompt_row_once(blocks,
+                                                             n_prompt):
+    """A prompt that ends inside its first chunk of 16 pages, at its
+    edge, one token past it and well into the second: the tails absorb
+    every prompt token but the last exactly once (the logits are the
+    reference's, which a row absorbed twice or a pad row absorbed once
+    would leave), the second chunk attends the first one's pages, and
+    the engine is the oracle's bitwise."""
+    import jax.numpy as jnp
+
+    tokens = np.random.default_rng(n_prompt).integers(
+        0, VOCAB, n_prompt + 6).tolist()
+    got = paged_logits(blocks, tokens, n_prompt)
+    want = np.asarray(ref.logits_fn(
+        blocks.model.params, jnp.asarray([tokens]), CFG))[0]
+    np.testing.assert_allclose(got, want[n_prompt - 1:len(tokens) - 1],
+                               atol=LOGIT_TOL, rtol=0)
+    prompt = tokens[:n_prompt]
+    traces = dict(blocks.trace_stats()["trace_counts"])
+    eng, (out,) = _drive(blocks, [(prompt, 6)])
+    assert out == sequential_decode(blocks, prompt, 6)[1]
+    st = eng.stats()
+    assert st["trace_counts"] == traces and set(traces.values()) == {1}
+    assert st["prefill_chunks"] == -(-n_prompt // 128)
+    assert st["prefill_pages"] == -(-n_prompt // PAGE)
+    assert st["prefill_pages"] * PAGE + st["prefill_rows_padded"] \
+        == st["prefill_chunks"] * 128
+    assert st["state_resets"] == 1
+    assert st["state_rows"] == n_prompt - 1 + 6
 
 
 def test_a_wrong_tail_would_show(program):
@@ -267,8 +314,8 @@ def test_a_chunk_at_zero_resets_a_poisoned_tail(program):
 
 def test_the_trie_is_off_whatever_prefix_cache_says(program):
     """A cached page would bring a prefix's K and V rows back without
-    the tails at its end: no trie is built, shared prefixes fill chunk
-    by chunk, and the streams are the oracle's."""
+    the tails at its end: no trie is built, shared prefixes are filled
+    page for page, and the streams are the oracle's."""
     shared = list(range(3, 3 + 2 * PAGE))
     reqs = [(shared + [7, 8, i], 5) for i in range(4)]
     eng, got = _drive(program, reqs, prefix_cache=True)
@@ -277,7 +324,7 @@ def test_the_trie_is_off_whatever_prefix_cache_says(program):
     assert st["prefix_cache"] is False
     assert st["prefix_hits"] == 0 and st["trie_blocks"] == 0
     assert st["cow_copies"] == 0
-    assert st["prefill_chunks"] == 4 * 3
+    assert st["prefill_chunks"] == 4 and st["prefill_pages"] == 4 * 3
     assert st["state_resets"] == 4
 
 

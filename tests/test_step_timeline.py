@@ -192,7 +192,7 @@ def test_decode_and_chunk_programs_carry_their_scopes(program):
         texts[rec.name] = jax.jit(rec.fn).lower(
             *rec.example_args).as_text(debug_info=True)
     step = texts[f"decode_step_s{SLOTS}"]
-    chunk = texts[f"decode_prefill_c{PAGE}"]
+    chunk = texts[f"decode_prefill_c{program.chunk_tokens}"]
     for scope in ("embed", "qkv", "kv_write", "kv_read", "attn", "mlp"):
         assert _scoped(step, scope), scope
         assert _scoped(chunk, scope), scope
