@@ -59,10 +59,50 @@ _PHASE_KEYS = {p: ("dl4j_train_phase_seconds", (("phase", p),))
 # level (as `metrics.get_registry()` holds the registry) so that a
 # reader reaches it after the engine or the fit loop that wrote it is
 # gone. Two kinds of record, both plain tuples of perf_counter seconds:
-#   (owner, step, t_begin, marks, t_end)   marks: [(phase, t_start), ..]
-#   (owner, "request", step at submit, t_submit, t_placed, t_first_token)
-TIMELINE_CAPACITY = 4096
+#   (owner, step, t_begin, marks, t_end, work)
+#       marks: ((phase, t_start), ..); work: what the step's call
+#       dispatched to the device (`WORK_FIELDS`), None for a fit step
+#       and for any caller of `end_step` that passes none
+#   (owner, "request", step at submit, t_submit, t_placed, t_first_token,
+#    prompt tokens, pages the trie mapped at its placements, chunks
+#    dispatched for it)     the last three None where a caller of
+#       `record_request` passes none
+# The ring holds a run: a serving window of 51 s at a cycle of 3 ms is
+# 17,000 step records and some 4,000 request records, with the warm-up
+# and the traced slice before them. A step record with the engine's ten
+# marks and its `work` is 1.2 KB, so the full ring is 40 MB of host
+# memory (PERF.md, Findings of PR 38). A record is tuples of numbers and
+# strings all the way down, so the collector lets go of it at its first
+# pass: with the marks in a list a full collection walked every record
+# of the ring (8 ms at 30,000 records, a stalled step). What the ring
+# does push out it counts: `timeline_dropped()`.
+TIMELINE_CAPACITY = 32768
 _TIMELINE: deque = deque(maxlen=TIMELINE_CAPACITY)
+_DROPPED = [0]      # records the ring has pushed out, this process
+# The `work` of an engine step record, `DecodeEngine.step_once`'s: ints
+# and two tuples, ready-made when it reaches `end_step`.
+#   ahead       1 where a decode step was in flight when this call
+#               dispatched its own: only then does the device run them
+#               back to back
+#   width       window width in pages of the decode step dispatched,
+#               0 where the call dispatched none (a drain)
+#   rows        rows decoding in it
+#   live_pages  pages it gathers that hold a live cell
+#   copies      copy-on-write page copies dispatched
+#   chunks      one (width of the prior pages' window, pages filled,
+#               tokens run) a prefill chunk dispatched, in order
+#   earlier     the same seven fields (their own `earlier` empty) of
+#               each call since the last record that dispatched
+#               something and left no record, having harvested nothing:
+#               chunks before any row decodes, the first dispatch after
+#               a drain
+# A call's record carries the step it HARVESTED (n) and the work it
+# DISPATCHED (chunks, copies, step n+1). On the device that work runs
+# between the end of step n and the end of step n+1: from this record's
+# `harvest` mark to the next record's. What `earlier` holds ran before
+# this record's `harvest` mark, after the record before's.
+WORK_FIELDS = ("ahead", "width", "rows", "live_pages", "copies", "chunks",
+               "earlier")
 # both host clocks read back to back, once: what converts a record's
 # perf_counter seconds to the Unix time a device trace's
 # `profile_start_time` is in
@@ -72,6 +112,19 @@ CLOCK_ANCHOR = (time.perf_counter_ns(), time.time_ns())
 def get_timeline() -> deque:
     """The bounded ring of step and request records (newest last)."""
     return _TIMELINE
+
+
+def timeline_dropped() -> int:
+    """Records the ring has pushed out since the process began: a
+    reader that wants a whole run checks that this reads 0."""
+    return _DROPPED[0]
+
+
+def _push(record: tuple) -> None:
+    # a full `deque(maxlen=)` drops its oldest in silence: count first
+    if len(_TIMELINE) == _TIMELINE.maxlen:
+        _DROPPED[0] += 1
+    _TIMELINE.append(record)
 
 
 def perf_to_unix_ns(t_perf_s: float) -> int:
@@ -87,10 +140,14 @@ def phase_spans(marks, t_end: float):
 
 
 def record_request(owner: str, step_at_submit: int, t_submit: float,
-                   t_placed: float, t_first_token: float) -> None:
-    """One record per request, written at its first token."""
-    _TIMELINE.append((owner, "request", step_at_submit, t_submit,
-                      t_placed, t_first_token))
+                   t_placed: float, t_first_token: float,
+                   prompt_tokens: Optional[int] = None,
+                   pages_mapped: Optional[int] = None,
+                   chunks: Optional[int] = None) -> None:
+    """One record per request, written at its first token: its three
+    clocks, and what the time to that token was made of."""
+    _push((owner, "request", step_at_submit, t_submit, t_placed,
+           t_first_token, prompt_tokens, pages_mapped, chunks))
 
 
 class StepPhaseProfiler:
@@ -172,9 +229,11 @@ class StepPhaseProfiler:
         except Exception:   # noqa: BLE001 - profiling is best-effort
             pass
 
-    def end_step(self, step=None) -> None:
+    def end_step(self, step=None, work=None) -> None:
         """The step ends now. `step` names it where `begin_step` could
-        not yet (the engine counts a step only once it has run)."""
+        not yet (the engine counts a step only once it has run);
+        `work` is the record's last field as the caller made it
+        (`WORK_FIELDS`)."""
         if self._t_begin is None:
             return
         t_end = time.perf_counter()
@@ -189,7 +248,7 @@ class StepPhaseProfiler:
                 totals[prev] += t - t_prev
                 prev, t_prev = ph, t
             totals[prev] += t_end - t_prev
-        _TIMELINE.append((self.owner, step, self._t_begin, marks, t_end))
+        _push((self.owner, step, self._t_begin, tuple(marks), t_end, work))
         if self.emit_metrics:
             self._emit(marks, t_end)
         tr = self.tracer
@@ -239,14 +298,6 @@ class StepPhaseProfiler:
             else 0.0,
             "phases": phases,
         }
-
-    def top_phases(self, n: int = 2) -> List[Tuple[str, float]]:
-        """The n largest phases by share — the dashboard line's view."""
-        attributed = sum(self.totals.values())
-        if attributed <= 0.0:
-            return []
-        ranked = sorted(self.totals.items(), key=lambda kv: -kv[1])
-        return [(p, s / attributed) for p, s in ranked[:n] if s > 0.0]
 
 
 # --------------------------------------------- cross-rank aggregation
@@ -331,8 +382,9 @@ def aggregate_prometheus_text(sources) -> str:
 
 
 __all__ = [
-    "PHASES", "StepPhaseProfiler", "TIMELINE_CAPACITY",
-    "get_timeline", "perf_to_unix_ns", "phase_spans", "record_request",
+    "PHASES", "StepPhaseProfiler", "TIMELINE_CAPACITY", "WORK_FIELDS",
+    "get_timeline", "timeline_dropped", "perf_to_unix_ns", "phase_spans",
+    "record_request",
     "dump_snapshot", "aggregate_snapshots", "aggregate_prometheus_text",
     "render_prometheus",
 ]

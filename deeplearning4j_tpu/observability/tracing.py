@@ -50,6 +50,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import statistics
 import threading
 import time
 import uuid
@@ -463,15 +464,16 @@ def merge_chrome_traces(docs, path: Optional[str] = None,
 def _phase_ends(source, phase: str) -> List[float]:
     """When each `phase` ended, in perf_counter nanoseconds and in
     order: from step records of `observability.perf.get_timeline()`
-    (`(owner, step, t_begin, marks, t_end)`), or from a Tracer's
-    `phase:<name>` spans."""
+    (`(owner, step, t_begin, marks, t_end, work)`; a record of five
+    fields, as they were before `work`, reads alike), or from a
+    Tracer's `phase:<name>` spans."""
     if isinstance(source, Tracer):
         ends = [(source._t0 + (s["t0_us"] + s["dur_us"]) * 1e-6) * 1e9
                 for s in source.spans()
                 if s["name"] == f"phase:{phase}"]
         return sorted(ends)
-    return [t1 * 1e9 for _, _, _, marks, t_end in source
-            for name, _, t1 in phase_spans(marks, t_end) if name == phase]
+    return [t1 * 1e9 for r in source
+            for name, _, t1 in phase_spans(r[3], r[4]) if name == phase]
 
 
 def clock_offset(source, executions, phase: str = "fetch") -> dict:
@@ -494,16 +496,30 @@ def clock_offset(source, executions, phase: str = "fetch") -> dict:
     millisecond the two timelines are one, wider and a reader must say
     so in place of a join it cannot stand behind.
 
-    Returns {"offset_ns", "spread_ns", "n"}; `n` 0 where either side
-    is empty. host_ns = device_ns + offset_ns."""
+    With a step always in flight (`DecodeEngine.step_once`) the
+    profiler's start or stop cuts the execution it finds running, so
+    an execution shorter than half the median is left out before the
+    pairing: the trace's last WHOLE execution is then the slice's last
+    record's. No measured join needed another pairing (PERF.md, PR
+    38), so none is searched for: `shift`, the whole executions the
+    trace is taken to have past the slice's last record, is always 0.
+    A trace that does run on by a whole execution reads as a wide
+    spread where its turns differ, and a turn off where they are alike.
+
+    Returns {"offset_ns", "spread_ns", "n", "shift"}; `n` 0 where
+    either side is empty. host_ns = device_ns + offset_ns."""
     ends = _phase_ends(source, phase)
+    executions = list(executions)
+    if executions:
+        half = statistics.median(e - s for s, e in executions) / 2.0
+        executions = [(s, e) for s, e in executions if e - s >= half]
     n = min(len(ends), len(executions))
     if not n:
-        return {"offset_ns": 0.0, "spread_ns": float("inf"), "n": 0}
+        return {"offset_ns": 0.0, "spread_ns": float("inf"), "n": 0,
+                "shift": 0}
     diffs = sorted(h - d[1] for h, d in zip(ends[-n:], executions[-n:]))
-    mid = diffs[n // 2] if n % 2 else (diffs[n // 2 - 1]
-                                        + diffs[n // 2]) / 2.0
-    return {"offset_ns": mid, "spread_ns": diffs[-1] - diffs[0], "n": n}
+    return {"offset_ns": statistics.median(diffs),
+            "spread_ns": diffs[-1] - diffs[0], "n": n, "shift": 0}
 
 
 def phases_over(records, intervals, offset_ns: float) -> Dict[str, float]:
@@ -512,8 +528,8 @@ def phases_over(records, intervals, offset_ns: float) -> Dict[str, float]:
     after `clock_offset`. Returns seconds by phase name; what no
     record covers is under `"(no record)"`."""
     # (start_ns, end_ns, phase) on the host's clock
-    spans = [(t0 * 1e9, t1 * 1e9, name) for _, _, _, marks, t_end in records
-             for name, t0, t1 in phase_spans(marks, t_end)]
+    spans = [(t0 * 1e9, t1 * 1e9, name) for r in records
+             for name, t0, t1 in phase_spans(r[3], r[4])]
     out: Dict[str, float] = {}
     for lo, hi in intervals:
         lo, hi = lo + offset_ns, hi + offset_ns

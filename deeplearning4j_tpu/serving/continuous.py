@@ -253,6 +253,12 @@ class GenerationHandle:
         # the engine's step count when the request was submitted (set
         # by `submit`): what places its timeline record among the steps
         self.step_submit = 0
+        # what the time to its first token was made of, for its
+        # timeline record: pages the trie mapped at its placements and
+        # chunks dispatched for it (a re-placement before the first
+        # token adds to both, as it adds to the time)
+        self.pages_mapped = 0
+        self.chunks = 0
         # root span of this leg's span tree (engine-owned; None when
         # the engine has no tracer — the default-off zero-cost path)
         self._span = None
@@ -676,6 +682,12 @@ class DecodeEngine:
         # the resident of its dispatch and to nobody who came after
         self._slot_gen = np.zeros(s, np.int64)
         self._steps_ahead = 0          # steps dispatched over an unfetched one
+        # what the calls of `step_once` dispatch, for the step records
+        # (`observability.perf.WORK_FIELDS`): this call's chunks as
+        # `_advance_fill` dispatches them, and the work of the calls
+        # since the last record that left none
+        self._work_chunks: List[Tuple[int, int, int]] = []
+        self._work_earlier: List[tuple] = []
         self._rows_discarded = 0       # rows whose resident had left
         # pending entries: (handle, replay_tokens or None)
         self._pending: deque = deque()
@@ -1220,7 +1232,8 @@ class DecodeEngine:
             elif kind == "ttft":
                 dt = b - handle.t_submit
                 record_request(self._phases.owner, handle.step_submit,
-                               handle.t_submit, a, b)
+                               handle.t_submit, a, b, len(handle.prompt),
+                               handle.pages_mapped, handle.chunks)
                 self._ttft_ring.append(dt)
                 _obs.observe("dl4j_decode_ttft_seconds", dt,
                              labels={"tenant": tenant})
@@ -1324,7 +1337,16 @@ class DecodeEngine:
         row to dispatch fetches and harvests the step in flight (the
         drain), and where nothing is in flight the order is the old
         one, a call late. The step record of a call carries the number
-        of the step it harvests.
+        of the step it HARVESTS (n) and, as its `work`
+        (`observability.perf.WORK_FIELDS`), what the call DISPATCHED:
+        its chunks, its copy-on-write copies and step n+1, built from
+        what the call has in hand (no fetch, no clock read). The device
+        runs that work between the end of step n and the end of step
+        n+1, so with a step in flight (`ahead`) the time from this
+        record's `harvest` mark to the next record's is the device's
+        time for exactly this record's `work`. A call that harvests
+        nothing leaves no record; what it dispatched rides in the next
+        record's `earlier` and ran before that record's `harvest`.
 
         Telemetry (fault points aside, counters, gauges) fires OUTSIDE
         the step lock — emission is never a blocking op under a
@@ -1340,6 +1362,8 @@ class DecodeEngine:
         quar_before = self._quarantines
         replays_before = self._replays
         chunks_before = self._prefill_chunks
+        copies_before = self._cow_copies
+        live_before = self._kv_pages_live
         filled_before = self._prefill_pages
         hits_before = self._prefix_page_hits
         wraps_before = self._ctx_wraps
@@ -1357,6 +1381,7 @@ class DecodeEngine:
             self._prepare_write_cells()
             decoding = self._decoding()
             flight, self._inflight = self._inflight, None
+            width = rows = 0
             if decoding.any():
                 pp.mark("tables")
                 page_ids, wp, wo = self._step_tables(decoding)
@@ -1364,6 +1389,18 @@ class DecodeEngine:
                 self._inflight = self._dispatch(decoding, page_ids, wp,
                                                 wo)
                 self._steps_ahead += flight is not None
+                width = page_ids.shape[1]
+                rows = int(np.count_nonzero(decoding))
+            chunked, self._work_chunks = tuple(self._work_chunks), []
+            work = (int(width > 0 and flight is not None), width, rows,
+                    self._kv_pages_live - live_before,
+                    self._cow_copies - copies_before, chunked)
+            if flight is not None:
+                work += (tuple(self._work_earlier),)
+                self._work_earlier = []
+            elif width or work[4] or chunked:
+                # no harvest, no record: the next record carries it
+                self._work_earlier.append(work + ((),))
             emitted = 0
             if flight is not None:
                 pp.mark("fetch")    # the host blocked on the device
@@ -1433,8 +1470,9 @@ class DecodeEngine:
             # one record an engine step, under the number of the step
             # this call harvested; a call that harvested none (idle,
             # chunks only, or the first dispatch after a drain) is left
-            # to the next record's `between_steps`
-            pp.end_step(step=self._steps)
+            # to the next record's `between_steps`, its work to the
+            # next record's `earlier`
+            pp.end_step(step=self._steps, work=work)
         return bool(flight is not None or self._inflight is not None
                     or admitted or chunks or evicted or n_deadline
                     or n_cancel)
@@ -1608,6 +1646,7 @@ class DecodeEngine:
             if pages:
                 self._prefix_hits += 1
                 self._prefix_page_hits += len(pages)
+                handle.pages_mapped += len(pages)
         if covered >= len(handle.prompt):
             self._fill_next[slot] = -1
             self._fill_done(slot)
@@ -1672,6 +1711,8 @@ class DecodeEngine:
         self._prefill_rows_padded += (program.chunk_pages - filled) * ps
         self._chunk_pages_gathered += page_ids.size
         self._chunk_pages_live += start // ps
+        self._work_chunks.append((page_ids.size, filled, len(chunk)))
+        handle.chunks += 1
         if self.tracer is not None:
             self._lat.append(("chunk", handle, t0,
                               time.perf_counter()))
