@@ -20,28 +20,39 @@ every expert held the result is the model's own layer and no partial
 sum), and `norm_eps` is the epsilon some routers put under the
 renormalisation (0: none, and the program that was traced without it).
 
+One product (nn/helpers/pallas_moe.py): a kernel reads only the held
+experts on the hit list, those some row chose (in the decode step,
+some active row: an inactive row's routed part is 0), adds them in
+ascending id order, and a row adds an exact 0 (a `where`) for a
+listed expert it did not choose. Where every held expert is hit it
+takes the time of einsums over every held expert to 1.7% at the
+benchmark cells' shapes, and less for every expert it skips (PERF.md,
+PR 39), so there is no second product to choose.
+
 Per-row independence (the property the decode oracle's byte identity
 rests on, engine/decode_program.py): no token is dropped and no
-capacity is shared. Every held expert runs over every row and a row's
-weight for an expert it did not choose is exactly 0, so a row's result
-is a function of that row alone, whatever the other rows route to.
+capacity is shared, and a row's result is a function of that row
+alone, whatever the other rows route to: the other rows decide only
+which zeros are added, so the engine's row and the oracle's agree bit
+for bit though their lists differ.
 With 16 experts of 94 MB held and 32 rows a step, the experts' weights
-are what a step moves; the rows a skinny product wastes cost nothing
-beside them. With all 64 experts of 28 MB held and 128 rows a step
-(benchmark cell `lfm2-moe-chat-closed128`) the same product is 16 times
-the operations the routing asked for, 1.24 TFLOP a step where 0.077 are
-needed, beside 9.66 GB of weights: the baseline a grouped product over
-the experts hit (ROADMAP S6 / R11) will be judged on.
+are what a step moves; the hit list reads the 9-10 of a layer that a
+step's rows reach. With all 64 experts of 28 MB held and 128 rows a
+step (benchmark cell `lfm2-moe-chat-closed128`: 8 rows an expert at
+the mean, four fifths of the experts hit) the weights are still what a
+step moves, and a listed expert runs over every row, 16 times the
+operations the routing asked for (ROADMAP R11).
 """
 
 from __future__ import annotations
 
 # over the active rows of a step, summed over the expert layers: the
 # token-expert pairs routed (all experts), those that fell on a held
-# expert, the most loaded held expert's, and the held experts that got
-# at least one
+# expert, the most loaded held expert's, the held experts that got at
+# least one, and the held experts whose weights the layer read (the
+# kernel's hit list: those hit)
 COUNTERS = ("moe_assignments", "moe_assignments_held",
-            "moe_max_held_load", "moe_experts_hit")
+            "moe_max_held_load", "moe_experts_hit", "moe_experts_read")
 
 
 def route(x, router_w, top_k: int, scale: float, bias=None,
@@ -96,34 +107,32 @@ def expert_layer(lp: dict, x, held, top_k: int, scale: float,
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.nn.attention import gated_mlp
+    from deeplearning4j_tpu.nn.helpers.pallas_moe import (
+        grouped_experts,
+        hit_list,
+    )
 
     with jax.named_scope("moe/router"):
         top_i, top_w = route(x, lp["router"], top_k, scale,
                              lp.get("router_bias"), norm_eps)
         w = held_weights(top_i, top_w, held)            # [N, E]
     with jax.named_scope("moe/experts"):
-        xe = x.astype(lp["eg"].dtype)
-        f32 = jnp.float32
-        g = jnp.einsum("nh,ehf->enf", xe, lp["eg"],
-                       preferred_element_type=f32)
-        u = jnp.einsum("nh,ehf->enf", xe, lp["eu"],
-                       preferred_element_type=f32)
-        act = (jax.nn.silu(g) * u).astype(lp["ed"].dtype)
-        ye = jnp.einsum("enf,efh->enh", act, lp["ed"],
-                        preferred_element_type=f32)
-        # the weighted sum elementwise in float32: a float32 dot
-        # would round its operands to bfloat16 on the chip
-        y = jnp.sum(ye * jnp.transpose(w)[:, :, None], axis=0)
+        if active is not None:
+            # an inactive row's output goes to scratch: it adds nothing
+            w = jnp.where(active[:, None], w, 0.0)
+        ids, n_hit = hit_list(w)
+        y = grouped_experts(x.astype(lp["eg"].dtype), w, ids, n_hit,
+                            lp["eg"], lp["eu"], lp["ed"])
     if "sg" in lp:
         with jax.named_scope("moe/shared"):
             y = y + gated_mlp(x, lp["sg"], lp["su"], lp["sd"])
     if active is None:
         return y, None
     with jax.named_scope("moe/router"):
-        load = jnp.sum((w > 0) & active[:, None], axis=0,
-                       dtype=jnp.int32)                  # [E]
+        load = jnp.sum(w > 0, axis=0, dtype=jnp.int32)  # [E]
         counts = jnp.stack([
             jnp.sum(active, dtype=jnp.int32) * top_k,
             jnp.sum(load), jnp.max(load),
-            jnp.sum(load > 0, dtype=jnp.int32)])
+            jnp.sum(load > 0, dtype=jnp.int32),
+            n_hit[0]])
     return y, counts

@@ -48,6 +48,11 @@ def topo():
     except Exception as e:   # noqa: BLE001 - any failure means: skip
         env.undo()
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # jax.default_backend() is "cpu" here, so the expert layer's kernel
+    # would pick interpret mode; the chip's compiler must get Mosaic
+    from deeplearning4j_tpu.nn.helpers import pallas_moe
+
+    env.setattr(pallas_moe, "_interpret", lambda: False)
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -249,9 +254,12 @@ def test_latent_cell_compiles_for_v5e_with_no_copy_of_the_pool(
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 1.0e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
-    layouts = set(re.findall(
-        r"bf16\[5,1025,128,640\]\{([0-9,]+):", compiled.as_text()))
+    text = compiled.as_text()
+    layouts = set(re.findall(r"bf16\[5,1025,128,640\]\{([0-9,]+):", text))
     assert layouts == {"3,2,1,0"}
+    # the step and the chunk read their experts through the hit list's
+    # kernel (nn/helpers/pallas_moe.py) at the chip's VMEM
+    assert ("tpu_custom_call" in text) == (program != "copy")
 
 
 @pytest.fixture(scope="module")
@@ -361,6 +369,8 @@ def test_hybrid_cell_compiles_for_v5e_with_pool_and_state_in_place(
     state_bytes = sum(int(np.prod(v)) * 4 for v in shapes.values())
     assert mem.temp_size_in_bytes < 1.0e9
     text = compiled.as_text()
+    # the step and the chunk through the hit list's kernel
+    assert ("tpu_custom_call" in text) == (program != "copy")
     if program == "copy":
         assert mem.alias_size_in_bytes >= pool_bytes
         return
@@ -412,6 +422,8 @@ def test_conv_cell_compiles_for_v5e_with_pool_and_tails_in_place(
     assert tails == (7, 128, 2, 2048)
     pool_bytes = int(np.prod(prog.kv_shape)) * 2
     text = compiled.as_text()
+    # 128 rows x 4 of 64 (nearly every expert hit): the kernel too
+    assert ("tpu_custom_call" in text) == (program != "copy")
     if program == "copy":
         assert mem.alias_size_in_bytes >= pool_bytes
         return
