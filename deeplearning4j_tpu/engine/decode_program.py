@@ -99,18 +99,26 @@ nn/delta_attention.py, zoo/hybrid_delta.py). It then gives
         the state: one buffer, or a dict of them, whose axes lead
         [state layer, slot, ...] (`init_state`; None for a model that
         describes none).
-  state_step(lp, x, state, i, active) -> (x, state, counts | None)
+  state_step(lp, x, state, i, active, positions)
+        -> (x, state, counts | None)
         the i-th state layer of the decode step, its feed-forward half
         included: each `active` row's own entry advanced by the row's
-        token, every other entry left as it was. No operation mixes
-        slots, so the bitwise contract below holds for a state as it
-        does for pages; the oracle carries a state of its own.
-  state_chunk(lp, x, entry, n_state) -> (x, entry)
-        the same layer over a chunk of ONE slot: `entry` is that
-        slot's entry of the layer as the chunk found it, and comes
-        back as it stands after the chunk's first `n_state` rows. A
-        recurrence has no mask that hides a pad row, so the host says
-        how many rows are absorbed (`state_rows`).
+        token at its logical position, every other entry left as it
+        was. No operation mixes slots, so the bitwise contract below
+        holds for a state as it does for pages; the oracle carries a
+        state of its own.
+  state_chunk(lp, x, entry, n_state, positions) -> (x, entry)
+        the same layer over a chunk of ONE slot at `positions` (start
+        + 0 .. chunk_tokens - 1): `entry` is that slot's entry of the
+        layer as the chunk found it, and comes back as it stands after
+        the chunk's first `n_state` rows. A recurrence has no mask
+        that hides a pad row, so the host says how many rows are
+        absorbed (`state_rows`).
+
+  A recurrence (nn/delta_attention.py, nn/short_conv.py) takes no
+  position; a window of rows a slot (nn/window_attention.py's ring:
+  position p in cell p mod window) takes them for its rotary and its
+  mask.
 
 Both programs then take the state after the pool and DONATE it like
 the pool, and return it after it. The chunk program takes two more
@@ -217,6 +225,9 @@ class DecodeProgram:
         # the attention window: every slot attends over at most
         # max_ctx logical positions (sliding once positions wrap)
         self.window = int(model.max_ctx)
+        if self.window % self.page_size:
+            raise ValueError(f"the window of {self.window} positions is "
+                             f"not whole pages of {self.page_size}")
         self.pages_per_slot = self.window // self.page_size
         # a prefill chunk: the whole pages a budget of CHUNK_TOKENS
         # holds, never past the window
@@ -463,7 +474,7 @@ class DecodeProgram:
                     # a state layer: each active row's own entry
                     # advanced, the others left as they were
                     x, state, c = model.state_step(lp, x, state, -1 - li,
-                                                   active)
+                                                   active, positions)
                     if c is not None:
                         counts.append(c)
                     continue
@@ -569,7 +580,7 @@ class DecodeProgram:
                 if li < 0:
                     x, entry = model.state_chunk(
                         lp, x, jax.tree.map(lambda a: a[-1 - li], entries),
-                        n_state)
+                        n_state, positions)
                     after.append(entry)
                     continue
                 # project + PARK the chunk's cells before gathering the

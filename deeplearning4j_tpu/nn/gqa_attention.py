@@ -2,7 +2,12 @@
 query heads on `n_kv` key/value heads, query head h reading K/V head
 h // (n_heads / n_kv), with a norm a head on q and k and rotary
 positions on the whole head (LFM2's and LFM2-MoE's `full_attention`
-layers).
+layers). What other published layers of this kind differ in is data:
+no norm a head where the layer has no `q_norm`, a partial or YaRN-scaled
+rotation (`rotary`'s `inv`, `factor`), and a sigmoid gate a head on the
+output from the layer's normed input (`gated_project`, `gate`:
+Laguna's `full_attention` and `sliding_attention` layers, the latter
+over nn/window_attention.py's ring).
 
 The cache is nn/attention.py's: one row of n_kv * head_dim numbers a
 cached position for K and one for V, pages flattened in ring order,
@@ -41,20 +46,52 @@ from deeplearning4j_tpu.nn.latent_attention import rotary
 
 
 def project(lp: dict, x, positions, n_heads: int, n_kv: int, theta: float,
-            eps: float):
+            eps: float, inv=None, factor: float = 1.0):
     """x [N, h] -> (q [N, n_heads, D], (k_row, v_row) [N, n_kv * D]
     each): the stream through the layer's norm and the three
-    projections, q and k through their norm a head (a gain of D) and
-    rotated by `positions`; k and v as the rows the pool stores."""
+    projections, q and k through their norm a head (a gain of D) where
+    the layer has one (`q_norm`, `k_norm`) and rotated by `positions`
+    (`inv` and `factor` are `rotary`'s: a partial or scaled rotation);
+    k and v as the rows the pool stores."""
+    return _project(lp, rms_norm(x, lp["norm_in"], eps), positions,
+                    n_heads, n_kv, theta, eps, inv, factor)
+
+
+def gated_project(lp: dict, x, positions, n_heads: int, n_kv: int,
+                  theta: float, eps: float, inv=None, factor: float = 1.0):
+    """`project` of a layer whose heads' outputs are gated: (q, gate
+    logits [N, n_heads] float32 from the same normed input through
+    `attn_gate` [h, n_heads], (k_row, v_row))."""
+    u = rms_norm(x, lp["norm_in"], eps)
+    q, cell = _project(lp, u, positions, n_heads, n_kv, theta, eps, inv,
+                       factor)
+    return q, mm(u, lp["attn_gate"]), cell
+
+
+def _project(lp, u, positions, n_heads, n_kv, theta, eps, inv, factor):
     import jax.numpy as jnp
 
-    u = rms_norm(x, lp["norm_in"], eps)
-    n = x.shape[0]
+    n = u.shape[0]
     q = jnp.reshape(mm(u, lp["wq"]), (n, n_heads, -1))
     k = jnp.reshape(mm(u, lp["wk"]), (n, n_kv, -1))
-    q = rotary(rms_norm(q, lp["q_norm"], eps), positions, theta)
-    k = rotary(rms_norm(k, lp["k_norm"], eps), positions, theta)
+
+    def norm(a, gain):
+        return rms_norm(a, lp[gain], eps) if gain in lp else a
+
+    q = rotary(norm(q, "q_norm"), positions, theta, inv, factor)
+    k = rotary(norm(k, "k_norm"), positions, theta, inv, factor)
     return q, (merge_heads(k), mm(u, lp["wv"]))
+
+
+def gate(att, logits):
+    """att [N, H * D] (heads merged) times sigmoid(logits) [N, H], each
+    head's D numbers by its own gate, float32."""
+    import jax
+    import jax.numpy as jnp
+
+    n, h = logits.shape
+    heads = jnp.reshape(att, (n, h, -1)) * jax.nn.sigmoid(logits)[..., None]
+    return merge_heads(heads)
 
 
 def _group_blocks(n_heads: int, n_kv: int, head_dim: int, dtype):

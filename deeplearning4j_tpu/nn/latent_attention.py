@@ -35,22 +35,62 @@ from __future__ import annotations
 from deeplearning4j_tpu.nn.attention import MASK_VALUE, _softmax, mm, rms_norm
 
 
-def rotary(x, positions, theta: float):
+def rotary(x, positions, theta: float, inv=None, factor: float = 1.0):
     """Rotate the trailing axis of `x` [N, .., d] by `positions` [N]:
     half-split pairing (i with i + d/2), angle pos * theta^(-2i/d),
     float32. Positions are logical and unbounded: only differences
-    reach a score."""
+    reach a score.
+
+    A scaled or partial rotation is data: `inv` [r/2] (host numbers,
+    such as `yarn_inverse_frequencies`) in place of theta's turns the
+    first r lanes alone, i paired with i + r/2, and passes the others
+    as they are; `factor` multiplies cos and sin (YaRN's attention
+    factor). With neither the program is the one it always was."""
     import jax.numpy as jnp
 
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv is None:
+        half = x.shape[-1] // 2
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        half = len(inv)
+        inv = jnp.asarray(inv, jnp.float32)
     ang = positions.astype(jnp.float32)[:, None] * inv        # [N, d/2]
     ang = jnp.reshape(ang, ang.shape[:1] + (1,) * (x.ndim - 2)
                       + ang.shape[1:])
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    out = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if 2 * half < x.shape[-1]:
+        out.append(x[..., 2 * half:])
+    return jnp.concatenate(out, axis=-1)
+
+
+def yarn_inverse_frequencies(dim: int, theta: float, factor: float,
+                             original: int, beta_fast: float,
+                             beta_slow: float):
+    """YaRN's inverse frequencies (numpy, [dim/2]) for a rotation over
+    `dim` lanes: theta's own below the band of dimensions that turn
+    fewer than `beta_slow` times over `original` positions, theta's
+    divided by `factor` above the band that turns more than
+    `beta_fast` times, a linear ramp between (Hugging Face
+    `_compute_yarn_parameters`, bounds truncated to whole dimensions)."""
+    import math
+
+    import numpy as np
+
+    def dim_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    base = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    keep = 1.0 - np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    return (1.0 / (factor * base)) * (1.0 - keep) + (1.0 / base) * keep
 
 
 def row_width(rank: int, d_rope: int) -> int:

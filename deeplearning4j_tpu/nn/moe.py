@@ -17,8 +17,11 @@ have added is simply not in the result.
 What varies between published layers of this kind is data: the shared
 expert is computed where the layer has one (`"sg" in lp`; with none and
 every expert held the result is the model's own layer and no partial
-sum), and `norm_eps` is the epsilon some routers put under the
-renormalisation (0: none, and the program that was traced without it).
+sum), `norm_eps` is the epsilon some routers put under the
+renormalisation (0: none, and the program that was traced without it),
+and `score` is the squashing of the router's logits: "sigmoid" as
+above, or "softmax" over all experts (Laguna's router: s = softmax(x
+W_g), the rest as written).
 
 One product (nn/helpers/pallas_moe.py): a kernel reads only the held
 experts on the hit list, those some row chose (in the decode step,
@@ -56,20 +59,28 @@ COUNTERS = ("moe_assignments", "moe_assignments_held",
 
 
 def route(x, router_w, top_k: int, scale: float, bias=None,
-          norm_eps: float = 0.0):
+          norm_eps: float = 0.0, score: str = "sigmoid"):
     """(expert ids [.., k], weights [.., k]) of each row: sigmoid
     scores over all experts in float32 at the highest matmul precision
     (2M parameters: nothing beside the experts, and a near-tie between
     the k-th and the next expert should turn on the stream's rounding,
     not on the router's own), the k largest, renormalised and scaled.
-    A `bias` [n_experts] moves the choice only: the k largest of
-    `scores + bias` are kept, weighted by their own scores. `norm_eps`
-    is added to the renormalisation's denominator where it is not 0
-    (a Python number: at 0 the traced program has no such add)."""
+    `score="softmax"` takes a softmax over all experts' logits in place
+    of the sigmoid (a Python string: the sigmoid's program is the one
+    it always was). A `bias` [n_experts] moves the choice only: the k
+    largest of `scores + bias` are kept, weighted by their own scores.
+    `norm_eps` is added to the renormalisation's denominator where it
+    is not 0 (a Python number: at 0 the traced program has no such
+    add)."""
     import jax
     import jax.numpy as jnp
 
-    scores = jax.nn.sigmoid(jnp.matmul(
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"router scores are 'sigmoid' or 'softmax': "
+                         f"{score!r}")
+    squash = jax.nn.sigmoid if score == "sigmoid" \
+        else lambda a: jax.nn.softmax(a, axis=-1)
+    scores = squash(jnp.matmul(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     if bias is None:
@@ -95,14 +106,14 @@ def held_weights(top_i, top_w, held):
 
 
 def expert_layer(lp: dict, x, held, top_k: int, scale: float,
-                 active=None, norm_eps: float = 0.0):
+                 active=None, norm_eps: float = 0.0, score: str = "sigmoid"):
     """x [N, h] (normed, float32) -> (y [N, h], counts). `lp` has the
     router `router` [h, n_experts] (and, where it has one, its
     selection bias `router_bias`), the held experts stacked in the
     order of `held` (`eg`, `eu` [E, h, f]; `ed` [E, f, h]) and, where
     the layer has one, the shared expert (`sg`, `su`, `sd`). `counts`
     is the int32 vector of COUNTERS over the rows `active` marks (None:
-    no counts); `norm_eps` is `route`'s."""
+    no counts); `norm_eps` and `score` are `route`'s."""
     import jax
     import jax.numpy as jnp
 
@@ -114,7 +125,7 @@ def expert_layer(lp: dict, x, held, top_k: int, scale: float,
 
     with jax.named_scope("moe/router"):
         top_i, top_w = route(x, lp["router"], top_k, scale,
-                             lp.get("router_bias"), norm_eps)
+                             lp.get("router_bias"), norm_eps, score)
         w = held_weights(top_i, top_w, held)            # [N, E]
     with jax.named_scope("moe/experts"):
         if active is not None:

@@ -17,6 +17,9 @@ from deeplearning4j_tpu.zoo.latent_moe import (  # noqa: F401
 from deeplearning4j_tpu.zoo.short_conv_moe import (  # noqa: F401
     ShortConvMoETransformer,
 )
+from deeplearning4j_tpu.zoo.window_moe import (  # noqa: F401
+    WindowMoETransformer,
+)
 from deeplearning4j_tpu.zoo.models import (  # noqa: F401
     AlexNet,
     FaceNetNN4Small2,

@@ -87,7 +87,8 @@ class HybridDeltaTransformer(LatentMoETransformer):
                             self.kda_heads, self.kda_head_dim,
                             self.conv_kernel)
 
-    def state_step(self, lp, x, state, si, active):
+    def state_step(self, lp, x, state, si, active, positions):
+        del positions               # a recurrence has no position
         from deeplearning4j_tpu.nn.delta_attention import decode_mix
 
         out, state = decode_mix(lp, x, state, si, active, self.kda_heads,
@@ -95,7 +96,8 @@ class HybridDeltaTransformer(LatentMoETransformer):
         x, counts = self._ffn(lp, x + out, active)
         return x, state, counts
 
-    def state_chunk(self, lp, x, entry, n_state):
+    def state_chunk(self, lp, x, entry, n_state, positions):
+        del positions
         from deeplearning4j_tpu.nn.delta_attention import chunk_mix
 
         out, entry = chunk_mix(lp, x, entry, n_state, self.kda_heads,

@@ -14,7 +14,7 @@ query with no bottleneck (`q_lora_rank=None`: one matrix `wq`), keys
 with no rotation (`rope_theta=None`), norms before each sublayer only
 (`sandwich_norm=False`), a router with a selection bias
 (`router_bias=True`) or an epsilon under its renormalisation
-(`route_eps`), an expert layer with no shared expert (`n_shared=0`: the
+(`route_eps`) or softmax scores (`route_score="softmax"`), an expert layer with no shared expert (`n_shared=0`: the
 layer then has no `sg`/`su`/`sd` leaves and nn/moe.py adds none), a
 head tied to the embedding (`tie_embeddings=True`: no `head` leaf).
 zoo/hybrid_delta.py puts linear-attention layers with a per-slot state
@@ -62,9 +62,8 @@ class LatentMoETransformer:
                  eps: float = 1e-5, seed: int = 123,
                  param_dtype: str = "float32",
                  sandwich_norm: bool = True, router_bias: bool = False,
-                 route_eps: float = 0.0, tie_embeddings: bool = False):
-        if max_ctx & (max_ctx - 1):
-            raise ValueError(f"max_ctx must be a power of two: {max_ctx}")
+                 route_eps: float = 0.0, tie_embeddings: bool = False,
+                 route_score: str = "sigmoid"):
         if qk_rope_dim % 2:
             raise ValueError(f"rotary pairs need an even qk_rope_dim: "
                              f"{qk_rope_dim}")
@@ -95,6 +94,7 @@ class LatentMoETransformer:
         self.sandwich_norm = bool(sandwich_norm)
         self.router_bias = bool(router_bias)
         self.route_eps = float(route_eps)
+        self.route_score = str(route_score)
         self.tie_embeddings = bool(tie_embeddings)
         self.seed = int(seed)
         # matrices, embedding and page pool; "float32" or "bfloat16"
@@ -296,7 +296,7 @@ class LatentMoETransformer:
             y, counts = expert_layer(
                 lp, rms_norm(x, lp["norm_pre_mlp"], self.eps),
                 self.experts_held, self.top_k, self.routed_scale, active,
-                self.route_eps)
+                self.route_eps, self.route_score)
             with jax.named_scope("moe/shared"):
                 x = x + self._post(lp, "norm_post_mlp", y)
         else:

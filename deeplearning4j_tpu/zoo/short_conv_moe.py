@@ -157,14 +157,16 @@ class ShortConvMoETransformer(LatentMoETransformer):
                                       self.n_kv_heads)
         return self._finish(lp, x, att, None)[0]
 
-    def state_step(self, lp, x, state, si, active):
+    def state_step(self, lp, x, state, si, active, positions):
+        del positions               # a recurrence has no position
         from deeplearning4j_tpu.nn.short_conv import decode_mix
 
         out, state = decode_mix(lp, x, state, si, active, self.eps)
         x, counts = self._ffn(lp, x + out, active)
         return x, state, counts
 
-    def state_chunk(self, lp, x, entry, n_state):
+    def state_chunk(self, lp, x, entry, n_state, positions):
+        del positions
         from deeplearning4j_tpu.nn.short_conv import chunk_mix
 
         out, entry = chunk_mix(lp, x, entry, n_state, self.eps)

@@ -442,6 +442,60 @@ def test_conv_cell_compiles_for_v5e_with_pool_and_tails_in_place(
     assert not [m for m in made if m[1] >= 1.0e9 and m[2] != prog.kv_shape]
 
 
+@pytest.fixture(scope="module")
+def window_cell(one_chip):
+    """`laguna-code-closed32` with shapes in the place of 3.43 GB of
+    bfloat16 weights, 2.69 GB of K/V pool and 0.20 GB of rings."""
+    import jax.numpy as jnp
+
+    from benchmark.drivers import serve_window
+    from benchmark.reference import laguna as ref
+
+    return _cell_programs(one_chip, "laguna-code-closed32", serve_window,
+                          ref, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("program", CELL_PROGRAMS)
+def test_window_cell_compiles_for_v5e_with_pool_and_rings_in_place(
+        window_cell, program):
+    """The cell's programs at the published widths (1.72B parameters,
+    32 of 256 experts of four layers, 32 slots of 10,240 positions for
+    the two full layers' K and V rows of 1,024 lanes in bfloat16, three
+    window layers' rings of 512 such rows a slot), the step and the
+    chunk at the widest and the narrowest window of the ladder (80 pages
+    and 4). Pool AND rings are donated and updated in place, each in ONE
+    layout and never copied whole or raised to float32, no buffer of
+    50 MB or more is narrower than a 128-lane tile, and arguments and
+    temporaries stay under the cell's ceiling of 92% of the chip's
+    15.75 GiB."""
+    prog, cases = window_cell
+    fn, args = cases[program]
+    compiled = getattr(fn, "__wrapped__", fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert prog.kv_shape == (2, 2, 2561, 128, 1024)
+    assert prog.widths == (4, 8, 16, 32, 64, 80)
+    rings = prog.model.state_shape(prog.max_slots)
+    assert rings == (3, 32, 2, 512, 1024)
+    pool_bytes = int(np.prod(prog.kv_shape)) * 2
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (program != "copy")
+    if program == "copy":
+        assert mem.alias_size_in_bytes >= pool_bytes
+        return
+    assert mem.alias_size_in_bytes >= pool_bytes + int(np.prod(rings)) * 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 0.92 * 15.75 * 2**30
+    assert set(re.findall(r"bf16\[2,2,2561,128,1024\]\{([0-9,]+):",
+                          text)) == {"4,3,2,1,0"}
+    assert set(re.findall(r"bf16\[3,32,2,512,1024\]\{([0-9,]+):",
+                          text)) == {"4,3,2,1,0"}
+    assert not re.findall(r"f32\[2,2,2561,128,1024\]", text)
+    made = _materialized(text)
+    assert not [m for m in made if m[0] == "copy"
+                and m[2] in (prog.kv_shape, rings)]
+    assert not [m for m in made if m[1] >= 50e6 and m[3] < 128]
+
+
 @pytest.mark.parametrize("cell,width", [("latent", 8), ("latent", 16),
                                         ("hybrid", 8), ("hybrid", 16),
                                         ("hybrid", 32)])

@@ -121,7 +121,8 @@ def paged_logits(prog, tokens, n_prompt):
         active = page_ids[:, 0] != SCRATCH_PAGE
         for lp, li in prog._layers(params):
             if li < 0:
-                x, state, _ = m.state_step(lp, x, state, -1 - li, active)
+                x, state, _ = m.state_step(lp, x, state, -1 - li, active,
+                                            pos)
                 continue
             q, cell = m.project(lp, x, pos)
             pool = m.write_cells(pool, li, cell, wp, wo)
@@ -236,8 +237,8 @@ def test_a_wrong_tail_would_show(program):
     model = program.model
     real = model.state_step
 
-    def frozen(lp, x, state, si, active):
-        x, _, counts = real(lp, x, state, si, active)
+    def frozen(lp, x, state, si, active, positions):
+        x, _, counts = real(lp, x, state, si, active, positions)
         return x, state, counts
 
     model.state_step = frozen
