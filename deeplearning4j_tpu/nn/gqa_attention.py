@@ -7,7 +7,10 @@ no norm a head where the layer has no `q_norm`, a partial or YaRN-scaled
 rotation (`rotary`'s `inv`, `factor`), and a sigmoid gate a head on the
 output from the layer's normed input (`gated_project`, `gate`:
 Laguna's `full_attention` and `sliding_attention` layers, the latter
-over nn/window_attention.py's ring).
+over nn/window_attention.py's ring), no rotation at all (`theta=None`)
+and a score scale of the model's own in place of 1/sqrt(head_dim)
+(`scale`: Granite-4.0-H's NoPE layers, whose `attention_multiplier`
+is 1/128).
 
 The cache is nn/attention.py's: one row of n_kv * head_dim numbers a
 cached position for K and one for V, pages flattened in ring order,
@@ -51,8 +54,9 @@ def project(lp: dict, x, positions, n_heads: int, n_kv: int, theta: float,
     each): the stream through the layer's norm and the three
     projections, q and k through their norm a head (a gain of D) where
     the layer has one (`q_norm`, `k_norm`) and rotated by `positions`
-    (`inv` and `factor` are `rotary`'s: a partial or scaled rotation);
-    k and v as the rows the pool stores."""
+    (`inv` and `factor` are `rotary`'s: a partial or scaled rotation;
+    `theta` None with no `inv`: no rotation); k and v as the rows the
+    pool stores."""
     return _project(lp, rms_norm(x, lp["norm_in"], eps), positions,
                     n_heads, n_kv, theta, eps, inv, factor)
 
@@ -78,8 +82,12 @@ def _project(lp, u, positions, n_heads, n_kv, theta, eps, inv, factor):
     def norm(a, gain):
         return rms_norm(a, lp[gain], eps) if gain in lp else a
 
-    q = rotary(norm(q, "q_norm"), positions, theta, inv, factor)
-    k = rotary(norm(k, "k_norm"), positions, theta, inv, factor)
+    def turn(a):
+        if theta is None and inv is None:
+            return a
+        return rotary(a, positions, theta, inv, factor)
+
+    q, k = turn(norm(q, "q_norm")), turn(norm(k, "k_norm"))
     return q, (merge_heads(k), mm(u, lp["wv"]))
 
 
@@ -126,18 +134,28 @@ def _own_lanes(full, n_kv: int):
     return merge_heads(jnp.sum(parts * own[:, :, None], axis=-2))
 
 
-def gqa_decode_attention(q, k_rows, v_rows, live, n_kv: int):
+def _scale(q, scale):
+    """The scores' factor: `scale` where the model gives one (a Python
+    number), 1/sqrt(head_dim) where it does not."""
+    import jax.numpy as jnp
+
+    if scale is None:
+        return 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
+    return float(scale)
+
+
+def gqa_decode_attention(q, k_rows, v_rows, live, n_kv: int, scale=None):
     """One position a slot against its gathered window (the DECODE
     shape): `q` [S, H, D] normed and rotated, `k_rows` / `v_rows`
     [S, cells, n_kv * D] in ring order with the new position's row
     already written, `live[s]` readable cells (the rest zeroed before
     the score contraction and masked after). Returns [S, H * D], heads
-    merged."""
+    merged; `scale` is `_scale`'s."""
     import jax.numpy as jnp
 
     f32 = jnp.float32
     n = k_rows.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
+    scale = _scale(q, scale)
     mask = jnp.arange(n)[None, :] < live[:, None]          # [S, N]
     k_rows = jnp.where(mask[:, :, None], k_rows, 0.0)
     v_rows = jnp.where(mask[:, :, None], v_rows, 0.0)
@@ -152,20 +170,21 @@ def gqa_decode_attention(q, k_rows, v_rows, live, n_kv: int):
     return _own_lanes(full, n_kv)
 
 
-def gqa_chunk_attention(q, k, v, k_rows, v_rows, n_prior, n_kv: int):
+def gqa_chunk_attention(q, k, v, k_rows, v_rows, n_prior, n_kv: int,
+                        scale=None):
     """One prompt chunk attending to its prior context and to itself
     (the CHUNK-PREFILL shape): `q` [T, H, D] of positions
     n_prior..n_prior+T-1, `k` / `v` [T, n_kv * D] the chunk's own rows
     in the pool's precision, `k_rows` / `v_rows` [cells, n_kv * D] the
     prior positions in ring order (cells >= n_prior are scratch:
     zeroed and masked). ONE softmax spans [prior cells ; chunk].
-    Returns [T, H * D], heads merged."""
+    Returns [T, H * D], heads merged; `scale` is `_scale`'s."""
     import jax.numpy as jnp
 
     f32 = jnp.float32
     t, h, d = q.shape
     n = k_rows.shape[0]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, q.dtype))
+    scale = _scale(q, scale)
     prior = jnp.arange(n) < n_prior                        # [N]
     k_rows = jnp.where(prior[:, None], k_rows, 0.0)
     v_rows = jnp.where(prior[:, None], v_rows, 0.0)

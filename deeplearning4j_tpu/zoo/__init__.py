@@ -14,6 +14,9 @@ from deeplearning4j_tpu.zoo.hybrid_delta import (  # noqa: F401
 from deeplearning4j_tpu.zoo.latent_moe import (  # noqa: F401
     LatentMoETransformer,
 )
+from deeplearning4j_tpu.zoo.mamba_moe import (  # noqa: F401
+    MambaMoETransformer,
+)
 from deeplearning4j_tpu.zoo.short_conv_moe import (  # noqa: F401
     ShortConvMoETransformer,
 )
