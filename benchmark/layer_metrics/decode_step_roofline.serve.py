@@ -2,7 +2,10 @@
 chip could take for what one step must do — weights read once, the live
 K/V cells of the active slots read once, one cell written per slot;
 counted from live lengths, not max_ctx — over the decode-step program's
-device time per execution."""
+device time per execution. Of the expert layers' held experts it counts
+those the window's rows chose, a step, as the program counted them
+(`moe_experts_hit`, the count `moe_roofline.serve` takes); a program
+with no such counter is counted as before."""
 
 from benchmark.roofline import roofline_seconds
 
@@ -15,7 +18,10 @@ def read(facts):
         return None
     ref, cfg, ctx = facts["reference"], facts["config"], facts["mean_context"]
     active = d["tokens_total"] / d["steps"]
+    counted = {} if "moe_experts_hit" not in d \
+        else {"experts_hit": d["moe_experts_hit"] / d["steps"]}
     least = roofline_seconds(active * ref.flops_per_token(cfg, ctx),
-                             ref.decode_step_bytes(cfg, active * ctx, active),
+                             ref.decode_step_bytes(cfg, active * ctx, active,
+                                                   **counted),
                              facts["peaks"], facts["chips"])
     return 100.0 * least / prog["median_s"]

@@ -188,16 +188,31 @@ def experts_hit(cfg: dict, rows: float) -> float:
     return len(d["held"]) * (1.0 - (1.0 - d["top_k"] / d["router"]) ** rows)
 
 
-def decode_step_bytes(cfg: dict, live_cells: float, slots: float) -> float:
+def _unread(cfg: dict, n_moe: int, slots: float,
+            counted: float | None) -> float:
+    """Held experts no row of a step chose, over its `n_moe` expert
+    layers: all the held less `counted`, the hits the program counted
+    a step, or where that is None less the `experts_hit` that `slots`
+    rows reach a layer at the mean."""
+    held = len(dims(cfg)["held"])
+    if counted is None:
+        return n_moe * (held - experts_hit(cfg, slots))
+    return n_moe * held - counted
+
+
+def decode_step_bytes(cfg: dict, live_cells: float, slots: float,
+                      experts_hit: float | None = None) -> float:
     """What one decode step must move whatever implements it: every
-    matrix that a row multiplies through once (of each expert layer's
-    experts the `experts_hit` that `slots` rows reach at the mean), the
+    matrix that a row multiplies through once (of the expert layers'
+    held experts those some row chose: `experts_hit`, summed over the
+    layers, as the program counted them a step; where it is None the
+    `experts_hit` that `slots` rows reach a layer at the mean), the
     `live_cells` K and V rows of the active slots once in each full
     layer and at most `sliding_window` a slot of them in each window
     layer's ring, and one new pair a slot in every attention layer."""
     d = dims(cfg)
     n_full, n_win = len(_layers(cfg, "full")), len(_layers(cfg, "window"))
-    unread = _n_moe(cfg) * (len(d["held"]) - experts_hit(cfg, slots)) \
+    unread = _unread(cfg, _n_moe(cfg), slots, experts_hit) \
         * expert_params(cfg)
     ring = min(live_cells, slots * d["window"])
     return (matmul_params(cfg) - unread) * BYTES \

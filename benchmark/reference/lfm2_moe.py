@@ -202,16 +202,30 @@ def experts_hit(cfg: dict, rows: float) -> float:
     return len(d["held"]) * (1.0 - (1.0 - d["top_k"] / d["router"]) ** rows)
 
 
-def decode_step_bytes(cfg: dict, live_cells: float, slots: float) -> float:
+def _unread(cfg: dict, n_moe: int, slots: float,
+            counted: float | None) -> float:
+    """Held experts no row of a step chose, over its `n_moe` expert
+    layers: all the held less `counted`, the hits the program counted
+    a step, or where that is None less the `experts_hit` that `slots`
+    rows reach a layer at the mean."""
+    held = len(dims(cfg)["held"])
+    if counted is None:
+        return n_moe * (held - experts_hit(cfg, slots))
+    return n_moe * held - counted
+
+
+def decode_step_bytes(cfg: dict, live_cells: float, slots: float,
+                      experts_hit: float | None = None) -> float:
     """What one decode step must move whatever implements it: every
-    matrix that a row multiplies through once (of each expert layer's
-    experts the `experts_hit` that `slots` rows reach at the mean), the
+    matrix that a row multiplies through once (of the expert layers'
+    held experts those some row chose: `experts_hit`, summed over the
+    layers, as the program counted them a step; where it is None the
+    `experts_hit` that `slots` rows reach a layer at the mean), the
     live K and V rows of the active slots once and one new pair a slot
     in each attention layer, and each active slot's tail read and
     written once in each short-convolution layer."""
-    d, n_conv, n_attn, n_moe = _counts(cfg)
-    unread = n_moe * (len(d["held"]) - experts_hit(cfg, slots)) \
-        * expert_params(cfg)
+    _, n_conv, n_attn, n_moe = _counts(cfg)
+    unread = _unread(cfg, n_moe, slots, experts_hit) * expert_params(cfg)
     return (matmul_params(cfg) - unread) * BYTES \
         + (live_cells + slots) * n_attn * cell_bytes(cfg) \
         + 2.0 * slots * n_conv * state_bytes(cfg)

@@ -161,16 +161,30 @@ def experts_hit(cfg: dict, rows: float) -> float:
     return len(d["held"]) * (1.0 - (1.0 - d["top_k"] / d["router"]) ** rows)
 
 
-def decode_step_bytes(cfg: dict, live_cells: float, slots: float) -> float:
+def _unread(cfg: dict, n_moe: int, slots: float,
+            counted: float | None) -> float:
+    """Held experts no row of a step chose, over its `n_moe` expert
+    layers: all the held less `counted`, the hits the program counted
+    a step, or where that is None less the `experts_hit` that `slots`
+    rows reach a layer at the mean."""
+    held = len(dims(cfg)["held"])
+    if counted is None:
+        return n_moe * (held - experts_hit(cfg, slots))
+    return n_moe * held - counted
+
+
+def decode_step_bytes(cfg: dict, live_cells: float, slots: float,
+                      experts_hit: float | None = None) -> float:
     """What one decode step must move whatever implements it: every
-    matrix that a row multiplies through once — of each expert layer's
-    held experts the `experts_hit` that `slots` rows reach at the mean,
-    not the ones no row chose —, the live latent rows of the active
+    matrix that a row multiplies through once — of the expert layers'
+    held experts those some row chose, not the others: `experts_hit`,
+    summed over the layers, as the program counted them a step; where
+    it is None the `experts_hit` that `slots` rows reach a layer at the
+    mean —, the live latent rows of the active
     slots once, one new row per slot."""
     d = dims(cfg)
     n_moe = d["layers"] - d["dense"]
-    unread = n_moe * (len(d["held"]) - experts_hit(cfg, slots)) \
-        * expert_params(cfg)
+    unread = _unread(cfg, n_moe, slots, experts_hit) * expert_params(cfg)
     return (matmul_params(cfg) - unread) * BYTES \
         + (live_cells + slots) * d["layers"] * cell_bytes(cfg)
 
