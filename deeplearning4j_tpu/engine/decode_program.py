@@ -6,7 +6,7 @@ programs, compiled ONCE per shape and never again (the static-shape
 constraint that makes one-program XLA serving work at all, per
 "Automatic Full Compilation ... to Cloud TPUs", arXiv 1810.09868):
 
-  decode step   ONE program per window width over the engine's fixed
+  decode step   ONE program per step width over the engine's fixed
                 [max_slots] batch: consume each slot's current token
                 (the host's, or where the host says so the one the
                 step before emitted, which then never leaves the
@@ -14,14 +14,16 @@ constraint that makes one-program XLA serving work at all, per
                 at its current LOGICAL position, scatter that
                 position's K/V into a host-chosen (page, offset) write
                 cell, gather each slot's attention window a whole page
-                at a time through its [width] page ids in ring order,
-                attend under per-slot live masks, emit each slot's
-                greedy next token. Requests joining/leaving, prefix
-                pages being shared, copy-on-write forks, and ring wrap
-                past max_ctx are all pure DATA (the host page table) —
-                one compiled shape per ladder width, all compiled
-                before traffic, so arbitrary traffic runs on the
-                warm-up's compiles (pinned by trace counters).
+                at a time through its [width] page ids in ring order
+                (or, for a model that reads the pool itself, read each
+                slot's live pages straight from it), attend under
+                per-slot live masks, emit each slot's greedy next
+                token. Requests joining/leaving, prefix pages being
+                shared, copy-on-write forks, and ring wrap past
+                max_ctx are all pure DATA (the host page table) — one
+                compiled shape per step width, all compiled before
+                traffic, so arbitrary traffic runs on the warm-up's
+                compiles (pinned by trace counters).
   chunk prefill ONE program per window width, over `chunk_tokens`
                 rows: process one aligned block of a prompt in
                 parallel — causal within the chunk, attending to the
@@ -48,7 +50,11 @@ a ladder of one). The host hands a program the narrowest ladder width
 that holds the live pages — of the longest decoding slot for a step,
 of the prior context for a chunk — and `step` / `prefill_chunk` pick
 the compiled program by the width of the ids they are given. `warmup`
-compiles and runs every width of both.
+compiles and runs every width of both. A model that attends straight
+from the pool (`decode_from_pool`, below) reads each slot's live pages
+and no more, so its step's cost follows `live` and not the ids' width:
+its step is compiled at `pages_per_slot` alone (`step_widths`, a
+ladder of one), and only its chunk keeps the ladder.
 
 What is the same for every model lives here: the three programs and
 their compile-once keys, the page indirection (`window_pages`, scratch
@@ -82,6 +88,13 @@ the model, and nothing here asks which one it is:
         half; `counts` is an int32 vector of `step_counters` (the
         model's names for it) over the `active` rows, summed here over
         layers and steps and read through `counters()`
+  decode_from_pool(lp, x, q, pool, li, page_ids, live, active)
+        -> (x, counts | None)
+        in the place of `read_window` + `decode_finish` in the decode
+        step, where the model has it: attention read straight from the
+        pool, each `active` row's first ceil(live / page_size) page ids
+        and nothing else (a kernel's read, under the `kv_read` scope),
+        then the feed-forward half
   head(params, x) -> logits
 
 A SECOND KIND OF STATE, for layers that keep no rows: a model may also
@@ -93,8 +106,9 @@ nn/delta_attention.py, zoo/hybrid_delta.py). It then gives
         a name a layer, "pages" or "state". The pool holds the
         "pages" layers only: `kv_shape`'s layer count and the index
         `write_cells` / `read_window` take follow the model's own
-        count of them, and `project` / `decode_finish` /
-        `chunk_finish` are called for those layers alone.
+        count of them, and `project` / `decode_finish` (or
+        `decode_from_pool`) / `chunk_finish` are called for those
+        layers alone.
   state_shape(max_slots), state_dtype
         the state: one buffer, or a dict of them, whose axes lead
         [state layer, slot, ...] (`init_state`; None for a model that
@@ -135,7 +149,8 @@ Page 0 is SCRATCH: the write target for inactive/suppressed rows and
 the gather target for pages with no live cell — never mapped live, and
 its (possibly garbage) bytes are kept out inside the attention
 primitives: a dead cell's score is masked, and what a sum would carry
-of it is zeroed first.
+of it is zeroed first (a read from the pool skips a page with no live
+cell, and an empty slot's row reads none).
 
 All three programs DONATE the pool (and the step and the chunk the
 state, where there is one): updates are in-place, the caller
@@ -159,7 +174,8 @@ engine and oracle agree bitwise when run at the same width. A chunk's
 width is a function of its start alone, so the two always agree on
 it; a step's is that of the longest slot decoding in it, so
 `sequential_decode` takes a `width` to run a request's steps at the
-one the engine ran them at.
+one the engine ran them at. A step read from the pool has one width,
+and reads no cell past a slot's live ones at any.
 
 Forensics and policy ride the exact StepProgram rails: programs live
 in the model's JitCache (record_trace inside traced bodies,
@@ -242,6 +258,11 @@ class DecodeProgram:
             ladder.append(w)
             w *= 2
         self.widths: Tuple[int, ...] = (*ladder, self.pages_per_slot)
+        # a model that attends straight from the pool reads each slot's
+        # live pages whatever the ids' width: its step has one width
+        self.from_pool = hasattr(model, "decode_from_pool")
+        self.step_widths: Tuple[int, ...] = (
+            (self.pages_per_slot,) if self.from_pool else self.widths)
         if n_pages is None:
             # equal HBM to a contiguous per-slot layout, + scratch
             n_pages = self.max_slots * self.pages_per_slot + 1
@@ -275,7 +296,7 @@ class DecodeProgram:
         # retraces, so the engine's stats (and the tracing story) can
         # report how many device dispatches a generation actually
         # cost, and how often each width was the one chosen
-        self._dispatches = {"step": dict.fromkeys(self.widths, 0),
+        self._dispatches = {"step": dict.fromkeys(self.step_widths, 0),
                             "chunk": dict.fromkeys(self.widths, 0),
                             "copy": 0}
         # the model's per-step counts (`step_counters`): totals on the
@@ -362,13 +383,18 @@ class DecodeProgram:
         position `pos` (every one after a wrap)."""
         return -(-min(pos + 1, self.window) // self.page_size)
 
-    def width_for(self, n_pages: int) -> int:
-        """The narrowest ladder width that holds `n_pages` pages."""
-        for w in self.widths:
+    def width_for(self, n_pages: int, widths=None) -> int:
+        """The narrowest width of `widths` (the ladder's by default)
+        that holds `n_pages` pages."""
+        for w in widths or self.widths:
             if w >= n_pages:
                 return w
         raise ValueError(f"{n_pages} pages exceed the window's "
                          f"{self.pages_per_slot}")
+
+    def step_width_for(self, n_pages: int) -> int:
+        """The narrowest step width that holds `n_pages` pages."""
+        return self.width_for(n_pages, self.step_widths)
 
     def window_pages(self, table: Sequence[Optional[int]], pos: int,
                      width: Optional[int] = None) -> np.ndarray:
@@ -397,19 +423,21 @@ class DecodeProgram:
         return page_ids
 
     # ------------------------------------------------------- compile
-    def _width(self, width: Optional[int]) -> int:
-        """`width` as a ladder width (None: the whole window). A
-        program of any other width would compile under traffic."""
+    def _width(self, width: Optional[int], widths=None) -> int:
+        """`width` as one of `widths`, the ladder's by default (None:
+        the whole window). A program of any other width would compile
+        under traffic."""
+        widths = widths or self.widths
         if width is None:
             return self.pages_per_slot
-        if width not in self.widths:
-            raise ValueError(f"window width {width} is not one of the "
-                             f"ladder's {self.widths}")
+        if width not in widths:
+            raise ValueError(f"window width {width} is not one of "
+                             f"{widths}")
         return int(width)
 
     def decode_key(self, width: Optional[int] = None):
         return ("decode_step", self.max_slots, self.window,
-                self.n_pages, self._width(width))
+                self.n_pages, self._width(width, self.step_widths))
 
     def chunk_key(self, width: Optional[int] = None):
         return ("decode_chunk_prefill", self.chunk_tokens,
@@ -437,7 +465,7 @@ class DecodeProgram:
 
     def _build_decode(self, trace_key: str):
         """Compile the shared decode step (the window's width is the
-        page ids': one jitted function a ladder width, each traced for
+        page ids': one jitted function a step width, each traced for
         one shape). Per-slot independence is the load-bearing
         property, at every width: no op mixes slots (batched einsums,
         per-row norms/softmax, per-row gathers), so an active slot's
@@ -485,12 +513,18 @@ class DecodeProgram:
                 with jax.named_scope("kv_write"):
                     pool = model.write_cells(pool, li, cell, write_page,
                                              write_off)
-                # gather: each slot's window a whole page at a time in
-                # ring order — the virtual-memory read
-                with jax.named_scope("kv_read"):
-                    window = model.read_window(pool, li, page_ids)
-                x, c = model.decode_finish(lp, x, q, window, live,
-                                           active)
+                if self.from_pool:
+                    # each active slot's live pages, read by the model
+                    # straight from the pool (its read is `kv_read`)
+                    x, c = model.decode_from_pool(lp, x, q, pool, li,
+                                                  page_ids, live, active)
+                else:
+                    # gather: each slot's window a whole page at a time
+                    # in ring order — the virtual-memory read
+                    with jax.named_scope("kv_read"):
+                        window = model.read_window(pool, li, page_ids)
+                    x, c = model.decode_finish(lp, x, q, window, live,
+                                               active)
                 if c is not None:
                     counts.append(c)
             with jax.named_scope("head"):
@@ -649,9 +683,9 @@ class DecodeProgram:
         """One decode step over all slots. `tokens`/`positions`/
         `write_page`/`write_off` are host [max_slots] int arrays and
         `page_ids` a host [max_slots, width] int array (one
-        `window_pages` row per slot, all of one ladder width that
-        holds the longest slot's live pages: the engine's translated
-        page table), which picks the program; returns
+        `window_pages` row per slot, all of one step width that holds
+        the longest slot's live pages: the engine's translated page
+        table), which picks the program; returns
         (new_kv, next_tokens, finite_ok) with `kv` donated — the
         caller MUST rebind. `finite_ok` is the per-slot finite-logits
         verdict ([max_slots] bool): a False row's token is numeric
@@ -673,8 +707,17 @@ class DecodeProgram:
         The host arrays go over as numpy copies: an argument made with
         `jnp.asarray` is a device program of its own between two steps
         (`jit_convert_element_type` in the trace, PERF.md PR 28), and a
-        copy is the caller's to change again at once."""
+        copy is the caller's to change again at once.
+
+        A step read from the pool stops at each row's live pages, so
+        ids of a narrower window are the same ids with scratch after
+        them: they are padded to its one width."""
         width = np.shape(page_ids)[1]
+        if self.from_pool and width < self.pages_per_slot:
+            page_ids = np.pad(page_ids,
+                              ((0, 0), (0, self.pages_per_slot - width)),
+                              constant_values=SCRATCH_PAGE)
+            width = self.pages_per_slot
         fn = self._decode_program(width)
         self._dispatches["step"][width] += 1
         held = (kv,) if state is None else (kv, state)
@@ -757,8 +800,9 @@ class DecodeProgram:
         return fn(kv, np.int32(src), np.int32(dst))
 
     def warmup(self, kv, buckets: Sequence[int] = (), state=None):
-        """Compile every program up front, the chunk and the step at
-        every ladder width (serving warmup discipline: compiles happen
+        """Compile every program up front, the chunk at every ladder
+        width and the step at every step width (serving warmup
+        discipline: compiles happen
         before traffic, the trace counters pin that none happen
         after). `buckets` is accepted for call-site compatibility and
         ignored — chunked prefill replaced the per-bucket prefill
@@ -778,6 +822,8 @@ class DecodeProgram:
                                      np.full(w, SCRATCH_PAGE, np.int32),
                                      (), state=state)
             kv, state = out if self.has_state else (out, None)
+            if w not in self.step_widths:
+                continue
             kv, _, _, *rest = self.step(
                 kv, zs, zs, np.full((s, w), SCRATCH_PAGE, np.int32),
                 zs, zs, *(() if state is None else (state,)))
@@ -803,8 +849,9 @@ class DecodeProgram:
     def lint_records(self, buckets: Sequence[int] = ()) -> List:
         """ProgramRecords for the decode step and the chunk prefill at
         the narrowest and the widest ladder width (one record each
-        where the ladder is one width; the widest keeps the bare name,
-        a narrower one adds `_w<pages>`), and the page copy — built
+        where the ladder is one width, and one step where the step has
+        one width; the widest keeps the bare name, a narrower one adds
+        `_w<pages>`), and the page copy — built
         through the same cache paths the engine uses (policy
         registered), traced/lowered by the lint but never executed.
         Donation on the page pool is DECLARED on every record
@@ -833,10 +880,10 @@ class DecodeProgram:
         records = []
         for w in sorted({self.widths[0], self.widths[-1]}):
             tag = "" if w == self.pages_per_slot else f"_w{w}"
-            step_fn = self._decode_program(w)
             chunk_fn = self._chunk_program(w)
-            records += [
-                ProgramRecord(
+            if w in self.step_widths:
+                step_fn = self._decode_program(w)
+                records.append(ProgramRecord(
                     name=f"decode_step_s{s}{tag}",
                     fn=getattr(step_fn, "__wrapped__", step_fn),
                     example_args=(model.params, *held, zs, zs,
@@ -846,7 +893,8 @@ class DecodeProgram:
                     precision_policy=self.precision_policy,
                     source=source,
                     consumed_outputs=tuple(range(
-                        2 + len(held) + bool(model.step_counters)))),
+                        2 + len(held) + bool(model.step_counters)))))
+            records += [
                 ProgramRecord(
                     name=f"decode_prefill_c{self.chunk_tokens}{tag}",
                     fn=getattr(chunk_fn, "__wrapped__", chunk_fn),

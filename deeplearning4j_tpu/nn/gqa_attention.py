@@ -34,6 +34,14 @@ never raised: a float32 copy of a gathered window would be twice the
 window) and sum in float32; norms, rotary, softmax are float32.
 Ring order, dead-cell zeroing and the one softmax over
 [prior cells ; chunk] are nn/attention.py's, for the same reasons.
+
+The decode step need not gather: `gqa_pool_attention` is the DECODE
+shape read straight from the pool by a kernel
+(nn/helpers/pallas_paged_gqa.py) that copies each slot's live pages and
+no others, with the same block-diagonal query, the same operands in
+the pool's dtype and an online softmax over the pages; its result is
+`gqa_decode_attention`'s over the same cells up to the order of the
+sums.
 """
 
 from __future__ import annotations
@@ -167,6 +175,32 @@ def gqa_decode_attention(q, k_rows, v_rows, live, n_kv: int, scale=None):
     w = _softmax(scores).astype(v_rows.dtype)
     full = jnp.einsum("shn,snc->shc", w, v_rows,
                       preferred_element_type=f32)          # [S, H, C]
+    return _own_lanes(full, n_kv)
+
+
+def gqa_pool_attention(q, pool, li: int, page_ids, live, active,
+                       n_kv: int, scale=None):
+    """`gqa_decode_attention` of each `active` row over its window
+    read straight from the page pool (`pool` as stored, `li` the page
+    layer, `page_ids` [S, W] in ring order, `live[s]` readable cells):
+    the kernel copies a row's first ceil(live / page) pages and nothing
+    else, under the `kv_read` scope, since it is the cache read. An
+    inactive row reads nothing and gives 0. Returns [S, H * D], heads
+    merged; `scale` is `_scale`'s."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.helpers.pallas_paged_gqa import (
+        paged_gqa_decode,
+    )
+
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    with jax.named_scope("kv_read"):
+        full = paged_gqa_decode(q, pool, li, page_ids,
+                                jnp.where(active, live, 0), n_kv, scale)
     return _own_lanes(full, n_kv)
 
 

@@ -106,9 +106,10 @@ its neighbors are, and when it joins. A step gathers a window as wide
 as its longest decoding slot needs (the program's ladder of widths):
 a wider window appends dead cells to every slot's reduction, zeroed
 and masked, and engine and oracle agree bitwise when run at the same
-width (engine/decode_program.py). tests/test_decode.py pins
-engine output == sequential per-request oracle under staggered churn
-AND mid-soak eviction chaos.
+width (engine/decode_program.py); a step that reads the pool itself
+has one width and reads each slot's live pages alone.
+tests/test_decode.py pins engine output == sequential per-request
+oracle under staggered churn AND mid-soak eviction chaos.
 
 Generation durability (the crash-proof layer on the same replay
 mechanism — a generation request is a durable object, not
@@ -1820,11 +1821,12 @@ class DecodeEngine:
         """Translate the page table into the decode dispatch's index
         arrays: [S, width] page ids in ring order per slot
         (`window_pages`; non-decoding rows gather scratch), `width`
-        the narrowest of the program's ladder that holds the live
+        the narrowest of the program's step widths that holds the live
         pages of the longest decoding slot, plus each slot's write
         cell (first-token steps and non-decoding rows write scratch).
         Counts what the step will read: every entry is a page
-        gathered, an entry off scratch a page with a live cell."""
+        gathered (where the step reads from the pool, only an entry
+        off scratch), an entry off scratch a page with a live cell."""
         from deeplearning4j_tpu.engine.decode_program import (
             SCRATCH_PAGE,
         )
@@ -1833,7 +1835,7 @@ class DecodeEngine:
         ps = self.program.page_size
         p = self.program.pages_per_slot
         rows = np.flatnonzero(decoding)
-        width = self.program.width_for(self.program.live_pages(
+        width = self.program.step_width_for(self.program.live_pages(
             int(self._positions[rows].max())))
         page_ids = np.full((s_n, width), SCRATCH_PAGE, np.int32)
         wp = np.full(s_n, SCRATCH_PAGE, np.int32)
@@ -1845,9 +1847,11 @@ class DecodeEngine:
             if not self._first_step[s]:
                 wp[s] = self._table[s][(pos // ps) % p]
                 wo[s] = pos % ps
-        self._kv_pages_gathered += page_ids.size
-        self._kv_pages_live += int(
-            np.count_nonzero(page_ids != SCRATCH_PAGE))
+        live = int(np.count_nonzero(page_ids != SCRATCH_PAGE))
+        # a step read from the pool reads the live pages alone
+        self._kv_pages_gathered += (live if self.program.from_pool
+                                    else page_ids.size)
+        self._kv_pages_live += live
         return page_ids, wp, wo
 
     def _harvest(self, nxt_host: np.ndarray, emits: np.ndarray) -> int:
@@ -2167,7 +2171,7 @@ def sequential_decode(program, prompt: Sequence[int],
     positions = np.zeros(s_n, np.int32)
     while len(out) < max_new_tokens and (eos_id is None or not out
                                          or out[-1] != eos_id):
-        w = width or program.width_for(program.live_pages(pos))
+        w = width or program.step_width_for(program.live_pages(pos))
         page_ids = np.full((s_n, w), SCRATCH_PAGE, np.int32)
         wp = np.full(s_n, SCRATCH_PAGE, np.int32)
         wo = np.zeros(s_n, np.int32)

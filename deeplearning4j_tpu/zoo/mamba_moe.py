@@ -22,6 +22,8 @@ block differs in passed as data (`route_score="softmax"`, `n_shared`,
 describes the pool of its "attn" layers alone and, for the "mamba"
 layers, the second kind of state of the contract there: the fourth
 model to describe one, and the one whose state a slot is the largest.
+Its decode step reads the pool itself (`decode_from_pool`: each slot's
+live pages, no gathered window).
 """
 
 from __future__ import annotations
@@ -141,14 +143,18 @@ class MambaMoETransformer(LatentMoETransformer):
             return project(lp, x, positions, self.n_heads, self.n_kv_heads,
                            None, self.eps)
 
-    def decode_finish(self, lp, x, q, window, live, active):
+    def decode_from_pool(self, lp, x, q, pool, li, page_ids, live,
+                         active):
+        """The decode step's attention read straight from the pool:
+        each active row's live pages alone."""
         import jax
 
-        from deeplearning4j_tpu.nn.gqa_attention import gqa_decode_attention
+        from deeplearning4j_tpu.nn.gqa_attention import gqa_pool_attention
 
         with jax.named_scope("attn"):
-            att = gqa_decode_attention(q, *window, live, self.n_kv_heads,
-                                       self.attention_multiplier)
+            att = gqa_pool_attention(q, pool, li, page_ids, live, active,
+                                     self.n_kv_heads,
+                                     self.attention_multiplier)
         return self._finish(lp, x, att, active)
 
     def chunk_finish(self, lp, x, q, cell, window, start):

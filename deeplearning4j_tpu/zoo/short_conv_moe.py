@@ -21,7 +21,8 @@ alone and, for the "conv" layers, the second kind of state of the
 contract there (`mix_kind`, `state_shape`, `state_step`,
 `state_chunk`): the second model to do so, after
 zoo/hybrid_delta.py, and with a state layer in the leading dense
-position.
+position. Its decode step reads the pool itself (`decode_from_pool`:
+each slot's live pages, no gathered window).
 """
 
 from __future__ import annotations
@@ -137,13 +138,17 @@ class ShortConvMoETransformer(LatentMoETransformer):
 
         return rows(0), rows(1)
 
-    def decode_finish(self, lp, x, q, window, live, active):
+    def decode_from_pool(self, lp, x, q, pool, li, page_ids, live,
+                         active):
+        """The decode step's attention read straight from the pool:
+        each active row's live pages alone."""
         import jax
 
-        from deeplearning4j_tpu.nn.gqa_attention import gqa_decode_attention
+        from deeplearning4j_tpu.nn.gqa_attention import gqa_pool_attention
 
         with jax.named_scope("attn"):
-            att = gqa_decode_attention(q, *window, live, self.n_kv_heads)
+            att = gqa_pool_attention(q, pool, li, page_ids, live, active,
+                                     self.n_kv_heads)
         return self._finish(lp, x, att, active)
 
     def chunk_finish(self, lp, x, q, cell, window, start):

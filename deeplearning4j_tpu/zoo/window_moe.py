@@ -23,7 +23,9 @@ passed as data (`route_score="softmax"`, `n_shared`). To
 engine/decode_program.py it describes the pool of its "full" layers
 alone and, for the "window" layers, the second kind of state of the
 contract there, which takes positions: the third model to describe a
-state, and the first whose state is rows.
+state, and the first whose state is rows. Its decode step reads the
+pool itself (`decode_from_pool`: each slot's live pages, no gathered
+window).
 
 One counter beside the expert layer's (`step_counters`):
 `window_cells_live`, the ring cells the window layers' active rows
@@ -181,14 +183,18 @@ class WindowMoETransformer(LatentMoETransformer):
         return jnp.concatenate(
             [counts, jnp.reshape(jnp.asarray(cells, jnp.int32), (1,))])
 
-    def decode_finish(self, lp, x, q, window, live, active):
+    def decode_from_pool(self, lp, x, q, pool, li, page_ids, live,
+                         active):
+        """A full layer of the decode step, its attention read straight
+        from the pool: each active row's live pages alone."""
         import jax
 
-        from deeplearning4j_tpu.nn.gqa_attention import gqa_decode_attention
+        from deeplearning4j_tpu.nn.gqa_attention import gqa_pool_attention
 
         q, g = q
         with jax.named_scope("attn"):
-            att = gqa_decode_attention(q, *window, live, self.n_kv_heads)
+            att = gqa_pool_attention(q, pool, li, page_ids, live, active,
+                                     self.n_kv_heads)
         x, counts = self._gated_out(lp, x, att, g, active)
         return x, None if counts is None else self._with_cells(counts, 0)
 

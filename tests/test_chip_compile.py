@@ -48,11 +48,17 @@ def topo():
     except Exception as e:   # noqa: BLE001 - any failure means: skip
         env.undo()
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # jax.default_backend() is "cpu" here, so the expert layer's kernel
-    # would pick interpret mode; the chip's compiler must get Mosaic
-    from deeplearning4j_tpu.nn.helpers import pallas_moe
+    # jax.default_backend() is "cpu" here, so the expert layer's, the
+    # Mamba-2 step's and the paged attention's kernels would pick
+    # interpret mode; the chip's compiler must get Mosaic
+    from deeplearning4j_tpu.nn.helpers import (
+        pallas_moe,
+        pallas_paged_gqa,
+        pallas_ssd,
+    )
 
-    env.setattr(pallas_moe, "_interpret", lambda: False)
+    for kernel in (pallas_moe, pallas_paged_gqa, pallas_ssd):
+        env.setattr(kernel, "_interpret", lambda: False)
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -158,7 +164,8 @@ def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
     serving cell as its driver builds it, with shapes on the described
     chip in the place of the weights and the pool. The decode step and
     the chunk at the widest window of the program's ladder (the whole
-    window) and, as `*_narrow`, at the narrowest."""
+    window) and, as `*_narrow`, at the narrowest (the step's own: a
+    step that reads the pool itself has the whole window alone)."""
     import json
     import os
     from types import SimpleNamespace
@@ -200,13 +207,16 @@ def _cell_programs(one_chip, name, driver, ref, matrix_dtype):
                  if isinstance(shape, dict) else sds(shape, dt),)
         tail = (one, one)
     assert prog.widths[0] < prog.widths[-1] == prog.pages_per_slot
-    for tag, p in (("", prog.widths[-1]), ("_narrow", prog.widths[0])):
+    assert prog.step_widths[-1] == prog.pages_per_slot
+    for tag, i in (("", -1), ("_narrow", 0)):
         # the step's last two arguments (PR 35): the step before's
         # tokens, on the device, and the rows that take them
+        p = prog.step_widths[i]
         cases["decode" + tag] = (
             prog._decode_program(p),
             (params, *held, zs, zs, sds((s, p), i32), zs, zs, zs,
              sds((s,), jnp.bool_)))
+        p = prog.widths[i]
         cases["chunk" + tag] = (
             prog._chunk_program(p),
             (params, *held, sds((t,), i32), one, sds((p,), i32),
@@ -534,3 +544,56 @@ def test_decode_step_keeps_its_pins_at_the_ladder_widths_between(
     pool = ",".join(str(d) for d in prog.kv_shape)
     assert set(re.findall(rf"bf16\[{pool}\]\{{([0-9,]+):", text)) == {
         ",".join(str(i) for i in reversed(range(len(prog.kv_shape))))}
+
+
+@pytest.fixture(scope="module")
+def granite_cell(one_chip):
+    """`granite-h-chat-closed128` with shapes in the place of its
+    bfloat16 weights, its K/V pool and its float32 Mamba-2 states."""
+    import jax.numpy as jnp
+
+    from benchmark.drivers import serve_granite
+    from benchmark.reference import granite_hybrid as ref
+
+    return _cell_programs(one_chip, "granite-h-chat-closed128",
+                          serve_granite, ref, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("cell", ["conv", "window", "granite"])
+def test_gqa_decode_step_reads_the_pool_in_place_and_gathers_no_window(
+        cell, request):
+    """The decode step of each cell whose page layers are grouped-query
+    attention, at its one width (the whole window): the attention reads
+    each slot's live pages straight from the pool through the paged
+    kernel (nn/helpers/pallas_paged_gqa.py). The pool is donated and
+    updated in place, in its ONE layout; no gathered window of
+    `bf16[slots, cells, n_kv * head_dim]` (nor of pages,
+    `[slots, pages, page, n_kv * head_dim]`) exists at any cell count,
+    where the gather made `bf16[32,10240,1024]`, `bf16[128,2048,512]`
+    and `bf16[96,2048,1024]` and their zeroed copies; and the step
+    needs under a gigabyte of temporaries."""
+    prog, cases = request.getfixturevalue(f"{cell}_cell")
+    assert prog.from_pool
+    assert prog.step_widths == (prog.pages_per_slot,)
+    fn, args = cases["decode"]
+    compiled = getattr(fn, "__wrapped__", fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert mem.temp_size_in_bytes < 1.0e9
+    import jax.numpy as jnp
+
+    shapes = prog.model.state_shape(prog.max_slots)
+    state = sum(int(np.prod(v)) for v in (
+        shapes.values() if isinstance(shapes, dict) else [shapes]))
+    assert mem.alias_size_in_bytes >= int(np.prod(prog.kv_shape)) * 2 \
+        + state * jnp.dtype(prog.model.state_dtype).itemsize
+    pool = ",".join(str(d) for d in prog.kv_shape)
+    assert set(re.findall(rf"bf16\[{pool}\]\{{([0-9,]+):", text)) == {
+        ",".join(str(i) for i in reversed(range(len(prog.kv_shape))))}
+    s, ps, c = prog.max_slots, prog.page_size, prog.kv_shape[-1]
+    windows = [dims for op, _, dims, _ in _materialized(text)
+               if op != "parameter" and dims[0] == s and dims[-1] == c
+               and (len(dims) == 3 and dims[1] >= ps
+                    or len(dims) == 4 and dims[2] == ps)]
+    assert not windows

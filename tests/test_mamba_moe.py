@@ -346,9 +346,8 @@ def _teacher_step(prog):
                 continue
             q, cell = m.project(lp, x, pos)
             pool = m.write_cells(pool, li, cell, wp, wo)
-            x, _ = m.decode_finish(lp, x, q, m.read_window(pool, li,
-                                                           page_ids),
-                                   live, active)
+            x, _ = m.decode_from_pool(lp, x, q, pool, li, page_ids,
+                                      live, active)
         return pool, state, m.head(params, x)
 
     return step

@@ -99,12 +99,14 @@ def test_four_shares_of_the_experts_add_up_to_the_uncut_layer(side):
 # sha256 of the jaxpr of each program DecodeProgram builds for the
 # models served before this one, at their default toy widths (2 slots,
 # pages of 8, 64 positions), as traced before nn/gqa_attention.py took
-# `theta=None` and `scale`
+# `theta=None` and `scale`; the decode steps of LFM2 and Laguna as
+# traced since their attention layers read the pool themselves
+# (`decode_from_pool`), their chunks and copies as before
 PINNED = {
-    "lfm2": {"decode_step_s2": "f1a719b5012a1a80",
+    "lfm2": {"decode_step_s2": "52a8efbb34959834",
              "decode_prefill_c64": "981ea8729d54e41d",
              "decode_page_copy": "f04b43b58d614b59"},
-    "laguna": {"decode_step_s2": "b33429c9668cae82",
+    "laguna": {"decode_step_s2": "21ec41e712e39096",
                "decode_prefill_c64": "e575f1f34ee0c24a",
                "decode_page_copy": "ffd2ef1d19758708"},
     "kimi": {"decode_step_s2": "1951f0d36112d48a",

@@ -126,9 +126,8 @@ def paged_logits(prog, tokens, n_prompt):
                 continue
             q, cell = m.project(lp, x, pos)
             pool = m.write_cells(pool, li, cell, wp, wo)
-            x, _ = m.decode_finish(lp, x, q, m.read_window(pool, li,
-                                                           page_ids),
-                                   live, active)
+            x, _ = m.decode_from_pool(lp, x, q, pool, li, page_ids,
+                                      live, active)
         return pool, state, m.head(params, x)
 
     out = []
